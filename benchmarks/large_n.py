@@ -63,9 +63,11 @@ def main() -> None:
             "name": name, "n": mat.n, "nnz": mat.nnz, "batch": BATCH,
             "window": plan.window, "stride": plan.stride,
             "num_blocks": plan.num_blocks,
-            "resident_state_bytes": 2 * (mat.n + 1) * BATCH * 4,
+            # tiled (8, 128) VMEM bytes of the x+b solve state
+            "resident_state_bytes": sptrsv_ops.state_bytes(
+                prog, BATCH, placement="resident")["xb"],
             "blocked_state_bytes": plan.state_bytes(BATCH),
-            # packed single-word encoding: double-buffered instruction VMEM
+            # packed single-word encoding: double-buffered instruction SMEM
             # (shared by both placements; was 3x larger with 5 planes)
             "instr_buffer_bytes": sptrsv_ops.instr_buffer_bytes(
                 prog, CYCLES_PER_BLOCK),

@@ -54,6 +54,9 @@ MODULES = {
 
 
 def main() -> None:
+    from repro.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     wanted = sys.argv[1:] or list(MODULES)
     for key in wanted:
         mod = MODULES[key]

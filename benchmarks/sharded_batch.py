@@ -2,27 +2,24 @@
 
 The batch axis of `api.solve_batch` is embarrassingly parallel, so weak
 scaling over devices (B = ndev * B_PER_DEVICE) should hold solve latency
-roughly flat while total solves/sec grows with the device count.  Each
-device count runs in its own subprocess because
-``--xla_force_host_platform_device_count`` must be set before jax
-initializes; the worker times the sharded solver (`mesh=`) against the
-single-device batched executor at the same total B.
+roughly flat while total solves/sec grows with the device count.  One
+process drives every device count over sub-meshes of its own devices
+(`shard.batch_mesh(k)`): a chip belongs to one process, so no child process
+is started.  On a CPU host, ``python -m benchmarks.sharded_batch`` fakes
+8 devices (the flag must precede JAX's initialization); under
+``benchmarks.run`` the counts are those of the devices JAX already has.
 """
 
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 
 DEVICE_COUNTS = [1, 2, 4, 8]
 B_PER_DEVICE = 16
 MATRICES = ["band_cz", "ckt_add20"]
 
 
-def worker(ndev: int) -> None:
-    """Runs inside the subprocess (XLA_FLAGS already set by the parent)."""
+def run() -> list[dict]:
     import numpy as np
 
     import jax
@@ -32,49 +29,30 @@ def worker(ndev: int) -> None:
 
     from .common import timeit
 
-    assert len(jax.devices()) == ndev, (len(jax.devices()), ndev)
-    mesh = shard.batch_mesh()
-    B = ndev * B_PER_DEVICE
+    counts = [k for k in DEVICE_COUNTS if k <= len(jax.devices())]
     rows = []
     for name in MATRICES:
         mat = generate(name)
         prog = api.compile(mat)
         flops = 2 * mat.nnz - mat.n
-        bmat = np.random.default_rng(0).standard_normal(
-            (mat.n, B)).astype(np.float32)
-
-        sharded = api.make_solver(prog, batch=B, mesh=mesh)
-        local = api.make_solver(prog, batch=B)
-        t_sh = timeit(lambda: np.asarray(sharded(bmat)))
-        t_lo = timeit(lambda: np.asarray(local(bmat)))
-        rows.append({
-            "name": name,
-            "devices": ndev,
-            "batch": B,
-            "sharded_solves_per_s": round(B / t_sh, 1),
-            "single_device_solves_per_s": round(B / t_lo, 1),
-            "sharded_gops": round(B * flops / t_sh / 1e9, 4),
-            "sharded_us_per_call": round(t_sh * 1e6, 1),
-        })
-    print(json.dumps(rows))
-
-
-def run() -> list[dict]:
-    rows = []
-    for ndev in DEVICE_COUNTS:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-        src = os.path.join(os.path.dirname(__file__), "..", "src")
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.sharded_batch",
-             "--worker", str(ndev)],
-            env=env, capture_output=True, text=True, timeout=900,
-            cwd=os.path.join(os.path.dirname(__file__), ".."),
-        )
-        if r.returncode != 0:
-            raise RuntimeError(f"worker ndev={ndev} failed:\n{r.stderr[-2000:]}")
-        rows.extend(json.loads(r.stdout.strip().splitlines()[-1]))
+        for ndev in counts:
+            mesh = shard.batch_mesh(ndev)
+            B = ndev * B_PER_DEVICE
+            bmat = np.random.default_rng(0).standard_normal(
+                (mat.n, B)).astype(np.float32)
+            sharded = api.make_solver(prog, batch=B, mesh=mesh)
+            local = api.make_solver(prog, batch=B)
+            t_sh = timeit(lambda: np.asarray(sharded(bmat)))
+            t_lo = timeit(lambda: np.asarray(local(bmat)))
+            rows.append({
+                "name": name,
+                "devices": ndev,
+                "batch": B,
+                "sharded_solves_per_s": round(B / t_sh, 1),
+                "single_device_solves_per_s": round(B / t_lo, 1),
+                "sharded_gops": round(B * flops / t_sh / 1e9, 4),
+                "sharded_us_per_call": round(t_sh * 1e6, 1),
+            })
     return rows
 
 
@@ -92,7 +70,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    if len(sys.argv) >= 3 and sys.argv[1] == "--worker":
-        worker(int(sys.argv[2]))
-    else:
-        main()
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
+    from repro.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    main()

@@ -408,13 +408,13 @@ def make_pallas_executor(
                 raise ValueError(f"expected b of shape {(n,)}, got {b.shape}")
             return core(b[:, None])[:, 0]
 
-        solve_one.placement = core.placement
-        solve_one.plan = core.plan
-        return solve_one
-    entry = batched_entry(core, n, batch, width)
-    entry.placement = core.placement
-    entry.plan = core.plan
-    return entry
+        solve = solve_one
+    else:
+        solve = batched_entry(core, n, batch, width)
+    solve.placement = core.placement
+    solve.plan = core.plan
+    solve.interpret = core.interpret
+    return solve
 
 
 def execute_jax(prog: Program, b: np.ndarray) -> np.ndarray:

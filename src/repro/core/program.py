@@ -61,6 +61,7 @@ __all__ = [
     "packed_planes",
     "pack_instructions",
     "decode_instructions",
+    "decode_word",
     "validate_fields",
 ]
 
@@ -159,27 +160,38 @@ def pack_instructions(op, src, ctl, slot, planes: int | None = None,
     return np.stack([src, rest], axis=1).astype(np.int32)
 
 
+def decode_word(w0, w1=None):
+    """Decode one lane's packed word(s) into ``(op, src, ctl, slot)``.
+
+    ``w0`` is plane 0 and ``w1`` plane 1 (``None`` in the single-plane
+    regime).  Pure ``&``/``>>`` arithmetic on values of any shape —
+    including the scalars the Pallas kernel reads from SMEM.
+    """
+    if w1 is None:
+        src = w0 & _SRC_MASK
+        rest = w0 >> SRC_BITS
+    else:
+        src = w0
+        rest = w1
+    op = rest & _OP_MASK
+    ctl = (rest >> _CTL_SHIFT) & _CTL_MASK
+    slot = (rest >> _SLOT_SHIFT) & _SLOT_MASK
+    return op, src, ctl, slot
+
+
 def decode_instructions(words, planes: int):
     """Decode packed words back into ``(op, src, ctl, slot)``.
 
     ``words`` is ``[..., planes, P]`` — a whole program, one cycle block, or
     a single cycle row — as a numpy array, a jax array, or a tracer: the
-    decode is pure ``&``/``>>`` arithmetic, so one helper serves the numpy
-    oracle, the `lax.scan` executor, and the Pallas kernels identically.
+    decode is pure ``&``/``>>`` arithmetic (`decode_word`), so one helper
+    serves the numpy oracle, the `lax.scan` executor, and the Pallas
+    kernels identically.
     """
-    w0 = words[..., 0, :]
-    if planes == 1:
-        src = w0 & _SRC_MASK
-        rest = w0 >> SRC_BITS
-    elif planes == 2:
-        src = w0
-        rest = words[..., 1, :]
-    else:
+    if planes not in (1, 2):
         raise ValueError(f"planes must be 1 or 2, got {planes}")
-    op = rest & _OP_MASK
-    ctl = (rest >> _CTL_SHIFT) & _CTL_MASK
-    slot = (rest >> _SLOT_SHIFT) & _SLOT_MASK
-    return op, src, ctl, slot
+    return decode_word(words[..., 0, :],
+                       words[..., 1, :] if planes == 2 else None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -296,7 +308,7 @@ class Program:
     # cycle t (EDGE reads x[src]; FINAL reads b[row] and writes x[row]).
     # Cycles with no active lane carry the empty sentinel (n, -1).  The
     # Pallas wrapper reduces these to per-cycle-block VMEM window bounds
-    # that drive the level-boundary flush/refill DMAs.
+    # that drive the level-boundary flush/shift DMAs.
     row_lo: np.ndarray | None = None  # [T] int32
     row_hi: np.ndarray | None = None  # [T] int32
     # Value provenance of `stream` (values-only recompilation, DESIGN.md

@@ -34,7 +34,6 @@ import weakref
 import numpy as np
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.sharding import rhs_sharding
@@ -76,24 +75,23 @@ def _build_sharded_executor(prog: Program, w_local: int, mesh: Mesh,
     constants fold into the replicated jaxpr); ``backend="pallas"`` maps
     `repro.kernels.sptrsv.ops.build_solver_cols`, so the kernel's memory
     placements — including the HBM-resident row-blocked large-n regime —
-    compose with mesh sharding.  `shard_map` has no replication rule for
-    `pallas_call`, so the pallas path disables the static replication
-    check; that is sound here because in/out specs are fully sharded over
-    the batch axis and the solve never communicates across devices.
+    compose with mesh sharding.  Both per-device solvers start their solve
+    state from unsharded zeros (the `lax.scan` carry, the kernel's
+    scratch), which the varying-axes check rejects, so the check is off;
+    that is sound here because in/out specs are fully sharded over the
+    batch axis and the solve never communicates across devices.
     """
     if backend == "pallas":
         from repro.kernels.sptrsv import ops as sptrsv_ops
 
         solve_local = sptrsv_ops.build_solver_cols(prog, w_local,
                                                    **backend_opts)
-        check = {"check_rep": False}
     else:
         solve_local = build_solve_cols(prog, w_local)
-        check = {}
     spec = P(None, mesh.axis_names)
     return jax.jit(
-        shard_map(solve_local, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                  **check)
+        jax.shard_map(solve_local, mesh=mesh, in_specs=(spec,),
+                      out_specs=spec, check_vma=False)
     )
 
 

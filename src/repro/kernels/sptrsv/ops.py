@@ -3,26 +3,29 @@
 Two memory placements for the solve state (DESIGN.md §1):
 
   * ``resident`` — x and b live in VMEM for the whole solve
-    (`kernel.sptrsv_pallas`); fastest while ``2 * n_pad * B * 4`` bytes fit.
+    (`kernel.sptrsv_pallas`); fastest while both fit.
   * ``blocked``  — x and b stay in HBM and the kernel slides a row-blocked
     VMEM window over them (`kernel.sptrsv_pallas_blocked`), flushing and
-    refilling at cycle-block boundaries with async DMA overlapped against
-    compute.  This is the large-n path: VMEM use is bounded by the window,
-    not by n.
+    shifting it at cycle-block boundaries with async DMA.  This is the
+    large-n path: VMEM use is bounded by the window, not by n.
 
 ``placement="auto"`` (the default) picks per solve: resident while the
 x+b footprint is under ``vmem_limit_bytes``, blocked beyond it whenever the
 program's row-access envelope admits a sliding window (`plan_window`).
+Footprints are counted as the chip lays them out: a ``[rows, B]`` f32 array
+takes whole (8, 128) tiles (`kernel.tiled_bytes`), so at B=16 it occupies
+8x its logical bytes.
 
 The wrapper performs the compiler-side data staging the hardware's stream
 memory provides: values are pre-gathered per instruction word so the kernel
 streams them sequentially (no positional indirection, as in the paper's
 stream-memory design), and the compiler's packed instruction words
 (``Program.instr``, ``[T, planes, P]`` int32 — DESIGN.md §Perf,
-"Instruction encoding") are padded to the cycle-block multiple so each
-block arrives in VMEM with a single DMA.  Per lane-cycle the kernel
-streams ``4 * planes + 4`` bytes (8 B in the single-plane regime) instead
-of the 24 B the historical five unpacked planes cost.
+"Instruction encoding") are padded to the cycle-block multiple and
+flattened, so each block arrives in SMEM with a single DMA.  Per
+lane-cycle the kernel streams ``4 * planes + 4`` bytes (8 B in the
+single-plane regime) instead of the 24 B the historical five unpacked
+planes cost.
 """
 
 from __future__ import annotations
@@ -36,9 +39,17 @@ import jax.numpy as jnp
 
 from repro.core.errors import PlacementInfeasibleError
 from repro.core.executor import _psum_slots, as_batch
-from repro.core.program import Program, decode_instructions
+from repro.core.program import Program
 
-from .kernel import sptrsv_pallas, sptrsv_pallas_blocked
+from repro.kernels.common import resolve_interpret
+
+from .kernel import (
+    blocked_state_bytes,
+    resident_state_bytes,
+    sptrsv_pallas,
+    sptrsv_pallas_blocked,
+    tiled_bytes,
+)
 
 __all__ = [
     "solve",
@@ -51,10 +62,10 @@ __all__ = [
     "DEFAULT_STATE_BYTES",
 ]
 
-# auto-placement threshold for the VMEM x+b solve-state footprint.  Real
-# TPU cores have ~16 MiB of VMEM shared with the instruction double
-# buffers and the psum register file; 4 MiB of solve state is a
-# comfortable default and is overridable per call (``vmem_limit_bytes``).
+# auto-placement threshold for the tiled VMEM x+b solve-state footprint.
+# A TPU v5e kernel gets 16 MiB of scoped VMEM by default, shared with the
+# feedback/psum lane state and Mosaic's own scratch; 4 MiB of x+b keeps the
+# resident kernel inside it.  Overridable per call (``vmem_limit_bytes``).
 DEFAULT_STATE_BYTES = 4 << 20
 
 _ROW_ALIGN = 8  # window/stride row granularity (f32 sublane tile)
@@ -78,8 +89,8 @@ class WindowPlan:
     reason: str = ""
 
     def state_bytes(self, nb: int) -> int:
-        """VMEM bytes for the double-buffered x+b windows."""
-        return (2 * (self.window + 1) + 2 * self.window) * nb * 4
+        """Tiled VMEM bytes of the double-buffered x+b windows."""
+        return 4 * tiled_bytes(self.window, nb)
 
 
 def _round_up(v: int, m: int) -> int:
@@ -177,12 +188,16 @@ def resolve_placement(
                 f"row-blocked placement infeasible: {plan.reason}",
                 detail={"reason": plan.reason})
         return "blocked", plan
-    resident_bytes = 2 * (prog.n + 1) * nb * 4
+    resident_bytes = 2 * tiled_bytes(_resident_rows(prog), nb)
     if resident_bytes <= vmem_limit_bytes or not plan.feasible:
         return "resident", None
     if plan.state_bytes(nb) >= resident_bytes:
         return "resident", None  # window as big as the vector: no point
     return "blocked", plan
+
+
+def _resident_rows(prog: Program) -> int:
+    return _round_up(prog.n, _ROW_ALIGN)
 
 
 def _pad_to(arr: np.ndarray, t_pad: int, fill=0) -> np.ndarray:
@@ -199,27 +214,26 @@ def _stage_instructions(prog: Program, cycles_per_block: int):
 
     The program already carries the packed ``[T, planes, P]`` words — the
     pack happens once at compile time; staging only pads to the cycle-block
-    multiple (pad rows are the all-NOP word 0) and gathers the f32 values
-    per instruction slot so the kernel streams them positionally.
+    multiple (pad rows are the all-NOP word 0), gathers the f32 values per
+    instruction slot so the kernel streams them positionally, and flattens
+    both (cycle-major, then plane, then lane) for the kernel's 1-D SMEM
+    buffers.
     """
     t = prog.cycles
     t_pad = _round_up(t, cycles_per_block)
     values = prog.stream[prog.val_idx]          # [T, P] pre-gathered
-    # transient decode for the NOP mask (don't touch the prog.opcode
-    # property: it would pin all four decoded planes on the Program)
-    op = decode_instructions(prog.instr, prog.planes)[0]
-    values = values * (op != 0)                 # NOP lanes -> 0.0
     instr = _pad_to(prog.instr, t_pad)          # [T_pad, planes, P]
-    return instr, _pad_to(values.astype(np.float32), t_pad)
+    return (instr.reshape(-1),
+            _pad_to(values.astype(np.float32), t_pad).reshape(-1))
 
 
 def instr_buffer_bytes(prog: Program, cycles_per_block: int = 128) -> int:
-    """VMEM bytes of the kernel's double-buffered instruction streaming.
+    """SMEM bytes of the kernel's double-buffered instruction streaming.
 
     Two cycle-block buffers of packed words plus two of pre-gathered f32
     values: ``2 * tb * P * (4 * planes + 4)`` — halved-plus by the packed
     single-word encoding (planes=1: 8 B per buffered lane-cycle vs the 24 B
-    of the historical five-plane layout).
+    of the historical five-plane layout).  A TPU v5e core has 1 MiB of SMEM.
     """
     return 2 * cycles_per_block * prog.num_cus * (4 * prog.planes + 4)
 
@@ -227,21 +241,27 @@ def instr_buffer_bytes(prog: Program, cycles_per_block: int = 128) -> int:
 def state_bytes(prog: Program, nb: int, *, placement: str,
                 plan: WindowPlan | None = None,
                 cycles_per_block: int = 128) -> dict:
-    """VMEM accounting of one Pallas solve: solve state + instruction buffers.
+    """Memory accounting of one Pallas solve, in tiled bytes.
 
-    Returns ``{"xb": ..., "instr": ..., "total": ...}`` bytes for ``nb``
-    RHS columns under ``placement`` (``"blocked"`` needs the `WindowPlan`).
+    Returns ``{"xb", "vmem", "instr"}`` for ``nb`` RHS columns under
+    ``placement`` (``"blocked"`` needs the `WindowPlan`): ``xb`` is the
+    x+b solve state, ``vmem`` all of the kernel's VMEM (x+b plus the
+    feedback/psum lane state), ``instr`` the SMEM instruction buffers.
     """
+    slots = _psum_slots(prog)
     if placement == "blocked":
         if plan is None or not plan.feasible:
             raise ValueError("blocked accounting needs a feasible WindowPlan")
         xb = plan.state_bytes(nb)
+        vmem = blocked_state_bytes(plan.window, nb, prog.num_cus, slots)
     elif placement == "resident":
-        xb = 2 * (prog.n + 1) * nb * 4
+        rows = _resident_rows(prog)
+        xb = 2 * tiled_bytes(rows, nb)
+        vmem = resident_state_bytes(rows, nb, prog.num_cus, slots)
     else:
         raise ValueError(f"unknown placement {placement!r}")
-    ib = instr_buffer_bytes(prog, cycles_per_block)
-    return {"xb": xb, "instr": ib, "total": xb + ib}
+    return {"xb": xb, "vmem": vmem,
+            "instr": instr_buffer_bytes(prog, cycles_per_block)}
 
 
 def build_solver_cols(
@@ -260,7 +280,8 @@ def build_solver_cols(
     resolves the memory placement, and returns a closure suitable for the
     per-(program, knobs) executor cache (`executor.make_pallas_executor`).
     The chosen regime is exposed as ``closure.placement`` /
-    ``closure.plan`` for tests and diagnostics.
+    ``closure.plan``, and the resolved interpreter flag as
+    ``closure.interpret``, for tests and diagnostics.
     """
     mode, plan = resolve_placement(
         prog, width, placement=placement, vmem_limit_bytes=vmem_limit_bytes,
@@ -271,27 +292,26 @@ def build_solver_cols(
     values = jnp.asarray(values_np)
     n = prog.n
     n_slots = _psum_slots(prog)
-    n_rows = (n + 1) if mode == "resident" else plan.n_hbm
+    n_rows = _resident_rows(prog) if mode == "resident" else plan.n_hbm
+    interpret = resolve_interpret(interpret)
+    kw = dict(num_cus=prog.num_cus, planes=prog.planes,
+              cycles_per_block=cycles_per_block, num_slots=n_slots,
+              interpret=interpret)
 
     @jax.jit  # fold the pad/slice into the kernel dispatch
     def solve_cols(bmat: jnp.ndarray) -> jnp.ndarray:
         bp = jnp.zeros((n_rows, width), jnp.float32)
         bp = bp.at[:n].set(jnp.asarray(bmat, jnp.float32))
         if mode == "resident":
-            x = sptrsv_pallas(
-                instr, values, bp, cycles_per_block=cycles_per_block,
-                num_slots=n_slots, interpret=interpret,
-            )
+            x = sptrsv_pallas(instr, values, bp, **kw)
         else:
-            x = sptrsv_pallas_blocked(
-                instr, values, bp, window=plan.window, stride=plan.stride,
-                cycles_per_block=cycles_per_block, num_slots=n_slots,
-                interpret=interpret,
-            )
+            x = sptrsv_pallas_blocked(instr, values, bp, window=plan.window,
+                                      stride=plan.stride, **kw)
         return x[:n]
 
     solve_cols.placement = mode
     solve_cols.plan = plan
+    solve_cols.interpret = interpret
     return solve_cols
 
 

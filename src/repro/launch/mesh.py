@@ -16,11 +16,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 chips per pod; 2 pods when multi_pod (512 chips total)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(model_axis: int = 1):
     """Mesh over whatever devices exist (smoke tests / single host)."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return _auto_mesh((n // model_axis, model_axis), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: the model code places arrays with with_sharding_constraint,
+    # which rejects specs naming Explicit axes (jax.make_mesh's default)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -2,7 +2,7 @@
 
 The blocked kernel is forced (``placement="blocked"``) with a small
 ``cycles_per_block`` in interpret mode, so the window machinery — boundary
-flush/shift/refill DMAs across many cycle blocks — is exercised on matrices
+flush/shift DMAs across many cycle blocks — is exercised on matrices
 whose ``x[n_pad, B]`` footprint exceeds a (deliberately tiny) configured
 VMEM threshold, as on a real TPU it would at paper-scale n.
 """

@@ -30,7 +30,8 @@ from repro.distributed.sharding import (param_shardings, cache_shardings,
                                         batch_sharding, dp_axes)
 
 out = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 for arch in %ARCHS%:
     cfg = get_config(arch).reduced()
     flags = RuntimeFlags(use_pallas=False, interpret=False, remat=True,

@@ -27,6 +27,29 @@ def test_sptrsv_kernel_vs_oracle(name, cpb):
     )
 
 
+@pytest.mark.parametrize("name,placement", [
+    ("hub_wall", "resident"), ("hub_wall", "blocked"),
+    ("ckt_add20", "resident"), ("ckt_fpga", "blocked"),
+])
+def test_sptrsv_kernel_same_cycle_finals(name, placement):
+    """Cycles whose FINAL lanes write several x rows at once: the kernel
+    runs lanes one after another, which must equal the synchronous cycle."""
+    from repro.core.program import OP_FINAL
+    from repro.kernels.sptrsv import ops
+
+    mat = generate(name)
+    prog = api.compile(mat)
+    finals = (prog.opcode == OP_FINAL).sum(axis=1)
+    assert finals.max() >= 2, finals.max()
+    bmat = np.random.default_rng(5).standard_normal((mat.n, 3))
+    x = ops.solve(prog, bmat, cycles_per_block=64, interpret=True,
+                  placement=placement)
+    # same float32 operations in the same order as the lax.scan executor
+    np.testing.assert_array_equal(x, api.solve_batch(prog, bmat))
+    ref = np.stack([serial_solve(mat, bmat[:, j]) for j in range(3)], axis=1)
+    np.testing.assert_allclose(x, ref, rtol=1e-5, atol=1e-5)
+
+
 def test_sptrsv_kernel_vs_program_oracle():
     from repro.kernels.sptrsv import ops, ref
 

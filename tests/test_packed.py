@@ -234,11 +234,13 @@ def test_vmem_instruction_buffers_halved():
     five_plane = 2 * 128 * prog.num_cus * 24
     assert now * 2 <= five_plane
     acct = ops.state_bytes(prog, 8, placement="resident")
-    assert acct["instr"] == now and acct["total"] == acct["xb"] + now
+    # the instruction buffers live in SMEM; VMEM holds x+b plus lane state
+    assert acct["instr"] == now and acct["vmem"] > acct["xb"]
     plan = ops.plan_window(prog, 64)
     acct_b = ops.state_bytes(prog, 8, placement="blocked", plan=plan,
                              cycles_per_block=64)
     assert acct_b["xb"] == plan.state_bytes(8)
+    assert acct_b["vmem"] - acct_b["xb"] == acct["vmem"] - acct["xb"]
 
 
 def test_instruction_breakdown_smoke():

@@ -2,10 +2,10 @@
 
 Fast tests run in-process on whatever devices exist (a 1-device mesh still
 exercises the full shard_map/placement/cache path).  The genuinely
-multi-device checks force an 8-device CPU host via
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` in a subprocess —
-the flag must be set before jax initializes.  At ~6 s the subprocess test
-stays inside the fast ``-m "not slow"`` loop.
+multi-device checks force a 4- and an 8-device CPU host via
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` in a subprocess —
+the flag must be set before jax initializes.  At ~6 s each the subprocess
+tests stay inside the fast ``-m "not slow"`` loop.
 """
 
 import json
@@ -101,11 +101,12 @@ def test_split_composes_with_sharded_path(mesh):
 
 
 # ---------------------------------------------------------------------------
-# forced 8-device host (subprocess: XLA_FLAGS must precede jax init)
+# forced multi-device host (subprocess: XLA_FLAGS must precede jax init)
 
 SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import os, sys
+ndev = int(sys.argv[1])
+os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
 import json
 import numpy as np
 import jax
@@ -127,21 +128,38 @@ for B in [1, 7, 8, 32]:
     w_local, _ = shard.sharded_widths(B, mesh)
     out["cases"].append({"B": B, "w_local": w_local, "rel": rel,
                          "traces": executor.trace_count() - before})
+# where the columns really live: one column block per device
+B = 8 * ndev
+bmat = rng.standard_normal((mat.n, B))
+ref = np.stack([serial_solve(mat, bmat[:, i]) for i in range(B)], axis=1)
+for backend, opts in (("jax", {}), ("pallas", {"interpret": True})):
+    x = shard.make_sharded_solver(prog, B, mesh, backend=backend, **opts)(bmat)
+    out[backend] = {
+        "shard_devices": len({s.device for s in x.addressable_shards}),
+        "shard_cols": sorted({s.data.shape[1] for s in x.addressable_shards}),
+        "rel": float(np.abs(np.asarray(x) - ref).max() / np.abs(ref).max()),
+    }
 print(json.dumps(out))
 """
 
 
-def test_forced_8_device_mesh():
-    # ~6 s (subprocess jax init + 2 traces): stays inside the fast loop
+@pytest.mark.parametrize("ndev", [4, 8])
+def test_forced_device_mesh(ndev):
+    # ~6 s (subprocess jax init + a few traces): stays inside the fast loop
     env = dict(os.environ)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
-    r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
+    r = subprocess.run([sys.executable, "-c", SCRIPT, str(ndev)], env=env,
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["devices"] == 8
+    assert out["devices"] == ndev
+    for backend in ("jax", "pallas"):
+        case = out[backend]
+        assert case["shard_devices"] == ndev, case  # not all on device 0
+        assert case["shard_cols"] == [8], case
+        assert case["rel"] <= 1e-5, case
     seen_widths = set()
     for case in out["cases"]:
         assert case["rel"] <= 1e-5, case
