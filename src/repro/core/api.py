@@ -70,6 +70,7 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import matrices
 from .compiler import ComputeDag, compile_dag as _compile_dag
@@ -182,13 +183,16 @@ def solve_batch(prog: Program, b_matrix: np.ndarray, mesh=None,
     for the placement knobs, including the HBM-resident row-blocked
     large-n path).
     """
-    validate_backend(backend, backend_opts)
-    bmat, _ = as_batch(b_matrix)
-    if mesh is not None or backend != "jax":
+    with TraceAnnotation("sptrsv.solve_batch"):
+        validate_backend(backend, backend_opts)
+        bmat, _ = as_batch(b_matrix)
+        if mesh is None and backend == "jax":
+            return execute_jax(prog, bmat)
         solver = make_solver(prog, batch=bmat.shape[1], mesh=mesh,
                              backend=backend, **backend_opts)
-        return np.asarray(solver(bmat))
-    return execute_jax(prog, bmat)
+        x = solver(bmat)
+        with TraceAnnotation("sptrsv.readback"):
+            return np.asarray(x)
 
 
 def make_solver(prog: Program, batch: int | None = None, mesh=None,

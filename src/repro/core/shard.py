@@ -34,6 +34,7 @@ import weakref
 import numpy as np
 
 import jax
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.sharding import rhs_sharding
@@ -104,8 +105,9 @@ def _cached_sharded_executor(prog: Program, w_local: int, mesh: Mesh,
     key = (w_local, mesh, backend, tuple(sorted(backend_opts.items())))
     fn = per_prog.get(key)
     if fn is None:
-        fn = _build_sharded_executor(prog, w_local, mesh, backend,
-                                     backend_opts)
+        with TraceAnnotation("sptrsv.executor_build"):
+            fn = _build_sharded_executor(prog, w_local, mesh, backend,
+                                         backend_opts)
         per_prog[key] = fn
     return fn
 
