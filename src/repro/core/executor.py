@@ -438,6 +438,8 @@ def make_pallas_executor(
     solve.placement = core.placement
     solve.plan = core.plan
     solve.interpret = core.interpret
+    solve.stream_words = core.stream_words
+    solve.slot_words = core.slot_words
     return solve
 
 

@@ -9,20 +9,42 @@ TPU adaptation of the paper's accelerator (DESIGN.md §1):
   * the solution vector, the feedback registers and the psum register file
     live in VMEM refs (the software-managed scratchpads of the paper).
 
-Per-lane execution.  A VLIW cycle is run as a scalar-driven loop over the
-P compute units: each lane's packed word is read from SMEM and decoded on
-the scalar unit (`program.decode_word`), and its row operands move as
-single ``[1, B]`` rows addressed by those scalars — ``x[src]`` /
-``b[src]`` loads, the FINAL ``x[src]`` store, and the lane's own feedback
-and psum-slot rows.  Lanes whose word is a no-op (op NOP, psum KEEP) are
-skipped.  Every access is a dynamically indexed row of a VMEM ref, which
-Mosaic lowers directly; no vector gather/scatter by 64 independent indices
-is needed.  Lanes of one cycle are independent (the scheduler guarantees
-an EDGE only reads x rows finalized in earlier cycles and FINAL rows are
-distinct), so running them one after another is exactly the synchronous
-cycle semantics of the numpy oracle.
+Per-entry execution.  The kernel runs the lanes of a VLIW cycle one after
+another on the scalar unit, so on the TPU a program is simply its *active*
+lane-words in cycle-major, lane-minor order; the cycle x lane grid is a
+hardware notion the kernel does not need.  The wrapper
+(`ops._stage_instructions`) keeps, per cycle block, only the words that do
+something (op not NOP, or psum control not KEEP) with their pre-gathered
+values and their lane ids (one more int32 plane beside the word planes),
+and pads every block's segment to one common length K with a filler entry
+(word 0 = NOP/KEEP, lane 0, value 0).  A per-block count table in SMEM says
+how many entries block g holds; the kernel runs ``ceil(count_g / UNROLL)``
+iterations of a loop whose body executes `UNROLL` entries.
 
-Double-buffered cycle-block streaming: the kernel owns two SMEM instruction
+Each entry's packed word is read from SMEM and decoded on the scalar unit
+(`program.decode_word`), and its row operands move as single ``[1, B]``
+rows addressed by those scalars — ``x[src]`` / ``b[src]`` loads, the
+``x[src]`` store, and the lane's own feedback and psum-slot rows.  The body
+has no branch: both conditional stores are unconditional stores of a
+select — the psum slot row gets ``feedback`` on STORE_RESET/SWAP and else
+the value just read from it, and ``x[src]`` gets ``(b[src] - psum) * v`` on
+FINAL and else the value just read from it.  Writing back a value read
+earlier in the same sequential order leaves the ref unchanged, so the
+results are bit-identical to the branching form, and the filler entry
+leaves every ref unchanged.  The loop body is one basic block, so the
+scheduler can overlap one entry's SMEM reads and decode with the previous
+entry's row work.  A program whose lanes are all active pays one extra SMEM
+read per entry (its lane id) and gains the branch removal.
+
+Every access is a dynamically indexed row of a VMEM ref, which Mosaic
+lowers directly; no vector gather/scatter by 64 independent indices is
+needed.  Lanes of one cycle are independent (the scheduler guarantees an
+EDGE only reads x rows finalized in earlier cycles and FINAL rows are
+distinct), so running them one after another is exactly the synchronous
+cycle semantics of the numpy oracle, and dropping the no-op words changes
+nothing but the work.
+
+Double-buffered cycle-block streaming: the kernel owns two SMEM stream
 buffers and, while executing cycle block g out of one buffer, prefetches
 block g+1 into the other (`pltpu.make_async_copy` + per-slot DMA
 semaphores), so instruction HBM traffic overlaps compute.
@@ -64,8 +86,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.program import (
     OP_EDGE,
     OP_FINAL,
-    OP_NOP,
-    PS_KEEP,
     PS_LOAD,
     PS_RESET,
     PS_STORE_RESET,
@@ -75,6 +95,8 @@ from repro.core.program import (
 from repro.kernels.common import resolve_interpret
 
 __all__ = [
+    "SEGMENT_ALIGN",
+    "UNROLL",
     "sptrsv_pallas",
     "sptrsv_pallas_blocked",
     "blocked_state_bytes",
@@ -89,6 +111,11 @@ _SUBLANES, _LANES = 8, 128
 # state needs more raises its own limit (`vmem_limit`).
 _SCOPED_VMEM_DEFAULT = 16 << 20
 _VMEM_HEADROOM = 4 << 20  # Mosaic's own internal scratch
+# Entries executed per iteration of the kernel's stream loop.
+UNROLL = 8
+# Segment length granularity: Mosaic slices a 1-D HBM array only in whole
+# (1024,) tiles, and a multiple of it is a multiple of UNROLL.
+SEGMENT_ALIGN = 1024
 
 
 def tiled_bytes(rows: int, cols: int) -> int:
@@ -116,61 +143,54 @@ def blocked_state_bytes(window: int, nb: int, p: int, num_slots: int) -> int:
     return 4 * tiled_bytes(window, nb) + _lane_state_bytes(p, num_slots, nb)
 
 
-def _run_block(ibuf, vbuf, slot, x_ref, b_ref, fb_ref, rf_ref, *, base,
-               rows, tb, p, planes, num_slots):
-    """Execute the ``tb`` cycles held in instruction buffer ``slot``.
+def _run_block(ibuf, vbuf, slot, count, x_ref, b_ref, fb_ref, rf_ref, *,
+               base, rows, k, planes, num_slots):
+    """Execute the ``count`` entries of the cycle block in buffer ``slot``.
 
     ``x_ref``/``b_ref`` hold solution/RHS rows ``[base, base + rows)`` (the
     whole padded vector with ``base=0`` in the VMEM-resident kernel, the
-    sliding window in the blocked one).  ``ibuf`` is the flat SMEM word
-    buffer ``[2 * tb * planes * p]``, ``vbuf`` the flat SMEM value buffer
-    ``[2 * tb * p]``.
+    sliding window in the blocked one).  ``ibuf`` is the flat SMEM buffer
+    ``[2 * (planes + 1) * k]`` of packed words with the lane ids as a last
+    plane, ``vbuf`` the flat SMEM value buffer ``[2 * k]``.  Entries past
+    ``count`` up to the next `UNROLL` multiple are filler.
     """
 
-    def lane(t, l):
-        w = (slot * tb + t) * planes * p + l
+    def entry(e):
+        w = slot * (planes + 1) * k + e
         op, src, ct, sl = decode_word(
-            ibuf[w], ibuf[w + p] if planes == 2 else None)
+            ibuf[w], ibuf[w + k] if planes == 2 else None)
+        lane = ibuf[w + planes * k]
+        v = vbuf[slot * k + e]
+        fb = fb_ref[pl.ds(lane, 1), :]
+        r = lane * num_slots + jnp.minimum(sl, num_slots - 1)
+        slot_val = rf_ref[pl.ds(r, 1), :]
+        # psum control mux (S1/S2 of Fig. 4b): the running sum continues
+        # from feedback (KEEP), restarts at 0 (RESET, STORE_RESET) or
+        # resumes a parked slot (LOAD, SWAP); STORE_RESET/SWAP park feedback
+        from_slot = (ct == PS_LOAD) | (ct == PS_SWAP)
+        zeroed = (ct == PS_RESET) | (ct == PS_STORE_RESET)
+        pv = jnp.where(from_slot, slot_val, jnp.where(zeroed, 0.0, fb))
+        parks = (ct == PS_STORE_RESET) | (ct == PS_SWAP)
+        rf_ref[pl.ds(r, 1), :] = jnp.where(parks, fb, slot_val)
 
-        @pl.when((op != OP_NOP) | (ct != PS_KEEP))
-        def _():
-            v = vbuf[(slot * tb + t) * p + l]
-            fb = fb_ref[pl.ds(l, 1), :]
-            r = l * num_slots + jnp.minimum(sl, num_slots - 1)
-            slot_val = rf_ref[pl.ds(r, 1), :]
-            # psum control mux (S1/S2 of Fig. 4b)
-            pv = jnp.where(ct == PS_RESET, 0.0, fb)
-            pv = jnp.where(ct == PS_LOAD, slot_val, pv)
+        row = jnp.clip(src - base, 0, rows - 1)
+        x_row = x_ref[pl.ds(row, 1), :]
+        pv = jnp.where(op == OP_EDGE, pv + v * x_row, pv)
+        x_ref[pl.ds(row, 1), :] = jnp.where(
+            op == OP_FINAL, (b_ref[pl.ds(row, 1), :] - pv) * v, x_row)
+        fb_ref[pl.ds(lane, 1), :] = pv
 
-            @pl.when((ct == PS_STORE_RESET) | (ct == PS_SWAP))
-            def _():
-                rf_ref[pl.ds(r, 1), :] = fb
+    def step(i, carry):
+        for u in range(UNROLL):
+            entry(i * UNROLL + u)
+        return carry
 
-            pv = jnp.where(ct == PS_STORE_RESET, 0.0, pv)
-            pv = jnp.where(ct == PS_SWAP, slot_val, pv)
-
-            row = jnp.clip(src - base, 0, rows - 1)
-            pv = jnp.where(op == OP_EDGE, pv + v * x_ref[pl.ds(row, 1), :], pv)
-
-            @pl.when(op == OP_FINAL)
-            def _():
-                x_ref[pl.ds(row, 1), :] = (b_ref[pl.ds(row, 1), :] - pv) * v
-
-            fb_ref[pl.ds(l, 1), :] = pv
-
-    def cycle(t, carry):
-        def step(l, c):
-            lane(t, l)
-            return c
-
-        return jax.lax.fori_loop(0, p, step, carry)
-
-    jax.lax.fori_loop(0, tb, cycle, 0)
+    jax.lax.fori_loop(0, (count + UNROLL - 1) // UNROLL, step, 0)
 
 
-def _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem, vsem, *, tb, p, planes):
+def _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem, vsem, *, k, planes):
     """(instr_dma, val_dma) constructors for cycle block g into buffer slot."""
-    wblk, vblk = tb * planes * p, tb * p
+    wblk = (planes + 1) * k
 
     def instr_dma(slot, g):
         return pltpu.make_async_copy(
@@ -179,43 +199,54 @@ def _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem, vsem, *, tb, p, planes):
 
     def val_dma(slot, g):
         return pltpu.make_async_copy(
-            val_ref.at[pl.ds(g * vblk, vblk)], vbuf.at[pl.ds(slot * vblk, vblk)],
+            val_ref.at[pl.ds(g * k, k)], vbuf.at[pl.ds(slot * k, k)],
             vsem.at[slot])
 
     return instr_dma, val_dma
 
 
-def _stream_scratch(tb, p, planes, num_slots, nb):
+def _stream_scratch(k, p, planes, num_slots, nb):
     """Scratch shared by both kernels: SMEM stream buffers + lane state."""
     return [
-        pltpu.SMEM((2 * tb * planes * p,), jnp.int32),  # ibuf
-        pltpu.SMEM((2 * tb * p,), jnp.float32),         # vbuf
-        pltpu.VMEM((p, nb), jnp.float32),               # feedback
-        pltpu.VMEM((p * num_slots, nb), jnp.float32),   # psum register file
-        pltpu.SemaphoreType.DMA((2,)),                  # isem
-        pltpu.SemaphoreType.DMA((2,)),                  # vsem
+        pltpu.SMEM((2 * (planes + 1) * k,), jnp.int32),  # ibuf
+        pltpu.SMEM((2 * k,), jnp.float32),               # vbuf
+        pltpu.VMEM((p, nb), jnp.float32),                # feedback
+        pltpu.VMEM((p * num_slots, nb), jnp.float32),    # psum register file
+        pltpu.SemaphoreType.DMA((2,)),                   # isem
+        pltpu.SemaphoreType.DMA((2,)),                   # vsem
     ]
+
+
+def _stream_shape(instr, values, counts, planes):
+    """(num_blocks, k) of a staged stream; checks the three agree."""
+    assert planes in (1, 2), f"expected packed 1- or 2-plane words, got {planes}"
+    num_blocks = counts.shape[0]
+    k = values.shape[0] // num_blocks
+    assert values.shape[0] == num_blocks * k and k % SEGMENT_ALIGN == 0, \
+        "pad every block's segment to one SEGMENT_ALIGN multiple first"
+    assert instr.shape[0] == num_blocks * (planes + 1) * k, \
+        "instr/values stream mismatch"
+    return num_blocks, k
 
 
 def _kernel(
     # inputs
-    instr_ref,  # [T * planes * P] int32, HBM (streamed by DMA)
-    val_ref,    # [T * P]          f32,   HBM (pre-gathered values)
-    b_ref,      # [n_pad, B]       f32,   VMEM — loaded once per solve
+    instr_ref,  # [G * (planes + 1) * K] int32, HBM (streamed by DMA)
+    val_ref,    # [G * K]                f32,   HBM (pre-gathered values)
+    cnt_ref,    # [G]                    int32, SMEM (entries per block)
+    b_ref,      # [n_pad, B]             f32,   VMEM — loaded once per solve
     # outputs
-    x_ref,      # [n_pad, B]       f32,   VMEM
+    x_ref,      # [n_pad, B]             f32,   VMEM
     # scratch
     ibuf, vbuf, fb_ref, rf_ref, isem, vsem,
     *,
-    cycles_per_block: int,
+    k: int,
     num_blocks: int,
     num_slots: int,
     planes: int,
-    p: int,
 ):
-    tb = cycles_per_block
     instr_dma, val_dma = _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem,
-                                      vsem, tb=tb, p=p, planes=planes)
+                                      vsem, k=k, planes=planes)
     x_ref[...] = jnp.zeros(x_ref.shape, jnp.float32)
     fb_ref[...] = jnp.zeros(fb_ref.shape, jnp.float32)
     rf_ref[...] = jnp.zeros(rf_ref.shape, jnp.float32)
@@ -235,8 +266,8 @@ def _kernel(
 
         instr_dma(slot, g).wait()
         val_dma(slot, g).wait()
-        _run_block(ibuf, vbuf, slot, x_ref, b_ref, fb_ref, rf_ref, base=0,
-                   rows=x_ref.shape[0], tb=tb, p=p, planes=planes,
+        _run_block(ibuf, vbuf, slot, cnt_ref[g], x_ref, b_ref, fb_ref, rf_ref,
+                   base=0, rows=x_ref.shape[0], k=k, planes=planes,
                    num_slots=num_slots)
         return carry
 
@@ -245,53 +276,49 @@ def _kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_cus", "planes", "cycles_per_block", "num_slots",
-                     "interpret"),
+    static_argnames=("num_cus", "planes", "num_slots", "interpret"),
 )
 def sptrsv_pallas(
-    instr: jnp.ndarray,    # [T * planes * P] packed int32 (T a block multiple)
-    values: jnp.ndarray,   # [T * P] f32 (pre-gathered stream values)
+    instr: jnp.ndarray,    # [G * (planes + 1) * K] int32 (words + lane ids)
+    values: jnp.ndarray,   # [G * K] f32 (pre-gathered stream values)
+    counts: jnp.ndarray,   # [G] int32 (active entries per cycle block)
     b: jnp.ndarray,        # [n_pad, B] f32
     *,
     num_cus: int,
     planes: int,
-    cycles_per_block: int = 128,
     num_slots: int = 12,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """VMEM-resident solve; ``instr``/``values`` are the flat staged streams
-    of `ops.build_solver_cols` (cycle-major, then plane, then lane)."""
+    """VMEM-resident solve; ``instr``/``values``/``counts`` are the compacted
+    stream of `ops._stage_instructions` (per cycle block: plane-major words,
+    lane ids, values)."""
     interpret = resolve_interpret(interpret)
     p = num_cus
-    assert planes in (1, 2), f"expected packed 1- or 2-plane words, got {planes}"
-    t = values.shape[0] // p
-    assert instr.shape[0] == t * planes * p, "instr/values stream mismatch"
-    assert t % cycles_per_block == 0, "pad the instruction stream first"
+    num_blocks, k = _stream_shape(instr, values, counts, planes)
     n_pad, nb = b.shape
     state = resident_state_bytes(n_pad, nb, p, num_slots)
 
     kernel = functools.partial(
         _kernel,
-        cycles_per_block=cycles_per_block,
-        num_blocks=t // cycles_per_block,
+        k=k,
+        num_blocks=num_blocks,
         num_slots=num_slots,
         planes=planes,
-        p=p,
     )
     return pl.pallas_call(
         kernel,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.HBM),   # instr stays in HBM
             pl.BlockSpec(memory_space=pltpu.HBM),   # values stay in HBM
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # per-block counts
             pl.BlockSpec(memory_space=pltpu.VMEM),  # b loaded once
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, nb), jnp.float32),
-        scratch_shapes=_stream_scratch(cycles_per_block, p, planes, num_slots,
-                                       nb),
+        scratch_shapes=_stream_scratch(k, p, planes, num_slots, nb),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(state)),
         interpret=interpret,
-    )(instr, values, b)
+    )(instr, values, counts, b)
 
 
 # ---------------------------------------------------------------------------
@@ -299,24 +326,24 @@ def sptrsv_pallas(
 # ---------------------------------------------------------------------------
 def _blocked_kernel(
     # inputs
-    instr_ref,   # [T * planes * P] int32, HBM (streamed by DMA)
-    val_ref,     # [T * P]          f32,   HBM (pre-gathered values)
-    b_hbm_ref,   # [n_hbm, lanes]   f32,   HBM (windowed by DMA)
+    instr_ref,   # [G * (planes + 1) * K] int32, HBM (streamed by DMA)
+    val_ref,     # [G * K]                f32,   HBM (pre-gathered values)
+    cnt_ref,     # [G]                    int32, SMEM (entries per block)
+    b_hbm_ref,   # [n_hbm, lanes]         f32,   HBM (windowed by DMA)
     # outputs
-    x_hbm_ref,   # [n_hbm, lanes]   f32,   HBM (windowed by DMA)
+    x_hbm_ref,   # [n_hbm, lanes]         f32,   HBM (windowed by DMA)
     # scratch
     ibuf, vbuf, fb_ref, rf_ref, isem, vsem,
     xwin,        # [2, window, lanes] — two x windows
     bwin,        # [2, window, lanes] — two b windows (read-only, refetched)
     bsem, xssem, xfsem,
     *,
-    cycles_per_block: int,
+    k: int,
     num_blocks: int,
     num_slots: int,
     window: int,
     stride: int,
     planes: int,
-    p: int,
 ):
     """x/b HBM-resident solve over a sliding VMEM row window.
 
@@ -334,10 +361,9 @@ def _blocked_kernel(
     shift and flush before it runs, and ``window >= 2*stride`` (checked by
     the wrapper) keeps the flushed rows out of the shifted range.
     """
-    tb = cycles_per_block
     w, r = window, stride
     instr_dma, val_dma = _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem,
-                                      vsem, tb=tb, p=p, planes=planes)
+                                      vsem, k=k, planes=planes)
 
     def b_dma(slot, g):
         return pltpu.make_async_copy(
@@ -382,8 +408,8 @@ def _blocked_kernel(
             val_dma(nxt, g + 1).start()
             b_dma(nxt, g + 1).start()
 
-        _run_block(ibuf, vbuf, slot, xwin.at[slot], bwin.at[slot], fb_ref,
-                   rf_ref, base=g * r, rows=w, tb=tb, p=p, planes=planes,
+        _run_block(ibuf, vbuf, slot, cnt_ref[g], xwin.at[slot], bwin.at[slot],
+                   fb_ref, rf_ref, base=g * r, rows=w, k=k, planes=planes,
                    num_slots=num_slots)
 
         @pl.when(g + 1 < num_blocks)
@@ -405,19 +431,19 @@ def _blocked_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_cus", "planes", "cycles_per_block", "num_slots",
-                     "window", "stride", "interpret"),
+    static_argnames=("num_cus", "planes", "num_slots", "window", "stride",
+                     "interpret"),
 )
 def sptrsv_pallas_blocked(
-    instr: jnp.ndarray,    # [T * planes * P] packed int32 (T a block multiple)
-    values: jnp.ndarray,   # [T * P] f32 (pre-gathered stream values)
+    instr: jnp.ndarray,    # [G * (planes + 1) * K] int32 (words + lane ids)
+    values: jnp.ndarray,   # [G * K] f32 (pre-gathered stream values)
+    counts: jnp.ndarray,   # [G] int32 (active entries per cycle block)
     b: jnp.ndarray,        # [n_hbm, B] f32 (padded to the window sweep)
     *,
     num_cus: int,
     planes: int,
     window: int,
     stride: int,
-    cycles_per_block: int = 128,
     num_slots: int = 12,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -433,11 +459,7 @@ def sptrsv_pallas_blocked(
     """
     interpret = resolve_interpret(interpret)
     p = num_cus
-    assert planes in (1, 2), f"expected packed 1- or 2-plane words, got {planes}"
-    t = values.shape[0] // p
-    assert instr.shape[0] == t * planes * p, "instr/values stream mismatch"
-    assert t % cycles_per_block == 0, "pad the instruction stream first"
-    num_blocks = t // cycles_per_block
+    num_blocks, k = _stream_shape(instr, values, counts, planes)
     n_hbm, nb = b.shape
     lanes = -(-nb // _LANES) * _LANES
     assert stride >= 1 and window >= 2 * stride, (window, stride)
@@ -447,25 +469,24 @@ def sptrsv_pallas_blocked(
 
     kernel = functools.partial(
         _blocked_kernel,
-        cycles_per_block=cycles_per_block,
+        k=k,
         num_blocks=num_blocks,
         num_slots=num_slots,
         window=window,
         stride=stride,
         planes=planes,
-        p=p,
     )
     return pl.pallas_call(
         kernel,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.HBM),   # instr stays in HBM
             pl.BlockSpec(memory_space=pltpu.HBM),   # values stay in HBM
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # per-block counts
             pl.BlockSpec(memory_space=pltpu.HBM),   # b stays in HBM
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.HBM),  # x stays in HBM
         out_shape=jax.ShapeDtypeStruct((n_hbm, lanes), jnp.float32),
-        scratch_shapes=_stream_scratch(cycles_per_block, p, planes, num_slots,
-                                       lanes) + [
+        scratch_shapes=_stream_scratch(k, p, planes, num_slots, lanes) + [
             pltpu.VMEM((2, window, lanes), jnp.float32),  # xwin
             pltpu.VMEM((2, window, lanes), jnp.float32),  # bwin
             pltpu.SemaphoreType.DMA((2,)),                # bsem
@@ -474,4 +495,4 @@ def sptrsv_pallas_blocked(
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(state)),
         interpret=interpret,
-    )(instr, values, jnp.pad(b, ((0, 0), (0, lanes - nb))))[:, :nb]
+    )(instr, values, counts, jnp.pad(b, ((0, 0), (0, lanes - nb))))[:, :nb]

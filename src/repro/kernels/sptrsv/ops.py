@@ -21,11 +21,11 @@ memory provides: values are pre-gathered per instruction word so the kernel
 streams them sequentially (no positional indirection, as in the paper's
 stream-memory design), and the compiler's packed instruction words
 (``Program.instr``, ``[T, planes, P]`` int32 — DESIGN.md §Perf,
-"Instruction encoding") are padded to the cycle-block multiple and
-flattened, so each block arrives in SMEM with a single DMA.  Per
-lane-cycle the kernel streams ``4 * planes + 4`` bytes (8 B in the
-single-plane regime) instead of the 24 B the historical five unpacked
-planes cost.
+"Instruction encoding") are compacted per cycle block to the words that do
+something, each with its lane id, padded to one segment length K, and
+flattened, so each block arrives in SMEM with a single DMA per stream.  Per
+kept entry the kernel streams ``4 * planes + 4 + 4`` bytes (words, lane id,
+value); no-op lane slots are neither streamed nor executed.
 """
 
 from __future__ import annotations
@@ -39,11 +39,13 @@ import jax.numpy as jnp
 
 from repro.core.errors import PlacementInfeasibleError
 from repro.core.executor import _psum_slots, as_batch
-from repro.core.program import Program
+from repro.core.program import OP_NOP, PS_KEEP, Program, decode_instructions
 
 from repro.kernels.common import resolve_interpret
 
 from .kernel import (
+    SEGMENT_ALIGN,
+    UNROLL,
     blocked_state_bytes,
     resident_state_bytes,
     sptrsv_pallas,
@@ -58,6 +60,7 @@ __all__ = [
     "build_solver_cols",
     "instr_buffer_bytes",
     "state_bytes",
+    "Stream",
     "WindowPlan",
     "DEFAULT_STATE_BYTES",
 ]
@@ -209,33 +212,89 @@ def _pad_to(arr: np.ndarray, t_pad: int, fill=0) -> np.ndarray:
     return out
 
 
-def _stage_instructions(prog: Program, cycles_per_block: int):
-    """Pad the packed instruction words and pre-gather the stream values.
+@dataclasses.dataclass(frozen=True)
+class Stream:
+    """A program staged for the kernel: its active words, compacted.
+
+    Cycle block g (``cycles_per_block`` cycles) keeps only the words with op
+    not NOP or psum control not KEEP, in cycle-major, lane-minor order, in a
+    segment of ``k`` entries (a `kernel.SEGMENT_ALIGN` multiple) padded with
+    the filler entry (word 0, lane 0, value 0).  ``instr`` is ``[G, planes + 1,
+    k]`` flattened (plane-major words, then the lane ids), ``values``
+    ``[G, k]`` flattened, ``counts[g]`` the active entries of block g.
+    """
+
+    instr: np.ndarray
+    values: np.ndarray
+    counts: np.ndarray
+    k: int
+    slot_words: int  # T_pad * P: the lane slots of the uncompacted grid
+
+    @property
+    def stream_words(self) -> int:
+        """Entries the kernel executes per solve (counts rounded up to the
+        unroll factor, filler included)."""
+        return int((-(-self.counts // UNROLL) * UNROLL).sum())
+
+
+def _active_blocks(prog: Program, cycles_per_block: int):
+    """``(words [G, planes, tb*P], active [G, tb*P])`` of the padded program,
+    each block's lane slots in cycle-major, lane-minor order."""
+    tb, p, planes = cycles_per_block, prog.num_cus, prog.planes
+    g = -(-prog.cycles // tb)
+    words = _pad_to(prog.instr, g * tb)                  # [T_pad, planes, P]
+    op, _, ctl, _ = decode_instructions(words, planes)   # [T_pad, P]
+    active = ((op != OP_NOP) | (ctl != PS_KEEP)).reshape(g, tb * p)
+    words = words.reshape(g, tb, planes, p).transpose(0, 2, 1, 3)
+    return words.reshape(g, planes, tb * p), active
+
+
+def _segment_len(active: np.ndarray) -> int:
+    return _round_up(max(int(active.sum(axis=1).max()), 1), SEGMENT_ALIGN)
+
+
+def _stage_instructions(prog: Program, cycles_per_block: int) -> Stream:
+    """Compact the packed instruction words and pre-gather the values.
 
     The program already carries the packed ``[T, planes, P]`` words — the
-    pack happens once at compile time; staging only pads to the cycle-block
-    multiple (pad rows are the all-NOP word 0), gathers the f32 values per
-    instruction slot so the kernel streams them positionally, and flattens
-    both (cycle-major, then plane, then lane) for the kernel's 1-D SMEM
-    buffers.
+    pack happens once at compile time; staging drops the no-op lane slots
+    of each cycle block (pad cycles are all no-op), keeps the lane id of
+    every word it keeps, gathers the f32 values per kept word so the kernel
+    streams them positionally, and pads each block's segment to the common
+    length (see `Stream`).
     """
-    t = prog.cycles
-    t_pad = _round_up(t, cycles_per_block)
-    values = prog.stream[prog.val_idx]          # [T, P] pre-gathered
-    instr = _pad_to(prog.instr, t_pad)          # [T_pad, planes, P]
-    return (instr.reshape(-1),
-            _pad_to(values.astype(np.float32), t_pad).reshape(-1))
+    tb, p, planes = cycles_per_block, prog.num_cus, prog.planes
+    words, active = _active_blocks(prog, tb)
+    g = active.shape[0]
+    values = _pad_to(prog.stream[prog.val_idx].astype(np.float32), g * tb)
+    values = values.reshape(g, tb * p)
+    k = _segment_len(active)
+
+    blk, slot = np.nonzero(active)               # row-major: in block order
+    pos = (np.cumsum(active, axis=1) - 1)[blk, slot]
+    instr = np.zeros((g, planes + 1, k), np.int32)
+    instr[blk, :planes, pos] = words[blk, :, slot]
+    instr[blk, planes, pos] = slot % p           # the word's lane
+    vals = np.zeros((g, k), np.float32)
+    vals[blk, pos] = values[blk, slot]
+    return Stream(instr=instr.reshape(-1), values=vals.reshape(-1),
+                  counts=active.sum(axis=1).astype(np.int32), k=k,
+                  slot_words=g * tb * p)
 
 
 def instr_buffer_bytes(prog: Program, cycles_per_block: int = 128) -> int:
     """SMEM bytes of the kernel's double-buffered instruction streaming.
 
-    Two cycle-block buffers of packed words plus two of pre-gathered f32
-    values: ``2 * tb * P * (4 * planes + 4)`` — halved-plus by the packed
-    single-word encoding (planes=1: 8 B per buffered lane-cycle vs the 24 B
-    of the historical five-plane layout).  A TPU v5e core has 1 MiB of SMEM.
+    Two segment buffers of K entries, each entry its packed words, its lane
+    id and its pre-gathered f32 value: ``2 * K * (4 * planes + 4 + 4)``,
+    where K is the largest cycle block's count of active words rounded up
+    to `kernel.SEGMENT_ALIGN` (1024).  At worst (every lane active in every
+    cycle) K is ``cycles_per_block * P``: 192 KiB for planes=1 and 256 KiB
+    for planes=2 at 128 cycles of 64 lanes.  A TPU v5e core has 1 MiB of
+    SMEM.
     """
-    return 2 * cycles_per_block * prog.num_cus * (4 * prog.planes + 4)
+    _, active = _active_blocks(prog, cycles_per_block)
+    return 2 * _segment_len(active) * (4 * prog.planes + 4 + 4)
 
 
 def state_bytes(prog: Program, nb: int, *, placement: str,
@@ -280,22 +339,25 @@ def build_solver_cols(
     resolves the memory placement, and returns a closure suitable for the
     per-(program, knobs) executor cache (`executor.make_pallas_executor`).
     The chosen regime is exposed as ``closure.placement`` /
-    ``closure.plan``, and the resolved interpreter flag as
-    ``closure.interpret``, for tests and diagnostics.
+    ``closure.plan``, the resolved interpreter flag as
+    ``closure.interpret``, and the compaction as ``closure.stream_words``
+    (entries executed per solve) against ``closure.slot_words`` (the
+    ``T_pad * P`` lane slots of the uncompacted grid), for tests and
+    diagnostics.
     """
     mode, plan = resolve_placement(
         prog, width, placement=placement, vmem_limit_bytes=vmem_limit_bytes,
         cycles_per_block=cycles_per_block, x_block_rows=x_block_rows,
     )
-    instr_np, values_np = _stage_instructions(prog, cycles_per_block)
-    instr = jnp.asarray(instr_np)
-    values = jnp.asarray(values_np)
+    stream = _stage_instructions(prog, cycles_per_block)
+    instr = jnp.asarray(stream.instr)
+    values = jnp.asarray(stream.values)
+    counts = jnp.asarray(stream.counts)
     n = prog.n
     n_slots = _psum_slots(prog)
     n_rows = _resident_rows(prog) if mode == "resident" else plan.n_hbm
     interpret = resolve_interpret(interpret)
-    kw = dict(num_cus=prog.num_cus, planes=prog.planes,
-              cycles_per_block=cycles_per_block, num_slots=n_slots,
+    kw = dict(num_cus=prog.num_cus, planes=prog.planes, num_slots=n_slots,
               interpret=interpret)
 
     @jax.jit  # fold the pad/slice into the kernel dispatch
@@ -303,15 +365,18 @@ def build_solver_cols(
         bp = jnp.zeros((n_rows, width), jnp.float32)
         bp = bp.at[:n].set(jnp.asarray(bmat, jnp.float32))
         if mode == "resident":
-            x = sptrsv_pallas(instr, values, bp, **kw)
+            x = sptrsv_pallas(instr, values, counts, bp, **kw)
         else:
-            x = sptrsv_pallas_blocked(instr, values, bp, window=plan.window,
-                                      stride=plan.stride, **kw)
+            x = sptrsv_pallas_blocked(instr, values, counts, bp,
+                                      window=plan.window, stride=plan.stride,
+                                      **kw)
         return x[:n]
 
     solve_cols.placement = mode
     solve_cols.plan = plan
     solve_cols.interpret = interpret
+    solve_cols.stream_words = stream.stream_words
+    solve_cols.slot_words = stream.slot_words
     return solve_cols
 
 

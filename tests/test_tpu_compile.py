@@ -65,15 +65,15 @@ def progs():
 
 
 def _kernel_args(prog, rows, one_chip):
-    instr, values = ops._stage_instructions(prog, CPB)
+    stream = ops._stage_instructions(prog, CPB)
 
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    kw = dict(num_cus=prog.num_cus, planes=prog.planes, cycles_per_block=CPB,
+    kw = dict(num_cus=prog.num_cus, planes=prog.planes,
               num_slots=executor._psum_slots(prog), interpret=False)
-    return (spec(instr.shape, instr.dtype), spec(values.shape, values.dtype),
-            spec((rows, B), jnp.float32)), kw
+    return (spec(stream.instr), spec(stream.values), spec(stream.counts),
+            spec(np.zeros((rows, B), np.float32))), kw
 
 
 def test_resident_kernel_compiles_for_v5e(progs, one_chip, no_compile_cache):
