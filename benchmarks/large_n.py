@@ -63,7 +63,7 @@ def main() -> None:
             "name": name, "n": mat.n, "nnz": mat.nnz, "batch": BATCH,
             "window": plan.window, "stride": plan.stride,
             "num_blocks": plan.num_blocks,
-            # tiled (8, 128) VMEM bytes of the x+b solve state
+            # tiled (8, 128) VMEM bytes of the solve state
             "resident_state_bytes": sptrsv_ops.state_bytes(
                 prog, BATCH, placement="resident")["xb"],
             "blocked_state_bytes": plan.state_bytes(BATCH),

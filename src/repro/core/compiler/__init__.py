@@ -14,12 +14,18 @@ a thin TriCSR wrapper over this pipeline.
 
 Per-pass wall-clock and metrics are recorded on
 ``program.stats.pass_stats`` (a list of `PassStats`) for observability;
-``compile_seconds`` stays the end-to-end total.
+``compile_seconds`` stays the end-to-end total.  Each pass is also a
+``jax.profiler.TraceAnnotation`` span named ``sptrsv.compile.<pass>`` (the
+names of `PASS_NAMES`), so a profiled set-up splits the compile; the ICR
+reorder runs per cycle inside the schedule pass, so it is one span per
+cycle, nested in ``sptrsv.compile.psum_schedule``.
 """
 
 from __future__ import annotations
 
 import time
+
+from jax.profiler import TraceAnnotation
 
 from ..program import AccelConfig, Program
 from . import assign, elide, emit, partition, sched
@@ -96,23 +102,23 @@ def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
         def _check(diags_fn, stage):
             pass
 
-    def _timed(fn, *args, **kw):
+    def _timed(name, fn, *args, **kw):
         t = time.perf_counter()
-        out = fn(*args, **kw)
+        with TraceAnnotation(f"sptrsv.compile.{name}"):
+            out = fn(*args, **kw)
         return out, time.perf_counter() - t
 
     _check(lambda: contracts.verify_frontend(dag), "frontend")
-    pir, t_part = _timed(partition.run, dag)
+    pir, t_part = _timed("partition", partition.run, dag)
     _check(lambda: contracts.verify_partition(pir), "partition")
-    air, t_assign = _timed(assign.run, pir, cfg)
+    air, t_assign = _timed("cu_assign", assign.run, pir, cfg)
     _check(lambda: contracts.verify_assign(air, cfg), "cu_assign")
     select_stats = None
     if schedule == "auto":
         from . import strategies
 
-        t = time.perf_counter()
-        sir, chosen, costs, run_seconds = strategies.select(air, cfg)
-        t_select = time.perf_counter() - t
+        (sir, chosen, costs, run_seconds), t_select = _timed(
+            "psum_schedule", strategies.select, air, cfg)
         t_sched = run_seconds[chosen]
         sir.stats.schedule_costs = costs
         select_stats = PassStats("strategy_select", t_select - t_sched, {
@@ -121,15 +127,16 @@ def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
             "predicted_cycles": {k: v["cycles"] for k, v in costs.items()},
         })
     elif schedule == "paper":
-        sir, t_sched = _timed(sched.run, air, cfg)
+        sir, t_sched = _timed("psum_schedule", sched.run, air, cfg)
     else:
         from . import strategies
 
-        sir, t_sched = _timed(strategies.get(schedule), air, cfg)
+        sir, t_sched = _timed("psum_schedule", strategies.get(schedule), air,
+                              cfg)
     _check(lambda: contracts.verify_schedule(sir, air, cfg), "psum_schedule")
-    eir, t_elide = _timed(elide.run, sir)
+    eir, t_elide = _timed("stall_elide", elide.run, sir)
     _check(lambda: contracts.verify_emit(eir, sir), "stall_elide")
-    prog, t_emit = _timed(emit.run, eir, cfg, planes=planes)
+    prog, t_emit = _timed("pack_emit", emit.run, eir, cfg, planes=planes)
     _check(lambda: contracts.verify_packed_program(prog, eir, cfg),
            "pack_emit")
 

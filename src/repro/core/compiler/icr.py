@@ -23,6 +23,7 @@ import heapq
 from collections import Counter
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..program import AccelConfig, ScheduleStats
 
@@ -99,7 +100,15 @@ def assign_sources(state: BankSpillState, cfg: AccelConfig,
     bank conflict or a spill reload are demoted to NOPs in place (their
     ``chosen`` entry cleared, ``nop_kind`` set) — the replay happens next
     cycle.  Returns {cu: src} for the surviving edge lanes.
+
+    Each call is one ``sptrsv.compile.icr_reorder`` profiler span, nested in
+    the schedule pass's ``sptrsv.compile.psum_schedule``.
     """
+    with TraceAnnotation("sptrsv.compile.icr_reorder"):
+        return _assign_sources(state, cfg, stats, chosen, nop_kind, cus)
+
+
+def _assign_sources(state, cfg, stats, chosen, nop_kind, cus) -> dict:
     p = len(chosen)
     edge_cus = [c for c in range(p) if chosen[c] and chosen[c][0] == "edge"]
     assigned_src: dict[int, int] = {}

@@ -133,9 +133,11 @@ def from_coo(
     name: str = "unnamed",
 ) -> TriCSR:
     """Build a TriCSR from strictly-lower COO triples plus a diagonal vector."""
-    rows = np.asarray(list(rows), dtype=np.int64)
-    cols = np.asarray(list(cols), dtype=np.int64)
-    vals = np.asarray(list(vals), dtype=np.float64)
+    def array(a, dtype):  # any iterable; arrays without a copy to a list
+        return np.asarray(a if isinstance(a, np.ndarray) else list(a), dtype)
+
+    rows, cols, vals = array(rows, np.int64), array(cols, np.int64), \
+        array(vals, np.float64)
     if np.any(cols >= rows):
         bad = int(np.argmax(cols >= rows))
         _reject(name, f"COO part must be strictly lower triangular "
@@ -153,11 +155,11 @@ def from_coo(
     np.cumsum(counts, out=rowptr[1:])
     colidx = np.empty(rowptr[-1], dtype=np.int64)
     values = np.empty(rowptr[-1], dtype=np.float64)
-    cursor = rowptr[:-1].copy()
-    for r, c, v in zip(rows, cols, vals):
-        colidx[cursor[r]] = c
-        values[cursor[r]] = v
-        cursor[r] += 1
+    # sorted row-major, entry k sits after the k earlier entries and the
+    # diagonals of the rows[k] rows before its own
+    dest = np.arange(len(rows)) + rows
+    colidx[dest] = cols
+    values[dest] = vals
     # diagonal last
     colidx[rowptr[1:] - 1] = np.arange(n)
     values[rowptr[1:] - 1] = np.asarray(diag, dtype=np.float64)

@@ -5,7 +5,8 @@ generate matrices spanning the same *structural archetypes* as the paper's
 Table III (FEM bands, circuit Jacobians, power networks, chemical-process
 chains, near-empty wide DAGs).  Every generator produces a well-conditioned
 lower-triangular system (unit-ish diagonal, bounded off-diagonals) so the
-f32 executor comparison against the f64 oracle stays tight.
+f32 executor comparison against the f64 oracle stays tight.  `stencil27` is
+the exception to "archetype": it is HPCG's own problem, pattern and values.
 """
 
 from __future__ import annotations
@@ -16,7 +17,8 @@ import numpy as np
 
 from .csr import TriCSR, from_coo
 
-__all__ = ["SUITE", "generate", "suite_names", "paper_like_suite"]
+__all__ = ["SUITE", "generate", "suite_names", "paper_like_suite",
+           "stencil27", "stencil27_coo"]
 
 
 def _finish(n, rows, cols, rng, name, scale=0.5) -> TriCSR:
@@ -162,9 +164,45 @@ def heavy_hub(n: int, hub_deg: int, seed: int, name: str) -> TriCSR:
     return _finish(n, rows, cols, rng, name)
 
 
+def stencil27_coo(nx: int):
+    """HPCG's 27-point stencil on an nx³ grid (``GenerateProblem``): rows in
+    natural order, x fastest; diagonal 26, every in-grid neighbour -1.
+
+    Returns the lower triangle as ``(rows, cols, vals, diag)`` — the strictly
+    lower COO part sorted by (row, col), and the diagonal — which is the
+    matrix the forward sweep of HPCG's symmetric Gauss-Seidel
+    (``ComputeSYMGS``) solves with: (D + L) x = r - U x_old.
+    """
+    n = nx ** 3
+    iz, iy, ix = (a.reshape(-1) for a in np.meshgrid(
+        np.arange(nx), np.arange(nx), np.arange(nx), indexing="ij"))
+    row = np.arange(n, dtype=np.int64)
+    rows, cols = [], []
+    for sz in (-1, 0, 1):
+        for sy in (-1, 0, 1):
+            for sx in (-1, 0, 1):
+                off = (sz * nx + sy) * nx + sx
+                if off >= 0:  # the diagonal and the upper triangle
+                    continue
+                ok = ((iz + sz >= 0) & (iz + sz < nx) & (iy + sy >= 0)
+                      & (iy + sy < nx) & (ix + sx >= 0) & (ix + sx < nx))
+                rows.append(row[ok])
+                cols.append(row[ok] + off)
+    rows, cols = np.concatenate(rows), np.concatenate(cols)
+    order = np.lexsort((cols, rows))
+    return (rows[order], cols[order], np.full(rows.size, -1.0),
+            np.full(n, 26.0))
+
+
+def stencil27(nx: int, name: str) -> TriCSR:
+    """tril of HPCG's 27-point operator on an nx³ local grid."""
+    return from_coo(nx ** 3, *stencil27_coo(nx), name=name)
+
+
 # ---------------------------------------------------------------------------
 # Registry.  Sizes bracket the paper's Table III (n = 628 .. 7479) plus larger
-# entries toward the 85k upper end of the 245-matrix sweep.
+# entries toward the 85k upper end of the 245-matrix sweep, and one HPCG
+# grid past it.
 # ---------------------------------------------------------------------------
 SUITE: dict[str, Callable[[], TriCSR]] = {}
 
@@ -215,6 +253,11 @@ def _build_suite() -> None:
     _reg("hub_mid", lambda: heavy_hub(3000, 700, 62, "hub_mid"))
     _reg("hub_wall", lambda: hub_wall(2048, 8, 512, 63, "hub_wall"))
     _reg("hub_wall_big", lambda: hub_wall(6144, 12, 1536, 64, "hub_wall_big"))
+    # HPCG's symmetric Gauss-Seidel forward sweep: a small grid, and the
+    # benchmark's 48³ local grid (HPCG ships 104³; benchmarks/chip/configs/
+    # hpcg_symgs48.json says why 48)
+    _reg("hpcg_8", lambda: stencil27(8, "hpcg_8"))
+    _reg("hpcg_symgs48", lambda: stencil27(48, "hpcg_symgs48"))
 
 
 _build_suite()

@@ -23,18 +23,18 @@ iterations of a loop whose body executes `UNROLL` entries.
 
 Each entry's packed word is read from SMEM and decoded on the scalar unit
 (`program.decode_word`), and its row operands move as single ``[1, B]``
-rows addressed by those scalars — ``x[src]`` / ``b[src]`` loads, the
-``x[src]`` store, and the lane's own feedback and psum-slot rows.  The body
-has no branch: both conditional stores are unconditional stores of a
-select — the psum slot row gets ``feedback`` on STORE_RESET/SWAP and else
-the value just read from it, and ``x[src]`` gets ``(b[src] - psum) * v`` on
-FINAL and else the value just read from it.  Writing back a value read
-earlier in the same sequential order leaves the ref unchanged, so the
-results are bit-identical to the branching form, and the filler entry
-leaves every ref unchanged.  The loop body is one basic block, so the
-scheduler can overlap one entry's SMEM reads and decode with the previous
-entry's row work.  A program whose lanes are all active pays one extra SMEM
-read per entry (its lane id) and gains the branch removal.
+rows addressed by those scalars — the ``x[src]`` load (and, in the blocked
+kernel, ``b[src]``), the ``x[src]`` store, and the lane's own feedback and
+psum-slot rows.  The body has no branch: both conditional stores are
+unconditional stores of a select — the psum slot row gets ``feedback`` on
+STORE_RESET/SWAP and else the value just read from it, and ``x[src]`` gets
+``(b[src] - psum) * v`` on FINAL and else the value just read from it.
+Writing back a value read earlier in the same sequential order leaves the
+ref unchanged, so the results are bit-identical to the branching form, and
+the filler entry leaves every ref unchanged.  The loop body is one basic
+block, so the scheduler can overlap one entry's SMEM reads and decode with
+the previous entry's row work.  A program whose lanes are all active pays
+one extra SMEM read per entry (its lane id) and gains the branch removal.
 
 Every access is a dynamically indexed row of a VMEM ref, which Mosaic
 lowers directly; no vector gather/scatter by 64 independent indices is
@@ -55,8 +55,12 @@ instruction stream solves B right-hand sides.
 
 Two memory-placement regimes for the solve state (DESIGN.md §1):
 
-  * `sptrsv_pallas` — x and b fully VMEM-resident.  Fastest while
-    ``x[n_pad, B]`` + ``b[n_pad, B]`` fit.
+  * `sptrsv_pallas` — x fully VMEM-resident, in one ``[n_pad, B]`` buffer
+    that starts as b: b arrives in HBM and one DMA copies it into the x
+    buffer.  Row i's b is read only by row i's FINAL, before x[i] is
+    written, and no EDGE reads row i before that FINAL (the schedule's
+    guarantee, checked by `core/analysis/hazards.py`), so FINAL reads b[i]
+    from the x row it has just loaded.  Fastest while ``x[n_pad, B]`` fits.
   * `sptrsv_pallas_blocked` — x and b stay HBM-resident (`pl.ANY`); the
     kernel owns a row-blocked VMEM *window* of `window` solution rows that
     slides forward by a fixed `stride` rows per cycle block.  At each block
@@ -134,8 +138,9 @@ def _lane_state_bytes(p: int, num_slots: int, nb: int) -> int:
 
 
 def resident_state_bytes(n_pad: int, nb: int, p: int, num_slots: int) -> int:
-    """VMEM bytes of the resident kernel: x and b + lane state."""
-    return 2 * tiled_bytes(n_pad, nb) + _lane_state_bytes(p, num_slots, nb)
+    """VMEM bytes of the resident kernel: the x buffer (b in place) + lane
+    state."""
+    return tiled_bytes(n_pad, nb) + _lane_state_bytes(p, num_slots, nb)
 
 
 def blocked_state_bytes(window: int, nb: int, p: int, num_slots: int) -> int:
@@ -149,9 +154,11 @@ def _run_block(ibuf, vbuf, slot, count, x_ref, b_ref, fb_ref, rf_ref, *,
 
     ``x_ref``/``b_ref`` hold solution/RHS rows ``[base, base + rows)`` (the
     whole padded vector with ``base=0`` in the VMEM-resident kernel, the
-    sliding window in the blocked one).  ``ibuf`` is the flat SMEM buffer
-    ``[2 * (planes + 1) * k]`` of packed words with the lane ids as a last
-    plane, ``vbuf`` the flat SMEM value buffer ``[2 * k]``.  Entries past
+    sliding window in the blocked one).  ``b_ref=None`` means b is in
+    ``x_ref`` (the resident kernel): FINAL reads it from the row it loads.
+    ``ibuf`` is the flat SMEM buffer ``[2 * (planes + 1) * k]`` of packed
+    words with the lane ids as a last plane, ``vbuf`` the flat SMEM value
+    buffer ``[2 * k]``.  Entries past
     ``count`` up to the next `UNROLL` multiple are filler.
     """
 
@@ -175,9 +182,10 @@ def _run_block(ibuf, vbuf, slot, count, x_ref, b_ref, fb_ref, rf_ref, *,
 
         row = jnp.clip(src - base, 0, rows - 1)
         x_row = x_ref[pl.ds(row, 1), :]
+        b_row = x_row if b_ref is None else b_ref[pl.ds(row, 1), :]
         pv = jnp.where(op == OP_EDGE, pv + v * x_row, pv)
         x_ref[pl.ds(row, 1), :] = jnp.where(
-            op == OP_FINAL, (b_ref[pl.ds(row, 1), :] - pv) * v, x_row)
+            op == OP_FINAL, (b_row - pv) * v, x_row)
         fb_ref[pl.ds(lane, 1), :] = pv
 
     def step(i, carry):
@@ -234,11 +242,11 @@ def _kernel(
     instr_ref,  # [G * (planes + 1) * K] int32, HBM (streamed by DMA)
     val_ref,    # [G * K]                f32,   HBM (pre-gathered values)
     cnt_ref,    # [G]                    int32, SMEM (entries per block)
-    b_ref,      # [n_pad, B]             f32,   VMEM — loaded once per solve
+    b_hbm_ref,  # [n_pad, B]             f32,   HBM — copied into x once
     # outputs
-    x_ref,      # [n_pad, B]             f32,   VMEM
+    x_ref,      # [n_pad, B]             f32,   VMEM (starts as b)
     # scratch
-    ibuf, vbuf, fb_ref, rf_ref, isem, vsem,
+    ibuf, vbuf, fb_ref, rf_ref, isem, vsem, bsem,
     *,
     k: int,
     num_blocks: int,
@@ -247,13 +255,14 @@ def _kernel(
 ):
     instr_dma, val_dma = _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem,
                                       vsem, k=k, planes=planes)
-    x_ref[...] = jnp.zeros(x_ref.shape, jnp.float32)
-    fb_ref[...] = jnp.zeros(fb_ref.shape, jnp.float32)
-    rf_ref[...] = jnp.zeros(rf_ref.shape, jnp.float32)
-
-    # warm-up: block 0 in flight before the block loop starts
+    # warm-up: b into the x buffer, block 0 in flight before the block loop
+    b_dma = pltpu.make_async_copy(b_hbm_ref, x_ref, bsem)
+    b_dma.start()
     instr_dma(0, 0).start()
     val_dma(0, 0).start()
+    fb_ref[...] = jnp.zeros(fb_ref.shape, jnp.float32)
+    rf_ref[...] = jnp.zeros(rf_ref.shape, jnp.float32)
+    b_dma.wait()
 
     def run_block(g, carry):
         slot = jax.lax.rem(g, 2)
@@ -266,7 +275,7 @@ def _kernel(
 
         instr_dma(slot, g).wait()
         val_dma(slot, g).wait()
-        _run_block(ibuf, vbuf, slot, cnt_ref[g], x_ref, b_ref, fb_ref, rf_ref,
+        _run_block(ibuf, vbuf, slot, cnt_ref[g], x_ref, None, fb_ref, rf_ref,
                    base=0, rows=x_ref.shape[0], k=k, planes=planes,
                    num_slots=num_slots)
         return carry
@@ -311,11 +320,13 @@ def sptrsv_pallas(
             pl.BlockSpec(memory_space=pltpu.HBM),   # instr stays in HBM
             pl.BlockSpec(memory_space=pltpu.HBM),   # values stay in HBM
             pl.BlockSpec(memory_space=pltpu.SMEM),  # per-block counts
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # b loaded once
+            pl.BlockSpec(memory_space=pltpu.HBM),   # b, DMA'd into x
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, nb), jnp.float32),
-        scratch_shapes=_stream_scratch(k, p, planes, num_slots, nb),
+        scratch_shapes=_stream_scratch(k, p, planes, num_slots, nb) + [
+            pltpu.SemaphoreType.DMA,                # bsem
+        ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(state)),
         interpret=interpret,
     )(instr, values, counts, b)

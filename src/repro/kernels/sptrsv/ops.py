@@ -2,16 +2,17 @@
 
 Two memory placements for the solve state (DESIGN.md §1):
 
-  * ``resident`` — x and b live in VMEM for the whole solve
-    (`kernel.sptrsv_pallas`); fastest while both fit.
+  * ``resident`` — x lives in VMEM for the whole solve, in one buffer that
+    b is copied into (`kernel.sptrsv_pallas`); fastest while it fits.
   * ``blocked``  — x and b stay in HBM and the kernel slides a row-blocked
     VMEM window over them (`kernel.sptrsv_pallas_blocked`), flushing and
     shifting it at cycle-block boundaries with async DMA.  This is the
     large-n path: VMEM use is bounded by the window, not by n.
 
 ``placement="auto"`` (the default) picks per solve: resident while the
-x+b footprint is under ``vmem_limit_bytes``, blocked beyond it whenever the
-program's row-access envelope admits a sliding window (`plan_window`).
+resident x footprint is under ``vmem_limit_bytes``, blocked beyond it
+whenever the program's row-access envelope admits a sliding window
+(`plan_window`) whose x and b windows take less.
 Footprints are counted as the chip lays them out: a ``[rows, B]`` f32 array
 takes whole (8, 128) tiles (`kernel.tiled_bytes`), so at B=16 it occupies
 8x its logical bytes.
@@ -65,10 +66,11 @@ __all__ = [
     "DEFAULT_STATE_BYTES",
 ]
 
-# auto-placement threshold for the tiled VMEM x+b solve-state footprint.
+# auto-placement threshold for the tiled VMEM solve-state footprint.
 # A TPU v5e kernel gets 16 MiB of scoped VMEM by default, shared with the
-# feedback/psum lane state and Mosaic's own scratch; 4 MiB of x+b keeps the
-# resident kernel inside it.  Overridable per call (``vmem_limit_bytes``).
+# feedback/psum lane state and Mosaic's own scratch; 4 MiB of solve state
+# keeps the resident kernel inside it.  Overridable per call
+# (``vmem_limit_bytes``).
 DEFAULT_STATE_BYTES = 4 << 20
 
 _ROW_ALIGN = 8  # window/stride row granularity (f32 sublane tile)
@@ -169,7 +171,7 @@ def resolve_placement(
 
     ``placement`` forces a regime (``"blocked"`` raises if the program's
     row envelope admits no window); ``"auto"`` compares the VMEM-resident
-    x+b footprint for ``nb`` RHS columns against ``vmem_limit_bytes``
+    x footprint for ``nb`` RHS columns against ``vmem_limit_bytes``
     (``None`` -> `DEFAULT_STATE_BYTES`) and only goes blocked when that
     saves memory and a window exists.  ``x_block_rows`` floors the planned
     window (perf knob; the planner still enlarges it to whatever the
@@ -191,7 +193,7 @@ def resolve_placement(
                 f"row-blocked placement infeasible: {plan.reason}",
                 detail={"reason": plan.reason})
         return "blocked", plan
-    resident_bytes = 2 * tiled_bytes(_resident_rows(prog), nb)
+    resident_bytes = tiled_bytes(_resident_rows(prog), nb)
     if resident_bytes <= vmem_limit_bytes or not plan.feasible:
         return "resident", None
     if plan.state_bytes(nb) >= resident_bytes:
@@ -304,8 +306,10 @@ def state_bytes(prog: Program, nb: int, *, placement: str,
 
     Returns ``{"xb", "vmem", "instr"}`` for ``nb`` RHS columns under
     ``placement`` (``"blocked"`` needs the `WindowPlan`): ``xb`` is the
-    x+b solve state, ``vmem`` all of the kernel's VMEM (x+b plus the
-    feedback/psum lane state), ``instr`` the SMEM instruction buffers.
+    solve state (resident: the one x buffer b is copied into; blocked: the
+    x and b windows), ``vmem`` all of the kernel's VMEM (the solve state
+    plus the feedback/psum lane state), ``instr`` the SMEM instruction
+    buffers.
     """
     slots = _psum_slots(prog)
     if placement == "blocked":
@@ -315,7 +319,7 @@ def state_bytes(prog: Program, nb: int, *, placement: str,
         vmem = blocked_state_bytes(plan.window, nb, prog.num_cus, slots)
     elif placement == "resident":
         rows = _resident_rows(prog)
-        xb = 2 * tiled_bytes(rows, nb)
+        xb = tiled_bytes(rows, nb)
         vmem = resident_state_bytes(rows, nb, prog.num_cus, slots)
     else:
         raise ValueError(f"unknown placement {placement!r}")

@@ -14,6 +14,7 @@ from repro.core import api
 from repro.core.csr import random_rhs, serial_solve
 from repro.core.matrices import generate
 from repro.kernels.sptrsv import ops
+from repro.kernels.sptrsv.kernel import tiled_bytes
 
 
 def _refs(mat, bmat):
@@ -59,7 +60,8 @@ def test_blocked_past_vmem_threshold():
     mat = generate("band_cz")
     prog = api.compile(mat)
     nb = 8
-    limit = 2 * (mat.n + 1) * nb * 4 - 1  # just below the x+b footprint
+    # just below the resident footprint: one tiled x buffer, b copied in
+    limit = tiled_bytes(ops._resident_rows(prog), nb) - 1
     mode, plan = ops.resolve_placement(prog, nb, vmem_limit_bytes=limit,
                                        cycles_per_block=64)
     assert mode == "blocked" and plan.feasible

@@ -118,7 +118,8 @@ def test_instr_buffer_bytes_counts_compacted_segments():
 @pytest.mark.parametrize("placement", ["resident", "blocked"])
 @pytest.mark.parametrize("planes", [1, 2])
 @pytest.mark.parametrize("batch", [1, 16])
-@pytest.mark.parametrize("name", ["band_cz", "ckt_rajat04", "hub_small"])
+@pytest.mark.parametrize("name", ["band_cz", "ckt_rajat04", "hub_small",
+                                  "hpcg_8"])
 def test_kernel_bit_identical_to_scan(name, batch, planes, placement):
     prog = _prog(name, planes)
     assert prog.planes == planes
@@ -136,6 +137,7 @@ def test_kernel_bit_identical_to_scan(name, batch, planes, placement):
 
 @pytest.mark.parametrize("name,placement", [
     ("ckt_rajat04", "resident"), ("chem_bp", "resident"), ("chem_bp", "blocked"),
+    ("hpcg_8", "resident"),
 ])
 def test_psum_starved_program_bit_identical(name, placement):
     """Two psum words per lane force slot spills: SWAP, STORE_RESET and LOAD

@@ -70,3 +70,35 @@ def test_solve_batch_spans(tmp_path, path):
         for a, b in zip(inner, inner[1:]):
             assert a[1] <= b[0]
     assert nested == len(spans) - len(roots)  # nothing outside a call
+
+
+def test_compile_pass_spans(tmp_path):
+    """One compile records one ``sptrsv.compile.<pass>`` span per pass of
+    `compiler.PASS_NAMES`, in that order; the ICR reorder runs once per
+    hardware cycle inside the schedule pass, so its spans, one per cycle,
+    all lie inside ``sptrsv.compile.psum_schedule``."""
+    from repro.core import compiler
+
+    mat = generate("hpcg_8")
+    popts = jax.profiler.ProfileOptions()
+    popts.host_tracer_level = 1
+    popts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=popts)
+    try:
+        prog = api.compile(mat)
+    finally:
+        jax.profiler.stop_trace()
+    spans = [s for s in _program_spans(tmp_path)
+             if s[2].startswith("sptrsv.compile.")]
+    names = [s[2].removeprefix("sptrsv.compile.") for s in spans]
+    assert list(dict.fromkeys(names)) == list(compiler.PASS_NAMES)
+    icr = [s for s in spans if s[2] == "sptrsv.compile.icr_reorder"]
+    passes = [s for s in spans if s not in icr]
+    assert [s[2] for s in passes] == [f"sptrsv.compile.{p}"
+                                      for p in compiler.PASS_NAMES
+                                      if p != "icr_reorder"]
+    for a, b in zip(passes, passes[1:]):
+        assert a[1] <= b[0]
+    lo, hi, _ = passes[compiler.PASS_NAMES.index("psum_schedule")]
+    assert len(icr) == prog.stats.cycles
+    assert all(lo <= s[0] and s[1] <= hi for s in icr)

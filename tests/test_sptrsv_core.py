@@ -24,6 +24,39 @@ def test_csr_validation_and_serial_solve():
     assert np.allclose(x, [1.0, 3.0, 6.0, 11.0])
 
 
+def _from_coo_loop(n, rows, cols, vals, diag):
+    """The per-entry loop `from_coo` placed entries with before it was
+    vectorised: duplicates keep the last, each row's off-diagonals by
+    ascending column, the diagonal last."""
+    last = {}
+    for r, c, v in zip(rows, cols, vals):
+        last[(r, c)] = v
+    rowptr, colidx, values = [0], [], []
+    for i in range(n):
+        for c in sorted(c for r, c in last if r == i):
+            colidx.append(c)
+            values.append(last[(i, c)])
+        colidx.append(i)
+        values.append(diag[i])
+        rowptr.append(len(colidx))
+    return rowptr, colidx, values
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_from_coo_matches_entry_loop(seed):
+    rng = np.random.default_rng(seed)
+    n = 40
+    rows = rng.integers(1, n, 300)  # with duplicates, in no order
+    cols = (rng.random(300) * rows).astype(np.int64)
+    vals = rng.standard_normal(300)
+    diag = rng.uniform(1.0, 2.0, n)
+    mat = from_coo(n, rows.tolist(), cols, vals, diag)
+    want = _from_coo_loop(n, rows.tolist(), cols.tolist(), vals.tolist(),
+                          diag)
+    for got, ref in zip((mat.rowptr, mat.colidx, mat.values), want):
+        np.testing.assert_array_equal(got, np.asarray(ref))
+
+
 def test_levels_match_longest_path():
     mat = generate("chain_1k")
     lv = compute_levels(mat)

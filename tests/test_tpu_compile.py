@@ -64,7 +64,7 @@ def progs():
             for name in ("ckt_add20", "band_huge64k")}
 
 
-def _kernel_args(prog, rows, one_chip):
+def _kernel_args(prog, rows, one_chip, batch=B):
     stream = ops._stage_instructions(prog, CPB)
 
     def spec(a):
@@ -73,7 +73,7 @@ def _kernel_args(prog, rows, one_chip):
     kw = dict(num_cus=prog.num_cus, planes=prog.planes,
               num_slots=executor._psum_slots(prog), interpret=False)
     return (spec(stream.instr), spec(stream.values), spec(stream.counts),
-            spec(np.zeros((rows, B), np.float32))), kw
+            spec(np.zeros((rows, batch), np.float32))), kw
 
 
 def test_resident_kernel_compiles_for_v5e(progs, one_chip, no_compile_cache):
@@ -85,6 +85,24 @@ def test_resident_kernel_compiles_for_v5e(progs, one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
     acct = ops.state_bytes(prog, B, placement="resident")
     assert acct["vmem"] <= kernel.vmem_limit(acct["vmem"]) <= V5E_VMEM
+
+
+def test_resident_kernel_compiles_at_hpcg_rows(progs, one_chip,
+                                               no_compile_cache):
+    """The resident kernel at the benchmark's HPCG grid (48³ = 110,592 rows)
+    and one column: one VMEM x buffer, b copied into it.  The stream is
+    ckt_add20's; the VMEM the kernel holds depends only on n_pad and B."""
+    prog = progs["ckt_add20"]
+    rows = 48 ** 3
+    args, kw = _kernel_args(prog, rows, one_chip, batch=1)
+    compiled = kernel.sptrsv_pallas.lower(*args, **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    state = kernel.resident_state_bytes(rows, 1, prog.num_cus,
+                                        kw["num_slots"])
+    assert state == kernel.tiled_bytes(rows, 1) + kernel.resident_state_bytes(
+        0, 1, prog.num_cus, kw["num_slots"])
+    assert state <= 60 * 10 ** 6
+    assert state < kernel.vmem_limit(state) <= V5E_VMEM
 
 
 def test_blocked_kernel_compiles_for_v5e(progs, one_chip, no_compile_cache):
