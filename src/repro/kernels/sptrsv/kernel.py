@@ -14,27 +14,38 @@ another on the scalar unit, so on the TPU a program is simply its *active*
 lane-words in cycle-major, lane-minor order; the cycle x lane grid is a
 hardware notion the kernel does not need.  The wrapper
 (`ops._stage_instructions`) keeps, per cycle block, only the words that do
-something (op not NOP, or psum control not KEEP) with their pre-gathered
-values and their lane ids (one more int32 plane beside the word planes),
-and pads every block's segment to one common length K with a filler entry
-(word 0 = NOP/KEEP, lane 0, value 0).  A per-block count table in SMEM says
-how many entries block g holds; the kernel runs ``ceil(count_g / UNROLL)``
+something (op not NOP, or psum control not KEEP), pads every block's
+segment to one common length K, and stages each entry pre-decoded: its
+pre-gathered value and `STREAM_PLANES` int32 planes of row indices and one
+flag (`ROW` .. `EDGE` below).  A per-block count table in SMEM says how
+many entries block g holds; the kernel runs ``ceil(count_g / UNROLL)``
 iterations of a loop whose body executes `UNROLL` entries.
 
-Each entry's packed word is read from SMEM and decoded on the scalar unit
-(`program.decode_word`), and its row operands move as single ``[1, B]``
-rows addressed by those scalars — the ``x[src]`` load (and, in the blocked
-kernel, ``b[src]``), the ``x[src]`` store, and the lane's own feedback and
-psum-slot rows.  The body has no branch: both conditional stores are
-unconditional stores of a select — the psum slot row gets ``feedback`` on
-STORE_RESET/SWAP and else the value just read from it, and ``x[src]`` gets
-``(b[src] - psum) * v`` on FINAL and else the value just read from it.
-Writing back a value read earlier in the same sequential order leaves the
-ref unchanged, so the results are bit-identical to the branching form, and
-the filler entry leaves every ref unchanged.  The loop body is one basic
-block, so the scheduler can overlap one entry's SMEM reads and decode with
-the previous entry's row work.  A program whose lanes are all active pays
-one extra SMEM read per entry (its lane id) and gains the branch removal.
+Address-selected rows.  The scalar unit sets the pace: one word costs the
+same at B=1 and B=16, and decoding the packed word and turning its fields
+into select masks took most of the scalar work of an entry.  So the
+wrapper resolves every choice the psum control and the op make into the
+row an access uses, and the kernel only loads, computes and stores:
+
+  * the lane state is one VMEM ref ``ls`` of `lane_rows` rows: the
+    feedback rows (one per lane), the psum register file (``num_slots``
+    rows per lane), a zero row and a trash row;
+  * the running sum starts from the row ``PV_SRC`` names: the lane's
+    feedback row (KEEP), the zero row (RESET, STORE_RESET) or a psum slot
+    (LOAD, SWAP);
+  * feedback is parked into the row ``PARK`` names: the psum slot on
+    STORE_RESET/SWAP, else the trash row;
+  * ``(b[src] - psum) * v`` is stored to the x row ``X_DST`` names: the
+    FINAL's own row, else the x ref's top row, which no word loads
+    (`ops._resident_rows` and `ops.plan_window` reserve it);
+  * the one select left is the MAC: ``psum + v * x[src]`` where ``EDGE``
+    is set.
+
+Per lane the arithmetic and its order are those of the `lax.scan`
+executor, so the results are bit-identical to it; the filler entry (the
+zero row as its source, the trash rows as its destinations) changes no row
+that is read.  The loop body is one basic block, so the scheduler can
+overlap one entry's SMEM reads with the previous entry's row work.
 
 Every access is a dynamically indexed row of a VMEM ref, which Mosaic
 lowers directly; no vector gather/scatter by 64 independent indices is
@@ -50,8 +61,8 @@ block g+1 into the other (`pltpu.make_async_copy` + per-slot DMA
 semaphores), so instruction HBM traffic overlaps compute.
 
 Multi-RHS batching: every row carries a trailing batch axis (``x[n_pad,
-B]``, ``feedback[P, B]``, ``rf[P * S, B]``), so one pass over the
-instruction stream solves B right-hand sides.
+B]``, ``ls[lane_rows, B]``), so one pass over the instruction stream
+solves B right-hand sides.
 
 Two memory-placement regimes for the solve state (DESIGN.md §1):
 
@@ -74,8 +85,10 @@ Two memory-placement regimes for the solve state (DESIGN.md §1):
 The feasibility conditions (every block's touched-row envelope inside its
 window; see `ops.plan_window`) are checked by the wrapper against the
 compiler-emitted per-cycle row ranges (`Program.row_lo/row_hi`).  The
-kernel still clamps every row index into its ref, so a corrupt program
-cannot address VMEM outside the solve state.
+wrapper stages every x row relative to the ref it addresses (the blocked
+kernel's rows relative to their block's window) and checks it lies in that
+ref, so a corrupt program cannot address VMEM outside the solve state; the
+kernel uses the staged rows as they are.
 """
 
 from __future__ import annotations
@@ -87,23 +100,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.program import (
-    OP_EDGE,
-    OP_FINAL,
-    PS_LOAD,
-    PS_RESET,
-    PS_STORE_RESET,
-    PS_SWAP,
-    decode_word,
-)
 from repro.kernels.common import resolve_interpret
 
 __all__ = [
     "SEGMENT_ALIGN",
+    "STREAM_PLANES",
     "UNROLL",
     "sptrsv_pallas",
     "sptrsv_pallas_blocked",
     "blocked_state_bytes",
+    "lane_rows",
     "resident_state_bytes",
     "tiled_bytes",
     "vmem_limit",
@@ -120,6 +126,12 @@ UNROLL = 8
 # Segment length granularity: Mosaic slices a 1-D HBM array only in whole
 # (1024,) tiles, and a multiple of it is a multiple of UNROLL.
 SEGMENT_ALIGN = 1024
+# The int32 planes of a staged entry: the x row it loads, the lane-state rows
+# its running sum starts from, its feedback lives in and feedback is parked
+# in, the x row it stores, and whether it is an EDGE (see the module
+# docstring).
+ROW, PV_SRC, FB, PARK, X_DST, EDGE = range(6)
+STREAM_PLANES = 6
 
 
 def tiled_bytes(rows: int, cols: int) -> int:
@@ -132,9 +144,15 @@ def vmem_limit(state_bytes: int) -> int:
     return max(_SCOPED_VMEM_DEFAULT, state_bytes + _VMEM_HEADROOM)
 
 
+def lane_rows(p: int, num_slots: int) -> int:
+    """Rows of the lane state: ``p`` feedback rows, ``p * num_slots`` psum
+    slot rows, the zero row and the trash row, in that order."""
+    return p * (num_slots + 1) + 2
+
+
 def _lane_state_bytes(p: int, num_slots: int, nb: int) -> int:
-    """VMEM bytes of the per-lane feedback rows and psum register file."""
-    return tiled_bytes(p, nb) + tiled_bytes(p * num_slots, nb)
+    """VMEM bytes of the lane state (`lane_rows`)."""
+    return tiled_bytes(lane_rows(p, num_slots), nb)
 
 
 def resident_state_bytes(n_pad: int, nb: int, p: int, num_slots: int) -> int:
@@ -148,45 +166,35 @@ def blocked_state_bytes(window: int, nb: int, p: int, num_slots: int) -> int:
     return 4 * tiled_bytes(window, nb) + _lane_state_bytes(p, num_slots, nb)
 
 
-def _run_block(ibuf, vbuf, slot, count, x_ref, b_ref, fb_ref, rf_ref, *,
-               base, rows, k, planes, num_slots):
+def _run_block(ibuf, vbuf, slot, count, x_ref, b_ref, ls_ref, *, k):
     """Execute the ``count`` entries of the cycle block in buffer ``slot``.
 
-    ``x_ref``/``b_ref`` hold solution/RHS rows ``[base, base + rows)`` (the
-    whole padded vector with ``base=0`` in the VMEM-resident kernel, the
-    sliding window in the blocked one).  ``b_ref=None`` means b is in
-    ``x_ref`` (the resident kernel): FINAL reads it from the row it loads.
-    ``ibuf`` is the flat SMEM buffer ``[2 * (planes + 1) * k]`` of packed
-    words with the lane ids as a last plane, ``vbuf`` the flat SMEM value
-    buffer ``[2 * k]``.  Entries past
-    ``count`` up to the next `UNROLL` multiple are filler.
+    ``x_ref``/``b_ref`` hold the solution/RHS rows the block's staged rows
+    index (the whole padded vector in the VMEM-resident kernel, the block's
+    window in the blocked one); the top one is spare.  ``b_ref=None`` means
+    b is in ``x_ref`` (the resident kernel): FINAL reads it from the row it
+    loads.  ``ls_ref`` is the lane state (`lane_rows`).  ``ibuf`` is
+    the flat SMEM buffer ``[2 * STREAM_PLANES * k]`` of staged planes,
+    ``vbuf`` the flat SMEM value buffer ``[2 * k]``.  Entries past ``count``
+    up to the next `UNROLL` multiple are filler.
     """
 
     def entry(e):
-        w = slot * (planes + 1) * k + e
-        op, src, ct, sl = decode_word(
-            ibuf[w], ibuf[w + k] if planes == 2 else None)
-        lane = ibuf[w + planes * k]
-        v = vbuf[slot * k + e]
-        fb = fb_ref[pl.ds(lane, 1), :]
-        r = lane * num_slots + jnp.minimum(sl, num_slots - 1)
-        slot_val = rf_ref[pl.ds(r, 1), :]
-        # psum control mux (S1/S2 of Fig. 4b): the running sum continues
-        # from feedback (KEEP), restarts at 0 (RESET, STORE_RESET) or
-        # resumes a parked slot (LOAD, SWAP); STORE_RESET/SWAP park feedback
-        from_slot = (ct == PS_LOAD) | (ct == PS_SWAP)
-        zeroed = (ct == PS_RESET) | (ct == PS_STORE_RESET)
-        pv = jnp.where(from_slot, slot_val, jnp.where(zeroed, 0.0, fb))
-        parks = (ct == PS_STORE_RESET) | (ct == PS_SWAP)
-        rf_ref[pl.ds(r, 1), :] = jnp.where(parks, fb, slot_val)
+        def plane(j):
+            return ibuf[(slot * STREAM_PLANES + j) * k + e]
 
-        row = jnp.clip(src - base, 0, rows - 1)
+        # the psum control mux (S1/S2 of Fig. 4b) is resolved into PV_SRC
+        # and PARK by staging
+        row, fb_row = plane(ROW), plane(FB)
+        v = vbuf[slot * k + e]
         x_row = x_ref[pl.ds(row, 1), :]
         b_row = x_row if b_ref is None else b_ref[pl.ds(row, 1), :]
-        pv = jnp.where(op == OP_EDGE, pv + v * x_row, pv)
-        x_ref[pl.ds(row, 1), :] = jnp.where(
-            op == OP_FINAL, (b_row - pv) * v, x_row)
-        fb_ref[pl.ds(lane, 1), :] = pv
+        pv = ls_ref[pl.ds(plane(PV_SRC), 1), :]
+        fb = ls_ref[pl.ds(fb_row, 1), :]
+        ls_ref[pl.ds(plane(PARK), 1), :] = fb
+        pv = jnp.where(plane(EDGE) != 0, pv + v * x_row, pv)
+        x_ref[pl.ds(plane(X_DST), 1), :] = (b_row - pv) * v
+        ls_ref[pl.ds(fb_row, 1), :] = pv
 
     def step(i, carry):
         for u in range(UNROLL):
@@ -196,9 +204,9 @@ def _run_block(ibuf, vbuf, slot, count, x_ref, b_ref, fb_ref, rf_ref, *,
     jax.lax.fori_loop(0, (count + UNROLL - 1) // UNROLL, step, 0)
 
 
-def _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem, vsem, *, k, planes):
+def _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem, vsem, *, k):
     """(instr_dma, val_dma) constructors for cycle block g into buffer slot."""
-    wblk = (planes + 1) * k
+    wblk = STREAM_PLANES * k
 
     def instr_dma(slot, g):
         return pltpu.make_async_copy(
@@ -213,55 +221,50 @@ def _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem, vsem, *, k, planes):
     return instr_dma, val_dma
 
 
-def _stream_scratch(k, p, planes, num_slots, nb):
+def _stream_scratch(k, p, num_slots, nb):
     """Scratch shared by both kernels: SMEM stream buffers + lane state."""
     return [
-        pltpu.SMEM((2 * (planes + 1) * k,), jnp.int32),  # ibuf
-        pltpu.SMEM((2 * k,), jnp.float32),               # vbuf
-        pltpu.VMEM((p, nb), jnp.float32),                # feedback
-        pltpu.VMEM((p * num_slots, nb), jnp.float32),    # psum register file
-        pltpu.SemaphoreType.DMA((2,)),                   # isem
-        pltpu.SemaphoreType.DMA((2,)),                   # vsem
+        pltpu.SMEM((2 * STREAM_PLANES * k,), jnp.int32),    # ibuf
+        pltpu.SMEM((2 * k,), jnp.float32),                  # vbuf
+        pltpu.VMEM((lane_rows(p, num_slots), nb), jnp.float32),  # ls
+        pltpu.SemaphoreType.DMA((2,)),                      # isem
+        pltpu.SemaphoreType.DMA((2,)),                      # vsem
     ]
 
 
-def _stream_shape(instr, values, counts, planes):
+def _stream_shape(instr, values, counts):
     """(num_blocks, k) of a staged stream; checks the three agree."""
-    assert planes in (1, 2), f"expected packed 1- or 2-plane words, got {planes}"
     num_blocks = counts.shape[0]
     k = values.shape[0] // num_blocks
     assert values.shape[0] == num_blocks * k and k % SEGMENT_ALIGN == 0, \
         "pad every block's segment to one SEGMENT_ALIGN multiple first"
-    assert instr.shape[0] == num_blocks * (planes + 1) * k, \
+    assert instr.shape[0] == num_blocks * STREAM_PLANES * k, \
         "instr/values stream mismatch"
     return num_blocks, k
 
 
 def _kernel(
     # inputs
-    instr_ref,  # [G * (planes + 1) * K] int32, HBM (streamed by DMA)
+    instr_ref,  # [G * STREAM_PLANES * K] int32, HBM (streamed by DMA)
     val_ref,    # [G * K]                f32,   HBM (pre-gathered values)
     cnt_ref,    # [G]                    int32, SMEM (entries per block)
     b_hbm_ref,  # [n_pad, B]             f32,   HBM — copied into x once
     # outputs
     x_ref,      # [n_pad, B]             f32,   VMEM (starts as b)
     # scratch
-    ibuf, vbuf, fb_ref, rf_ref, isem, vsem, bsem,
+    ibuf, vbuf, ls_ref, isem, vsem, bsem,
     *,
     k: int,
     num_blocks: int,
-    num_slots: int,
-    planes: int,
 ):
     instr_dma, val_dma = _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem,
-                                      vsem, k=k, planes=planes)
+                                      vsem, k=k)
     # warm-up: b into the x buffer, block 0 in flight before the block loop
     b_dma = pltpu.make_async_copy(b_hbm_ref, x_ref, bsem)
     b_dma.start()
     instr_dma(0, 0).start()
     val_dma(0, 0).start()
-    fb_ref[...] = jnp.zeros(fb_ref.shape, jnp.float32)
-    rf_ref[...] = jnp.zeros(rf_ref.shape, jnp.float32)
+    ls_ref[...] = jnp.zeros(ls_ref.shape, jnp.float32)
     b_dma.wait()
 
     def run_block(g, carry):
@@ -275,9 +278,7 @@ def _kernel(
 
         instr_dma(slot, g).wait()
         val_dma(slot, g).wait()
-        _run_block(ibuf, vbuf, slot, cnt_ref[g], x_ref, None, fb_ref, rf_ref,
-                   base=0, rows=x_ref.shape[0], k=k, planes=planes,
-                   num_slots=num_slots)
+        _run_block(ibuf, vbuf, slot, cnt_ref[g], x_ref, None, ls_ref, k=k)
         return carry
 
     jax.lax.fori_loop(0, num_blocks, run_block, 0)
@@ -285,35 +286,28 @@ def _kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_cus", "planes", "num_slots", "interpret"),
+    static_argnames=("num_cus", "num_slots", "interpret"),
 )
 def sptrsv_pallas(
-    instr: jnp.ndarray,    # [G * (planes + 1) * K] int32 (words + lane ids)
+    instr: jnp.ndarray,    # [G * STREAM_PLANES * K] int32 (staged planes)
     values: jnp.ndarray,   # [G * K] f32 (pre-gathered stream values)
     counts: jnp.ndarray,   # [G] int32 (active entries per cycle block)
-    b: jnp.ndarray,        # [n_pad, B] f32
+    b: jnp.ndarray,        # [n_pad, B] f32, row n_pad - 1 spare
     *,
     num_cus: int,
-    planes: int,
     num_slots: int = 12,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """VMEM-resident solve; ``instr``/``values``/``counts`` are the compacted
-    stream of `ops._stage_instructions` (per cycle block: plane-major words,
-    lane ids, values)."""
+    stream of `ops._stage_instructions` (per cycle block: the planes, then
+    the values)."""
     interpret = resolve_interpret(interpret)
     p = num_cus
-    num_blocks, k = _stream_shape(instr, values, counts, planes)
+    num_blocks, k = _stream_shape(instr, values, counts)
     n_pad, nb = b.shape
     state = resident_state_bytes(n_pad, nb, p, num_slots)
 
-    kernel = functools.partial(
-        _kernel,
-        k=k,
-        num_blocks=num_blocks,
-        num_slots=num_slots,
-        planes=planes,
-    )
+    kernel = functools.partial(_kernel, k=k, num_blocks=num_blocks)
     return pl.pallas_call(
         kernel,
         in_specs=[
@@ -324,7 +318,7 @@ def sptrsv_pallas(
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, nb), jnp.float32),
-        scratch_shapes=_stream_scratch(k, p, planes, num_slots, nb) + [
+        scratch_shapes=_stream_scratch(k, p, num_slots, nb) + [
             pltpu.SemaphoreType.DMA,                # bsem
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(state)),
@@ -337,24 +331,22 @@ def sptrsv_pallas(
 # ---------------------------------------------------------------------------
 def _blocked_kernel(
     # inputs
-    instr_ref,   # [G * (planes + 1) * K] int32, HBM (streamed by DMA)
+    instr_ref,   # [G * STREAM_PLANES * K] int32, HBM (streamed by DMA)
     val_ref,     # [G * K]                f32,   HBM (pre-gathered values)
     cnt_ref,     # [G]                    int32, SMEM (entries per block)
     b_hbm_ref,   # [n_hbm, lanes]         f32,   HBM (windowed by DMA)
     # outputs
     x_hbm_ref,   # [n_hbm, lanes]         f32,   HBM (windowed by DMA)
     # scratch
-    ibuf, vbuf, fb_ref, rf_ref, isem, vsem,
-    xwin,        # [2, window, lanes] — two x windows
+    ibuf, vbuf, ls_ref, isem, vsem,
+    xwin,        # [2, window, lanes] — two x windows, top row spare
     bwin,        # [2, window, lanes] — two b windows (read-only, refetched)
     bsem, xssem, xfsem,
     *,
     k: int,
     num_blocks: int,
-    num_slots: int,
     window: int,
     stride: int,
-    planes: int,
 ):
     """x/b HBM-resident solve over a sliding VMEM row window.
 
@@ -374,7 +366,7 @@ def _blocked_kernel(
     """
     w, r = window, stride
     instr_dma, val_dma = _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem,
-                                      vsem, k=k, planes=planes)
+                                      vsem, k=k)
 
     def b_dma(slot, g):
         return pltpu.make_async_copy(
@@ -391,8 +383,7 @@ def _blocked_kernel(
         return pltpu.make_async_copy(
             xwin.at[slot, pl.ds(0, r)], x_hbm_ref.at[pl.ds(g * r, r)], xfsem)
 
-    fb_ref[...] = jnp.zeros(fb_ref.shape, jnp.float32)
-    rf_ref[...] = jnp.zeros(rf_ref.shape, jnp.float32)
+    ls_ref[...] = jnp.zeros(ls_ref.shape, jnp.float32)
 
     # warm-up: block 0 inputs in flight before the block loop starts
     instr_dma(0, 0).start()
@@ -420,8 +411,7 @@ def _blocked_kernel(
             b_dma(nxt, g + 1).start()
 
         _run_block(ibuf, vbuf, slot, cnt_ref[g], xwin.at[slot], bwin.at[slot],
-                   fb_ref, rf_ref, base=g * r, rows=w, k=k, planes=planes,
-                   num_slots=num_slots)
+                   ls_ref, k=k)
 
         @pl.when(g + 1 < num_blocks)
         def _boundary():
@@ -442,17 +432,16 @@ def _blocked_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_cus", "planes", "num_slots", "window", "stride",
+    static_argnames=("num_cus", "num_slots", "window", "stride",
                      "interpret"),
 )
 def sptrsv_pallas_blocked(
-    instr: jnp.ndarray,    # [G * (planes + 1) * K] int32 (words + lane ids)
+    instr: jnp.ndarray,    # [G * STREAM_PLANES * K] int32 (staged planes)
     values: jnp.ndarray,   # [G * K] f32 (pre-gathered stream values)
     counts: jnp.ndarray,   # [G] int32 (active entries per cycle block)
     b: jnp.ndarray,        # [n_hbm, B] f32 (padded to the window sweep)
     *,
     num_cus: int,
-    planes: int,
     window: int,
     stride: int,
     num_slots: int = 12,
@@ -470,7 +459,7 @@ def sptrsv_pallas_blocked(
     """
     interpret = resolve_interpret(interpret)
     p = num_cus
-    num_blocks, k = _stream_shape(instr, values, counts, planes)
+    num_blocks, k = _stream_shape(instr, values, counts)
     n_hbm, nb = b.shape
     lanes = -(-nb // _LANES) * _LANES
     assert stride >= 1 and window >= 2 * stride, (window, stride)
@@ -482,10 +471,8 @@ def sptrsv_pallas_blocked(
         _blocked_kernel,
         k=k,
         num_blocks=num_blocks,
-        num_slots=num_slots,
         window=window,
         stride=stride,
-        planes=planes,
     )
     return pl.pallas_call(
         kernel,
@@ -497,7 +484,7 @@ def sptrsv_pallas_blocked(
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.HBM),  # x stays in HBM
         out_shape=jax.ShapeDtypeStruct((n_hbm, lanes), jnp.float32),
-        scratch_shapes=_stream_scratch(k, p, planes, num_slots, lanes) + [
+        scratch_shapes=_stream_scratch(k, p, num_slots, lanes) + [
             pltpu.VMEM((2, window, lanes), jnp.float32),  # xwin
             pltpu.VMEM((2, window, lanes), jnp.float32),  # bwin
             pltpu.SemaphoreType.DMA((2,)),                # bsem

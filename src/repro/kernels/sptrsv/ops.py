@@ -23,10 +23,11 @@ streams them sequentially (no positional indirection, as in the paper's
 stream-memory design), and the compiler's packed instruction words
 (``Program.instr``, ``[T, planes, P]`` int32 — DESIGN.md §Perf,
 "Instruction encoding") are compacted per cycle block to the words that do
-something, each with its lane id, padded to one segment length K, and
-flattened, so each block arrives in SMEM with a single DMA per stream.  Per
-kept entry the kernel streams ``4 * planes + 4 + 4`` bytes (words, lane id,
-value); no-op lane slots are neither streamed nor executed.
+something, staged pre-decoded as the rows each access uses (`kernel`'s
+module docstring), padded to one segment length K, and flattened, so each
+block arrives in SMEM with a single DMA per stream.  Per kept entry the
+kernel streams ``4 * STREAM_PLANES + 4`` bytes (planes, value); no-op lane
+slots are neither streamed nor executed.
 """
 
 from __future__ import annotations
@@ -40,14 +41,33 @@ import jax.numpy as jnp
 
 from repro.core.errors import PlacementInfeasibleError
 from repro.core.executor import _psum_slots, as_batch
-from repro.core.program import OP_NOP, PS_KEEP, Program, decode_instructions
+from repro.core.program import (
+    OP_EDGE,
+    OP_FINAL,
+    OP_NOP,
+    PS_KEEP,
+    PS_LOAD,
+    PS_RESET,
+    PS_STORE_RESET,
+    PS_SWAP,
+    Program,
+    decode_instructions,
+)
 
 from repro.kernels.common import resolve_interpret
 
 from .kernel import (
+    EDGE,
+    FB,
+    PARK,
+    PV_SRC,
+    ROW,
     SEGMENT_ALIGN,
+    STREAM_PLANES,
     UNROLL,
+    X_DST,
     blocked_state_bytes,
+    lane_rows,
     resident_state_bytes,
     sptrsv_pallas,
     sptrsv_pallas_blocked,
@@ -115,7 +135,9 @@ def plan_window(
     window for block g is placed at base ``g * stride``, so feasibility
     requires ``g*stride <= lo_g`` and ``hi_g < g*stride + window`` for all
     g.  The stride is maximized (smallest window), then the window sized to
-    the worst block — both rounded to the f32 sublane granularity.
+    the worst block plus one spare top row, which takes the x stores of
+    words that finalize nothing (`kernel`'s ``X_DST``) — both rounded to
+    the f32 sublane granularity.
 
     Programs whose row envelope does not advance monotonically enough
     (e.g. circuit matrices with hub columns read across the whole DAG)
@@ -151,7 +173,7 @@ def plan_window(
     for gi in range(g):
         if nonempty[gi]:
             w_req = max(w_req, int(hi[gi]) - gi * stride + 1)
-    window = max(w_req, 2 * stride, min_window or 0, 2 * _ROW_ALIGN)
+    window = max(w_req + 1, 2 * stride, min_window or 0, 2 * _ROW_ALIGN)
     window = _round_up(window, _ROW_ALIGN)
     n_hbm = (g - 1) * stride + window
     return WindowPlan(True, stride=stride, window=window, n_hbm=n_hbm,
@@ -202,7 +224,9 @@ def resolve_placement(
 
 
 def _resident_rows(prog: Program) -> int:
-    return _round_up(prog.n, _ROW_ALIGN)
+    """Rows of the resident x buffer: n and at least one spare row on top,
+    which takes the x stores of words that finalize nothing."""
+    return _round_up(prog.n + 1, _ROW_ALIGN)
 
 
 def _pad_to(arr: np.ndarray, t_pad: int, fill=0) -> np.ndarray:
@@ -221,8 +245,8 @@ class Stream:
     Cycle block g (``cycles_per_block`` cycles) keeps only the words with op
     not NOP or psum control not KEEP, in cycle-major, lane-minor order, in a
     segment of ``k`` entries (a `kernel.SEGMENT_ALIGN` multiple) padded with
-    the filler entry (word 0, lane 0, value 0).  ``instr`` is ``[G, planes + 1,
-    k]`` flattened (plane-major words, then the lane ids), ``values``
+    the filler entry (`_filler`).  ``instr`` is ``[G, STREAM_PLANES, k]``
+    flattened (the planes `kernel.ROW` .. `kernel.EDGE`), ``values``
     ``[G, k]`` flattened, ``counts[g]`` the active entries of block g.
     """
 
@@ -255,15 +279,54 @@ def _segment_len(active: np.ndarray) -> int:
     return _round_up(max(int(active.sum(axis=1).max()), 1), SEGMENT_ALIGN)
 
 
-def _stage_instructions(prog: Program, cycles_per_block: int) -> Stream:
+def _planes(op, row, ctl, slot, lane, p: int, num_slots: int,
+            spare: int) -> np.ndarray:
+    """``[STREAM_PLANES, m]`` staged planes of decoded words (see `kernel`);
+    ``row`` is each word's x row in the ref the kernel addresses, ``spare``
+    that ref's top row.
+
+    Lane-state rows: feedback of lane l at l, psum slot j of lane l at ``p +
+    l * num_slots + j`` (overflow slots share the last), then the zero row
+    and the trash row (`kernel.lane_rows`).
+    """
+    zero = lane_rows(p, num_slots) - 2
+    trash = zero + 1
+    slot_row = p + lane * num_slots + np.minimum(slot, num_slots - 1)
+    out = np.empty((STREAM_PLANES, len(op)), np.int64)
+    out[ROW] = np.where(op == OP_NOP, 0, row)
+    out[PV_SRC] = np.where((ctl == PS_LOAD) | (ctl == PS_SWAP), slot_row,
+                           np.where((ctl == PS_RESET) | (ctl == PS_STORE_RESET),
+                                    zero, lane))
+    out[FB] = lane
+    out[PARK] = np.where((ctl == PS_STORE_RESET) | (ctl == PS_SWAP),
+                         slot_row, trash)
+    out[X_DST] = np.where(op == OP_FINAL, row, spare)
+    out[EDGE] = op == OP_EDGE
+    return out
+
+
+def _filler(p: int, num_slots: int, spare: int) -> np.ndarray:
+    """The filler entry's planes: it sums from the zero row and stores to
+    the trash row and the spare x row only, so it changes no row that is
+    read."""
+    zero = lane_rows(p, num_slots) - 2
+    out = np.zeros(STREAM_PLANES, np.int64)
+    out[[PV_SRC, FB, PARK, X_DST]] = [zero, zero + 1, zero + 1, spare]
+    return out
+
+
+def _stage_instructions(prog: Program, cycles_per_block: int,
+                        plan: WindowPlan | None = None) -> Stream:
     """Compact the packed instruction words and pre-gather the values.
 
     The program already carries the packed ``[T, planes, P]`` words — the
     pack happens once at compile time; staging drops the no-op lane slots
-    of each cycle block (pad cycles are all no-op), keeps the lane id of
-    every word it keeps, gathers the f32 values per kept word so the kernel
-    streams them positionally, and pads each block's segment to the common
-    length (see `Stream`).
+    of each cycle block (pad cycles are all no-op), decodes every word it
+    keeps into the rows the kernel's accesses use (`_planes`), gathers the
+    f32 values per kept word so the kernel streams them positionally, and
+    pads each block's segment to the common length (see `Stream`).  x rows
+    index the resident x buffer, or with a blocked ``plan`` the window of
+    their block; a row outside it raises ``ValueError``.
     """
     tb, p, planes = cycles_per_block, prog.num_cus, prog.planes
     words, active = _active_blocks(prog, tb)
@@ -271,12 +334,27 @@ def _stage_instructions(prog: Program, cycles_per_block: int) -> Stream:
     values = _pad_to(prog.stream[prog.val_idx].astype(np.float32), g * tb)
     values = values.reshape(g, tb * p)
     k = _segment_len(active)
+    slots = _psum_slots(prog)
 
     blk, slot = np.nonzero(active)               # row-major: in block order
     pos = (np.cumsum(active, axis=1) - 1)[blk, slot]
-    instr = np.zeros((g, planes + 1, k), np.int32)
-    instr[blk, :planes, pos] = words[blk, :, slot]
-    instr[blk, planes, pos] = slot % p           # the word's lane
+    kept = words[blk, :, slot][..., None]        # [m, planes, 1]
+    op, src, ctl, ps = (f[:, 0] for f in decode_instructions(kept, planes))
+    row = src.astype(np.int64)
+    if plan is None:
+        spare = _resident_rows(prog) - 1
+    else:
+        spare = plan.window - 1
+        row = row - blk * plan.stride
+    bad = (op != OP_NOP) & ((row < 0) | (row >= spare))
+    if bad.any():
+        i = int(np.flatnonzero(bad)[0])
+        raise ValueError(
+            f"word of cycle block {blk[i]} addresses x row {src[i]}, outside "
+            f"the {spare} rows its {'window' if plan else 'x buffer'} holds")
+    instr = np.empty((g, STREAM_PLANES, k), np.int32)
+    instr[...] = _filler(p, slots, spare)[:, None]
+    instr[blk, :, pos] = _planes(op, row, ctl, ps, slot % p, p, slots, spare).T
     vals = np.zeros((g, k), np.float32)
     vals[blk, pos] = values[blk, slot]
     return Stream(instr=instr.reshape(-1), values=vals.reshape(-1),
@@ -287,16 +365,15 @@ def _stage_instructions(prog: Program, cycles_per_block: int) -> Stream:
 def instr_buffer_bytes(prog: Program, cycles_per_block: int = 128) -> int:
     """SMEM bytes of the kernel's double-buffered instruction streaming.
 
-    Two segment buffers of K entries, each entry its packed words, its lane
-    id and its pre-gathered f32 value: ``2 * K * (4 * planes + 4 + 4)``,
-    where K is the largest cycle block's count of active words rounded up
-    to `kernel.SEGMENT_ALIGN` (1024).  At worst (every lane active in every
-    cycle) K is ``cycles_per_block * P``: 192 KiB for planes=1 and 256 KiB
-    for planes=2 at 128 cycles of 64 lanes.  A TPU v5e core has 1 MiB of
-    SMEM.
+    Two segment buffers of K entries, each entry its `kernel.STREAM_PLANES`
+    int32 planes and its pre-gathered f32 value: ``2 * K * (4 *
+    STREAM_PLANES + 4)``, where K is the largest cycle block's count of
+    active words rounded up to `kernel.SEGMENT_ALIGN` (1024).  At worst
+    (every lane active in every cycle) K is ``cycles_per_block * P``: 448
+    KiB at 128 cycles of 64 lanes.  A TPU v5e core has 1 MiB of SMEM.
     """
     _, active = _active_blocks(prog, cycles_per_block)
-    return 2 * _segment_len(active) * (4 * prog.planes + 4 + 4)
+    return 2 * _segment_len(active) * (4 * STREAM_PLANES + 4)
 
 
 def state_bytes(prog: Program, nb: int, *, placement: str,
@@ -353,7 +430,7 @@ def build_solver_cols(
         prog, width, placement=placement, vmem_limit_bytes=vmem_limit_bytes,
         cycles_per_block=cycles_per_block, x_block_rows=x_block_rows,
     )
-    stream = _stage_instructions(prog, cycles_per_block)
+    stream = _stage_instructions(prog, cycles_per_block, plan)
     instr = jnp.asarray(stream.instr)
     values = jnp.asarray(stream.values)
     counts = jnp.asarray(stream.counts)
@@ -361,8 +438,7 @@ def build_solver_cols(
     n_slots = _psum_slots(prog)
     n_rows = _resident_rows(prog) if mode == "resident" else plan.n_hbm
     interpret = resolve_interpret(interpret)
-    kw = dict(num_cus=prog.num_cus, planes=prog.planes, num_slots=n_slots,
-              interpret=interpret)
+    kw = dict(num_cus=prog.num_cus, num_slots=n_slots, interpret=interpret)
 
     @jax.jit  # fold the pad/slice into the kernel dispatch
     def solve_cols(bmat: jnp.ndarray) -> jnp.ndarray:

@@ -70,8 +70,8 @@ def _kernel_args(prog, rows, one_chip, batch=B):
     def spec(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    kw = dict(num_cus=prog.num_cus, planes=prog.planes,
-              num_slots=executor._psum_slots(prog), interpret=False)
+    kw = dict(num_cus=prog.num_cus, num_slots=executor._psum_slots(prog),
+              interpret=False)
     return (spec(stream.instr), spec(stream.values), spec(stream.counts),
             spec(np.zeros((rows, batch), np.float32))), kw
 
@@ -89,11 +89,12 @@ def test_resident_kernel_compiles_for_v5e(progs, one_chip, no_compile_cache):
 
 def test_resident_kernel_compiles_at_hpcg_rows(progs, one_chip,
                                                no_compile_cache):
-    """The resident kernel at the benchmark's HPCG grid (48³ = 110,592 rows)
-    and one column: one VMEM x buffer, b copied into it.  The stream is
-    ckt_add20's; the VMEM the kernel holds depends only on n_pad and B."""
+    """The resident kernel at the benchmark's HPCG grid (48³ = 110,592 rows
+    and the spare row, padded to 110,600) and one column: one VMEM x buffer,
+    b copied into it.  The stream is ckt_add20's; the VMEM the kernel holds
+    depends only on n_pad and B."""
     prog = progs["ckt_add20"]
-    rows = 48 ** 3
+    rows = 48 ** 3 + 8
     args, kw = _kernel_args(prog, rows, one_chip, batch=1)
     compiled = kernel.sptrsv_pallas.lower(*args, **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
