@@ -6,7 +6,7 @@ import dataclasses
 
 from repro.core.matrices import generate
 from repro.core.program import AccelConfig
-from repro.core.schedule import compile_program
+from repro.core.compiler import compile_program
 
 from .common import emit
 

@@ -6,7 +6,7 @@
 
 Compiles every suite matrix with ``schedule="auto"`` (DESIGN.md §11): the
 compiler runs each registered strategy — the paper's psum-cache scheduler
-plus the level-set and list-scheduler alternatives — scores each dense
+and the list-scheduler pick orders of the same machine — scores each dense
 trace with the analytic cost model, and keeps the predicted-cheapest.
 Because the cost model's cycle count is exact (it *is* the dense trace
 length), the recorded frontier doubles as the measured one: per matrix
@@ -38,7 +38,7 @@ BENCH_JSON = os.path.join(os.path.dirname(__file__), "..",
 BENCH_SCHEMA = "sptrsv-bench-schedule"
 BENCH_VERSION = 1
 
-STRATEGY_NAMES = ("paper", "level", "locality", "cpath", "eager")
+STRATEGY_NAMES = ("paper", "locality", "cpath", "eager")
 # ckt_fpga must be an auto win (list schedulers beat the paper's resume
 # order on psum-bound circuit DAGs); band_cz is an order-forced tie.
 SMOKE_SET = ("band_cz", "ckt_fpga")

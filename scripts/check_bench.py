@@ -77,7 +77,7 @@ CHAOS_ENTRY_FIELDS = {
 
 # scheduling-strategy frontier (benchmarks/schedule_frontier.py): one
 # cycles/stalls/spills triple per registered strategy, plus auto's pick
-_STRATEGY_NAMES = ("paper", "level", "locality", "cpath", "eager")
+_STRATEGY_NAMES = ("paper", "locality", "cpath", "eager")
 SCHEDULE_ROW_FIELDS = {
     "name": str,
     "n": int,

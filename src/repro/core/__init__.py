@@ -6,10 +6,9 @@ branch-free VLIW executors, and the benchmark-matrix suite.
 """
 
 from . import api, compiler, dag, frontends, matrices, serve  # noqa: F401
-from .compiler import ComputeDag, compile_dag  # noqa: F401
+from .compiler import ComputeDag, compile_dag, compile_program  # noqa: F401
 from .csr import TriCSR, UpperCSR, serial_solve, serial_solve_upper  # noqa: F401
 from .program import AccelConfig, Program, ScheduleStats  # noqa: F401
-from .schedule import compile_program  # noqa: F401
 from .executor import (  # noqa: F401
     execute_jax,
     execute_numpy,

@@ -94,7 +94,7 @@ from .fine import FineConfig, FineStats, schedule_fine
 from .frontends.dagcirc import DagCircuit, lower_circuit
 from .frontends.upper import lower_upper
 from .program import AccelConfig, Program
-from .schedule import compile_program
+from .compiler import compile_program
 
 __all__ = [
     "matrix",
@@ -150,7 +150,7 @@ def recompile_values(prog: Program, mat: TriCSR) -> Program:
     the cost.  Raises ``ValueError`` on a pattern mismatch or a program
     serialized before provenance existed (run `compile` instead).
     """
-    from .schedule import recompile_values as _recompile
+    from .compiler import recompile_values as _recompile
 
     return _recompile(prog, mat)
 

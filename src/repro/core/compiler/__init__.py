@@ -1,6 +1,6 @@
 """Staged compiler pipeline for SpTRSV-like compute DAGs (DESIGN.md §6).
 
-Replaces the historical monolithic ``schedule.compile_program`` with an
+Replaces the historical monolithic SpTRSV compiler with an
 explicit pass pipeline over documented IR dataclasses (`ir.py`)::
 
     ComputeDag → partition → cu-assign → psum-cache schedule (+ per-cycle
@@ -9,8 +9,8 @@ explicit pass pipeline over documented IR dataclasses (`ir.py`)::
 `compile_dag` is the generic entry point: it accepts any workload lowered
 to the `ComputeDag` frontend contract (`core/frontends/`) and emits the
 unchanged `Program` format every executor, the batching/sharding paths and
-the packed encoding already consume.  ``schedule.compile_program`` is now
-a thin TriCSR wrapper over this pipeline.
+the packed encoding already consume.  `compile_program` is its TriCSR
+front door (lower through the SpTRSV frontend, then `compile_dag`).
 
 Per-pass wall-clock and metrics are recorded on
 ``program.stats.pass_stats`` (a list of `PassStats`) for observability;
@@ -28,7 +28,7 @@ import time
 from jax.profiler import TraceAnnotation
 
 from ..program import AccelConfig, Program
-from . import assign, elide, emit, partition, sched
+from . import assign, elide, emit, partition, sched, strategies
 from .ir import (  # noqa: F401  (re-exported IR surface)
     AssignIR,
     ComputeDag,
@@ -41,6 +41,7 @@ from .sched import MAX_PSUM_SLOT, PSUM_OVERFLOW_SLOTS  # noqa: F401
 
 __all__ = [
     "compile_dag",
+    "compile_program",
     "recompile_values",
     "ComputeDag",
     "PartitionIR",
@@ -68,13 +69,14 @@ def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
     The pipeline stages run in order; each records a `PassStats` entry on
     ``program.stats.pass_stats``.
 
-    ``schedule`` picks the schedule pass (DESIGN.md §11): ``"paper"`` (the
-    default psum-cache scheduler), an alternative strategy by name
-    (``"level"``, ``"locality"``), or ``"auto"`` — compile every candidate
-    and keep the one the analytic cost model predicts cheapest.  The
-    decision lands in ``stats.schedule`` (and, for auto, the per-candidate
-    predictions in ``stats.schedule_costs``); auto's selection overhead is
-    a synthetic ``"strategy_select"`` entry on ``pass_stats``.
+    ``schedule`` picks the schedule machine's pick order (DESIGN.md §11):
+    ``"paper"`` (the default psum-cache scheduler), a list preset by name
+    (``"locality"``, ``"cpath"``, ``"eager"``), or ``"auto"`` — compile
+    every candidate and keep the one the analytic cost model predicts
+    cheapest.  The decision lands in ``stats.schedule`` (and, for auto,
+    the per-candidate predictions in ``stats.schedule_costs``); auto's
+    selection overhead is a synthetic ``"strategy_select"`` entry on
+    ``pass_stats``.
 
     ``verify_ir=True`` runs the per-pass contract verifiers
     (`core/analysis/contracts.py`) on every intermediate IR and raises
@@ -115,8 +117,6 @@ def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
     _check(lambda: contracts.verify_assign(air, cfg), "cu_assign")
     select_stats = None
     if schedule == "auto":
-        from . import strategies
-
         (sir, chosen, costs, run_seconds), t_select = _timed(
             "psum_schedule", strategies.select, air, cfg)
         t_sched = run_seconds[chosen]
@@ -129,8 +129,6 @@ def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
     elif schedule == "paper":
         sir, t_sched = _timed("psum_schedule", sched.run, air, cfg)
     else:
-        from . import strategies
-
         sir, t_sched = _timed("psum_schedule", strategies.get(schedule), air,
                               cfg)
     _check(lambda: contracts.verify_schedule(sir, air, cfg), "psum_schedule")
@@ -164,6 +162,23 @@ def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
             PassStats("verify_ir", t_verify, {"stages_verified": verified}))
     prog.stats.compile_seconds = time.perf_counter() - t0
     return prog
+
+
+def compile_program(mat, cfg: AccelConfig | None = None, *,
+                    planes: int | None = None,
+                    schedule: str = "paper",
+                    verify_ir: bool = False) -> Program:
+    """Compile a lower-triangular `TriCSR` into a packed VLIW `Program`.
+
+    Lowers ``mat`` through the SpTRSV frontend
+    (`frontends.sptrsv.lower_tri`) and runs `compile_dag` with the same
+    keywords.  The frozen copy of the original monolithic compiler in
+    `tests/legacy_schedule.py` pins its output bit for bit.
+    """
+    from ..frontends.sptrsv import lower_tri
+
+    return compile_dag(lower_tri(mat), cfg, planes=planes,
+                       schedule=schedule, verify_ir=verify_ir)
 
 
 def recompile_values(prog: Program, new_workload) -> Program:

@@ -16,10 +16,30 @@ fully predict the behavior of the hardware", §III-B).
 Deviations from the paper (DESIGN.md §5): online least-used-first-fit bank
 assignment; windowed ICR; emergency psum overflow parks on detected global
 stalls (counted as ``dm_escapes``).
+
+This is the only schedule machine (DESIGN.md §11).  The strategies of
+`compiler.strategies` are `PRESETS` of it: each keeps every slot rule, the
+ICR sub-stage and the delivery model, and changes only the phase-1 pick
+order of a CU's next node:
+
+  * ``"paper"``    — resume the first parked node in cache order, else
+    continue the current one, else start in program order (the paper's);
+  * ``"cpath"``    — resume the parked node of greatest critical-path
+    height, else continue, else start in program order;
+  * ``"locality"`` — continue while the current node has work (the psum
+    feedback path costs no slot traffic), else resume by height, else
+    start in program order: wins on psum-capacity-bound circuit DAGs;
+  * ``"eager"``    — like ``"locality"``, but start the node with the most
+    issuable edges: draining delivered values early keeps the x_i file
+    from thrashing on spill-bound hub DAGs.
+
+The coarse dataflow (one node at a time, program order) is the machine's
+own branch and runs only under ``"paper"``.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import time
 
 import numpy as np
@@ -40,7 +60,7 @@ from ..program import (
 from . import icr
 from .ir import AssignIR, ScheduleIR
 
-__all__ = ["run", "PSUM_OVERFLOW_SLOTS", "MAX_PSUM_SLOT"]
+__all__ = ["run", "Preset", "PRESETS", "PSUM_OVERFLOW_SLOTS", "MAX_PSUM_SLOT"]
 
 PSUM_OVERFLOW_SLOTS = 4  # emergency data-memory-modelled psum spill slots
 
@@ -131,16 +151,69 @@ class _CU:
         return self.done_count == len(self.tasks)
 
 
-def run(air: AssignIR, cfg: AccelConfig) -> ScheduleIR:
-    """Simulate the machine over the assigned DAG; return the dense trace."""
+@dataclasses.dataclass(frozen=True)
+class Preset:
+    """Phase-1 pick order of one schedule strategy (module docstring)."""
+
+    continue_first: bool    # continue the current node before any resume
+    resume_by_height: bool  # resume the tallest parked node, not the first
+    start_most_ready: bool  # start the most-ready node, not program order
+
+
+# Registry order is `strategies.select`'s tie-break order: "paper" first.
+PRESETS: dict[str, Preset] = {
+    "paper": Preset(continue_first=False, resume_by_height=False,
+                    start_most_ready=False),
+    "locality": Preset(continue_first=True, resume_by_height=True,
+                       start_most_ready=False),
+    "cpath": Preset(continue_first=False, resume_by_height=True,
+                    start_most_ready=False),
+    "eager": Preset(continue_first=True, resume_by_height=True,
+                    start_most_ready=True),
+}
+
+
+def node_heights(consumers: list[list[int]], n: int) -> np.ndarray:
+    """Longest-path distance to a sink (critical-path priority)."""
+    height = np.zeros(n, dtype=np.int64)
+    for j in range(n - 1, -1, -1):
+        if consumers[j]:
+            height[j] = max(height[i] for i in consumers[j]) + 1
+    return height
+
+
+def _most_ready(startable: dict[int, int], nodes: list[_Node]) -> int:
+    """Position of the startable node with the most issuable edges; program
+    order breaks ties (sources have no edges, so -pos decides them)."""
+    return max(startable,
+               key=lambda pos: (len(nodes[startable[pos]].ready), -pos))
+
+
+def run(air: AssignIR, cfg: AccelConfig, *,
+        preset: str = "paper") -> ScheduleIR:
+    """Simulate the machine over the assigned DAG; return the dense trace.
+
+    ``preset`` names the pick order (`PRESETS`); only ``"paper"`` runs the
+    coarse dataflow.
+    """
+    order = PRESETS[preset]
+    if preset != "paper" and cfg.dataflow != "medium":
+        raise ValueError(
+            f"schedule={preset!r} requires dataflow='medium', "
+            f"got {cfg.dataflow!r} (use schedule='paper')")
     if cfg.dataflow not in ("medium", "coarse"):
         raise ValueError(f"unknown dataflow {cfg.dataflow!r}")
+    coarse = cfg.dataflow == "coarse"
+    continue_first = order.continue_first
+    most_ready = order.start_most_ready
     dag = air.part.dag
     n, p = dag.n, cfg.num_cus
     scale = dag.scale
     task_lists = air.task_lists
     owner = air.owner
     consumers = air.part.consumers
+    # an O(nnz) Python pass: only the presets that resume by height pay it
+    height = node_heights(consumers, n) if order.resume_by_height else None
 
     nodes: list[_Node] = []
     for i in range(n):
@@ -174,7 +247,8 @@ def run(air: AssignIR, cfg: AccelConfig) -> ScheduleIR:
 
     while solved_total < n:
         if cycle > max_cycles:
-            raise RuntimeError(f"scheduler did not converge on {dag.name}")
+            raise RuntimeError(
+                f"{preset} scheduler did not converge on {dag.name}")
         op_row = np.zeros(p, dtype=np.uint8)
         val_row = np.zeros(p, dtype=np.int32)
         src_row = np.zeros(p, dtype=np.int32)
@@ -193,7 +267,7 @@ def run(air: AssignIR, cfg: AccelConfig) -> ScheduleIR:
             cur = cu.current
             cur_live = cur is not None and not cur.solved
 
-            if cfg.dataflow == "coarse":
+            if coarse:
                 cu.advance_head()
                 if cur_live and cur.has_work():
                     kind = "edge" if cur.ready else "final"
@@ -210,22 +284,32 @@ def run(air: AssignIR, cfg: AccelConfig) -> ScheduleIR:
                 continue
 
             picked: tuple[str, _Node] | None = None
-            for nd in cu.cached:  # cached nodes have absolute priority
-                if nd.has_work():
-                    picked = ("resume", nd)
-                    break
+            if continue_first and cur_live and cur.has_work():
+                picked = ("continue", cur)  # psum feedback stays hot
+            elif height is None:
+                for nd in cu.cached:  # first parked node in cache order
+                    if nd.has_work():
+                        picked = ("resume", nd)
+                        break
+            else:
+                resumable = [nd for nd in cu.cached if nd.has_work()]
+                if resumable:  # deepest critical path first
+                    picked = ("resume",
+                              max(resumable, key=lambda nd: height[nd.nid]))
             if picked is None and cur_live and cur.has_work():
                 picked = ("continue", cur)
             if picked is None and startable[c] and (cfg.psum_cache or not cur_live):
-                pos = min(startable[c])
-                picked = ("start", nodes[startable[c][pos]])
+                sc = startable[c]
+                pos = _most_ready(sc, nodes) if most_ready else min(sc)
+                picked = ("start", nodes[sc[pos]])
             if picked is None:
                 # deadlock escape (also required with psum_cache=False: a
                 # blocked current node can circularly wait on unstarted
                 # nodes — see module docstring)
                 if stall_streak >= 2 and cur_live and startable[c]:
-                    pos = min(startable[c])
-                    nd = nodes[startable[c][pos]]
+                    sc = startable[c]
+                    pos = _most_ready(sc, nodes) if most_ready else min(sc)
+                    nd = nodes[sc[pos]]
                     stats.dm_escapes += 1
                     kind = "edge" if nd.ready else "final"
                     chosen[c] = (kind, nd, PS_STORE_RESET, cu.peek_over_slot())
@@ -381,9 +465,11 @@ def run(air: AssignIR, cfg: AccelConfig) -> ScheduleIR:
 
     stats.cycles = cycle
     stats.per_cu_edges = np.array([cu.edge_count for cu in cus])
+    stats.schedule = preset
     num_slots = max(cu.next_over for cu in cus)
 
     metrics = {
+        "strategy": preset,
         "dataflow": cfg.dataflow,
         "hardware_cycles": cycle,
         "exec_edges": stats.exec_edges,

@@ -1,18 +1,17 @@
-"""Pluggable scheduler strategies + per-matrix auto-select (DESIGN.md §11).
+"""Scheduler strategies + per-matrix auto-select (DESIGN.md §11).
 
-The staged pipeline made the schedule pass swappable: any function
-``run(air: AssignIR, cfg: AccelConfig) -> ScheduleIR`` that honours the
-`analysis.contracts.verify_schedule` contract slots in between cu-assign
-and stall-elide, and every downstream pass and executor runs its output
-unchanged.  This package holds the strategy registry:
+Every strategy is a pick-order preset of the one schedule machine
+(`compiler.sched`, whose docstring describes each order); its dense
+`ScheduleIR` slots in between cu-assign and stall-elide, and every
+downstream pass and executor runs it unchanged.  This package holds the
+registry and the auto mode:
 
-  * ``"paper"``    — the paper's psum-cache scheduler (`compiler.sched`),
-                     the default and the baseline;
-  * ``"level"``    — level-set wavefront packing (`level.py`);
-  * ``"locality"`` — psum-reuse-first list scheduling (`locality.py`);
-  * ``"cpath"``    — critical-path-first list scheduling (`locality.py`);
+  * ``"paper"``    — the paper's psum-cache scheduler, the default and the
+                     baseline;
+  * ``"locality"`` — psum-reuse-first list scheduling;
+  * ``"cpath"``    — critical-path-first list scheduling;
   * ``"eager"``    — consume-early list scheduling for spill-bound hub
-                     DAGs (`locality.py`);
+                     DAGs;
   * ``"auto"``     — compile every applicable candidate, score each dense
                      trace with the analytic cost model (`cost.py`), keep
                      the cheapest.  Ties keep registry order, so ``auto``
@@ -25,12 +24,12 @@ benchmark are built on it).
 
 from __future__ import annotations
 
+import functools
 import time
 
 from ...program import AccelConfig
 from .. import sched
 from ..ir import AssignIR, ScheduleIR
-from . import level, locality
 from .cost import CostEstimate, predict_cycles
 
 __all__ = [
@@ -49,11 +48,7 @@ AUTO = "auto"
 # Registry order is the tie-break order: "paper" first means the baseline
 # wins every tie, which is what makes auto never predicted-worse than it.
 STRATEGIES: dict[str, object] = {
-    "paper": sched.run,
-    level.NAME: level.run,
-    locality.NAME: locality.run,
-    locality.CPATH: locality.run_cpath,
-    locality.EAGER: locality.run_eager,
+    name: functools.partial(sched.run, preset=name) for name in sched.PRESETS
 }
 
 
@@ -71,8 +66,8 @@ def get(name: str):
 def candidate_names(cfg: AccelConfig) -> list[str]:
     """Strategies applicable under ``cfg`` (auto's candidate set).
 
-    The alternative strategies model the medium-granularity machine; the
-    coarse dataflow keeps its single paper schedule.
+    The list presets model the medium-granularity machine; the coarse
+    dataflow keeps its single paper schedule.
     """
     if cfg.dataflow != "medium":
         return ["paper"]

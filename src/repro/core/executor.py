@@ -74,7 +74,7 @@ from .program import (
     Program,
     decode_instructions,
 )
-from .schedule import PSUM_OVERFLOW_SLOTS
+from .compiler import PSUM_OVERFLOW_SLOTS
 
 __all__ = [
     "as_batch",

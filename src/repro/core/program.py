@@ -110,7 +110,7 @@ def validate_fields(op, src, ctl, slot, planes: int) -> None:
 
     Shared by the compiler and the packer: any field exceeding its bit
     width raises a clear ``ValueError`` instead of silently wrapping into a
-    neighbouring field (the historical risk: `schedule._CU.peek_over_slot`
+    neighbouring field (the historical risk: `compiler.sched._CU.peek_over_slot`
     grows overflow slots toward 250 while the packed slot field is 8 bits).
     """
     op = np.asarray(op)

@@ -579,7 +579,7 @@ def run_fault_injection(mat: TriCSR, prog: Program | None = None, *,
     check.  The acceptance bar is ``not any(r["silent_wrong"])``.
     """
     from . import serialize
-    from .schedule import compile_program
+    from .compiler import compile_program
 
     if prog is None:
         prog = compile_program(mat)
@@ -795,7 +795,7 @@ def run_service_fault_injection(mats=None, *, seed: int = 0,
     """
     from .matrices import banded
     from .resilience import AdmissionConfig, BreakerConfig, ResilienceConfig, RetryPolicy
-    from .schedule import compile_program
+    from .compiler import compile_program
     from .serve import ManualClock, ProgramCache, SolveService
 
     if mats is None:

@@ -90,7 +90,7 @@ from .executor import execute_numpy, pad_batch, validate_backend
 from .program import AccelConfig, Program
 from .resilience import BreakerBoard, IncidentLog, ResilienceConfig
 from .robust import LADDER, _ENTRY, Incident
-from .schedule import compile_program, recompile_values
+from .compiler import compile_program, recompile_values
 
 __all__ = [
     "FLUSH_DEADLINE",

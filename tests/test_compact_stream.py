@@ -32,7 +32,7 @@ from repro.core.program import (
     AccelConfig,
     decode_instructions,
 )
-from repro.core.schedule import compile_program
+from repro.core.compiler import compile_program
 from repro.kernels.sptrsv import kernel, ops
 
 CPB = 64

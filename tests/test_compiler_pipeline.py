@@ -22,7 +22,7 @@ from repro.core.compiler import ir, sched  # noqa: E402
 from repro.core.frontends.sptrsv import lower_tri  # noqa: E402
 from repro.core.matrices import generate, suite_names  # noqa: E402
 from repro.core.program import MAX_SLOT, SLOT_BITS, AccelConfig  # noqa: E402
-from repro.core.schedule import allocate_nodes, compile_program  # noqa: E402
+from repro.core.compiler import compile_program  # noqa: E402
 
 FAST_SET = ["band_cz", "ckt_rajat04", "chem_bp", "wide_c36", "hub_small"]
 CFG_VARIANTS = [
@@ -117,7 +117,7 @@ def test_pass_boundaries_compose():
 
 def test_allocate_nodes_wrapper_unchanged():
     mat = generate("chem_bp")
-    tasks = allocate_nodes(mat, AccelConfig())
+    tasks = compiler.assign.allocate(mat.n, mat.in_degree(), AccelConfig())
     legacy = legacy_schedule.allocate_nodes(mat, AccelConfig())
     assert tasks == legacy
 

@@ -33,7 +33,7 @@ from repro.core.program import (
     packed_planes,
     validate_fields,
 )
-from repro.core.schedule import compile_program
+from repro.core.compiler import compile_program
 
 
 def _fields(op, src, ctl, slot):

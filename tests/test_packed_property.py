@@ -52,7 +52,7 @@ def test_pack_decode_roundtrip(case):
 def test_random_program_repacks_bit_exactly(n, seed):
     """decode -> re-pack over a real compiled program is the identity."""
     from repro.core.csr import from_coo
-    from repro.core.schedule import compile_program
+    from repro.core.compiler import compile_program
 
     rng = np.random.default_rng(seed)
     rows, cols = [], []

@@ -30,7 +30,7 @@ from repro.core.resilience import (
     ResilienceConfig,
     RetryPolicy,
 )
-from repro.core.schedule import compile_program
+from repro.core.compiler import compile_program
 from repro.core.serve import ManualClock, ProgramCache, SolveService
 
 _MATS = {

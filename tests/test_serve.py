@@ -384,7 +384,7 @@ def test_same_pattern_new_values_is_a_values_refresh(tmp_path):
                                api.reference_solve(a2, b),
                                rtol=1e-4, atol=1e-4)
     # the refreshed stream is bit-identical to a full recompile's
-    from repro.core.schedule import compile_program
+    from repro.core.compiler import compile_program
 
     np.testing.assert_array_equal(p2.stream, compile_program(a2).stream)
 

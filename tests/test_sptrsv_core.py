@@ -10,7 +10,7 @@ from repro.core.csr import TriCSR, from_coo, random_rhs, serial_solve
 from repro.core.dag import analyze, compute_levels
 from repro.core.matrices import SUITE, generate
 from repro.core.program import AccelConfig
-from repro.core.schedule import compile_program
+from repro.core.compiler import compile_program
 
 SMALL = ["chain_1k", "band_cz", "ckt_rajat04", "chem_bp", "wide_c36", "hub_small"]
 

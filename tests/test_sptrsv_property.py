@@ -9,7 +9,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 from repro.core import api
 from repro.core.csr import from_coo, serial_solve
 from repro.core.program import AccelConfig
-from repro.core.schedule import compile_program
+from repro.core.compiler import compile_program
 
 
 @st.composite

@@ -26,10 +26,12 @@ PARITY_SET = ["band_cz", "hub_small"]
 
 # ------------------------------------------------ registry + validation
 def test_registry_shape_and_unknown_name():
-    assert list(strategies.STRATEGIES) == ["paper", "level", "locality",
-                                           "cpath", "eager"]
+    assert list(strategies.STRATEGIES) == ["paper", "locality", "cpath",
+                                           "eager"]
     with pytest.raises(ValueError, match="unknown schedule strategy"):
         strategies.get("nope")
+    with pytest.raises(ValueError, match="options: paper, locality"):
+        strategies.get("level")
     with pytest.raises(ValueError, match="unknown schedule strategy"):
         api.compile(generate("hub_small"), schedule="nope")
 
@@ -66,14 +68,10 @@ def test_strategy_jax_and_pallas_executors_agree(strategy):
     xr = ops.solve(prog, b, interpret=True, placement="resident")
     np.testing.assert_allclose(xr, ref, rtol=1e-5, atol=1e-5)
     plan = ops.plan_window(prog, 64)
-    if plan.feasible:
-        xb = ops.solve(prog, b, cycles_per_block=64, interpret=True,
-                       placement="blocked")
-        np.testing.assert_allclose(xb, ref, rtol=1e-5, atol=1e-5)
-    else:
-        # level-set packing interleaves distant rows, so its envelope
-        # can legitimately admit no window; the SPT205 lint covers it
-        assert strategy == "level", plan.reason
+    assert plan.feasible, plan.reason
+    xb = ops.solve(prog, b, cycles_per_block=64, interpret=True,
+                   placement="blocked")
+    np.testing.assert_allclose(xb, ref, rtol=1e-5, atol=1e-5)
 
 
 # ------------------------------------------------ cost model + auto
@@ -139,20 +137,20 @@ def test_program_cache_keys_separate_strategies():
 
 
 # ------------------------------------------------ SPT208 frontier lint
-def _fake_costs(paper: int, level: int) -> dict:
+def _fake_costs(paper: int, locality: int) -> dict:
     return {s: {"strategy": s, "cycles": c, "stall_rows": 0,
                 "psum_spills": 0, "planes": 1}
-            for s, c in (("paper", paper), ("level", level))}
+            for s, c in (("paper", paper), ("locality", locality))}
 
 
 def test_spt208_fires_past_threshold_only():
     from repro.core.analysis import analyze_program
 
     prog = api.compile(generate("hub_small"))
-    prog.stats.schedule = "level"
-    prog.stats.schedule_costs = _fake_costs(paper=100, level=150)
+    prog.stats.schedule = "locality"
+    prog.stats.schedule_costs = _fake_costs(paper=100, locality=150)
     assert "SPT208" in analyze_program(prog).codes()
-    prog.stats.schedule_costs = _fake_costs(paper=100, level=105)
+    prog.stats.schedule_costs = _fake_costs(paper=100, locality=105)
     assert "SPT208" not in analyze_program(prog).codes()  # within 10%
 
 
