@@ -67,10 +67,10 @@ def main() -> None:
             "resident_state_bytes": sptrsv_ops.state_bytes(
                 prog, BATCH, placement="resident")["xb"],
             "blocked_state_bytes": plan.state_bytes(BATCH),
-            # packed single-word encoding: double-buffered instruction SMEM
-            # (shared by both placements; was 3x larger with 5 planes)
+            # the blocked kernel's double-buffered instruction SMEM of
+            # lane-state words (was 3x larger with 5 unpacked planes)
             "instr_buffer_bytes": sptrsv_ops.instr_buffer_bytes(
-                prog, CYCLES_PER_BLOCK),
+                prog, CYCLES_PER_BLOCK, "blocked"),
             "instr_traffic_kib": round(prog.instr_bytes() / 1024, 1),
         }
         for label, solver in solvers.items():

@@ -22,12 +22,15 @@ memory provides: values are pre-gathered per instruction word so the kernel
 streams them sequentially (no positional indirection, as in the paper's
 stream-memory design), and the compiler's packed instruction words
 (``Program.instr``, ``[T, planes, P]`` int32 — DESIGN.md §Perf,
-"Instruction encoding") are compacted per cycle block to the words that do
-something, staged pre-decoded as the rows each access uses (`kernel`'s
-module docstring), padded to one segment length K, and flattened, so each
-block arrives in SMEM with a single DMA per stream.  Per kept entry the
-kernel streams ``4 * STREAM_PLANES + 4`` bytes (planes, value); no-op lane
-slots are neither streamed nor executed.
+"Instruction encoding") are decoded once into the rows each access uses
+(`kernel`'s module docstring) and flattened, so each block arrives in SMEM
+with a single DMA per stream.  The resident kernel gets the row-serial
+stream (`_stage_rows`): the rows in the order of their FINALs, in blocks of
+`kernel.ROW_BLOCK` entries of ``4 * ROW_PLANES + 4`` bytes.  The blocked
+kernel gets the lane-state stream (`_stage_instructions`): per cycle block
+the words that do something, padded to one segment length K, in entries of
+``4 * STREAM_PLANES + 4`` bytes.  No-op lane slots are neither streamed nor
+executed.
 """
 
 from __future__ import annotations
@@ -57,12 +60,16 @@ from repro.core.program import (
 from repro.kernels.common import resolve_interpret
 
 from .kernel import (
+    DST,
     EDGE,
     FB,
     PARK,
     PV_SRC,
     ROW,
+    ROW_BLOCK,
+    ROW_PLANES,
     SEGMENT_ALIGN,
+    SRC,
     STREAM_PLANES,
     UNROLL,
     X_DST,
@@ -240,14 +247,16 @@ def _pad_to(arr: np.ndarray, t_pad: int, fill=0) -> np.ndarray:
 
 @dataclasses.dataclass(frozen=True)
 class Stream:
-    """A program staged for the kernel: its active words, compacted.
+    """A program staged for a kernel: its entries, compacted, in blocks.
 
-    Cycle block g (``cycles_per_block`` cycles) keeps only the words with op
-    not NOP or psum control not KEEP, in cycle-major, lane-minor order, in a
-    segment of ``k`` entries (a `kernel.SEGMENT_ALIGN` multiple) padded with
-    the filler entry (`_filler`).  ``instr`` is ``[G, STREAM_PLANES, k]``
-    flattened (the planes `kernel.ROW` .. `kernel.EDGE`), ``values``
-    ``[G, k]`` flattened, ``counts[g]`` the active entries of block g.
+    ``instr`` is ``[G, planes, k]`` flattened, ``values`` ``[G, k]``
+    flattened, ``counts[g]`` the entries of block g; ``k`` is a
+    `kernel.SEGMENT_ALIGN` multiple and each block is padded with a filler
+    entry.  Lane-state streams (`_stage_instructions`): block g holds the
+    active words of ``cycles_per_block`` cycles in the planes `kernel.ROW`
+    .. `kernel.EDGE`.  Row-serial streams (`_stage_rows`): blocks of ``k``
+    entries, only the last one short, in the planes `kernel.SRC` and
+    `kernel.DST`.
     """
 
     instr: np.ndarray
@@ -317,7 +326,8 @@ def _filler(p: int, num_slots: int, spare: int) -> np.ndarray:
 
 def _stage_instructions(prog: Program, cycles_per_block: int,
                         plan: WindowPlan | None = None) -> Stream:
-    """Compact the packed instruction words and pre-gather the values.
+    """Stage the blocked kernel's lane-state stream: compact the packed
+    instruction words and pre-gather the values.
 
     The program already carries the packed ``[T, planes, P]`` words — the
     pack happens once at compile time; staging drops the no-op lane slots
@@ -362,16 +372,162 @@ def _stage_instructions(prog: Program, cycles_per_block: int,
                   slot_words=g * tb * p)
 
 
-def instr_buffer_bytes(prog: Program, cycles_per_block: int = 128) -> int:
-    """SMEM bytes of the kernel's double-buffered instruction streaming.
+def _row_chains(prog: Program) -> tuple[np.ndarray, np.ndarray]:
+    """``(t, lane)`` of the words the row-serial stream runs, in its order.
 
-    Two segment buffers of K entries, each entry its `kernel.STREAM_PLANES`
-    int32 planes and its pre-gathered f32 value: ``2 * K * (4 *
-    STREAM_PLANES + 4)``, where K is the largest cycle block's count of
-    active words rounded up to `kernel.SEGMENT_ALIGN` (1024).  At worst
-    (every lane active in every cycle) K is ``cycles_per_block * P``: 448
-    KiB at 128 cycles of 64 lanes.  A TPU v5e core has 1 MiB of SMEM.
+    Follows each lane's psum control (the mux of `executor.execute_numpy`)
+    once.  A lane's active words fall into segments: one starts at a word
+    whose psum control is not KEEP, or at the lane's first word, and runs
+    over the KEEP words after it.  A segment's running sum starts from zero
+    (RESET, STORE_RESET, a lane's first word, a slot never parked into) or
+    from the sum parked in its slot (LOAD, SWAP) by the last STORE_RESET or
+    SWAP there, which is the parking lane's segment before it.  So the
+    chain a FINAL ends is the path of segments from one that started from
+    zero to the FINAL's own, and its row runs that path's EDGE words in
+    stream order, then the FINAL.  Rows come in the order of their FINALs.
+    Psum-only words (op NOP) do no arithmetic and are dropped, as are sums
+    that no FINAL ends.  A word that continues a chain after its FINAL
+    raises ``ValueError``.
     """
+    op, _, ctl, slot = decode_instructions(prog.instr, prog.planes)
+    t, lane = np.nonzero((op != OP_NOP) | (ctl != PS_KEEP))  # cycle-major
+    op, ctl = op[t, lane], ctl[t, lane]
+    slot = np.minimum(slot[t, lane], _psum_slots(prog) - 1)
+
+    # segments, numbered in the order of their first words
+    by_lane = np.argsort(lane, kind="stable")
+    starts = ctl[by_lane] != PS_KEEP
+    starts[np.flatnonzero(np.diff(lane[by_lane], prepend=-1))] = True
+    first = by_lane[starts]
+    rank = np.empty(len(first), np.int64)
+    rank[np.argsort(first)] = np.arange(len(first))
+    seg = np.empty(len(t), np.int64)
+    seg[by_lane] = rank[np.cumsum(starts) - 1]
+    first = np.sort(first)
+
+    # each segment's op words, in stream order; a FINAL ends its segment
+    kept = np.flatnonzero(op != OP_NOP)
+    kept = kept[np.argsort(seg[kept], kind="stable")]
+    kseg = seg[kept]
+    size = np.bincount(kseg, minlength=len(first))
+    begin = np.cumsum(size) - size
+    fin = op[kept] == OP_FINAL
+    after = np.flatnonzero(fin & (np.arange(len(kept))
+                                  != begin[kseg] + size[kseg] - 1))
+    ended = np.zeros(len(first), bool)
+    ended[kseg[fin]] = True
+
+    def refuse(w):
+        raise ValueError(
+            f"word at cycle {t[w]}, lane {lane[w]} continues a running sum "
+            f"after the FINAL that ended it")
+
+    if len(after):
+        refuse(kept[after[0] + 1])
+    parent = np.full(len(first), -1, np.int64)
+    last, parked = {}, {}
+    for s, (c, ln, q) in enumerate(zip(ctl[first].tolist(),
+                                       lane[first].tolist(),
+                                       slot[first].tolist())):
+        if c in (PS_LOAD, PS_SWAP):
+            up = parent[s] = parked.get((ln, q), -1)
+            if up >= 0 and ended[up]:
+                ended[s] = True     # a dead sum stays dead
+                if size[s]:
+                    refuse(kept[begin[s]])
+        if c in (PS_STORE_RESET, PS_SWAP):
+            parked[(ln, q)] = last.get(ln, -1)
+        last[ln] = s
+
+    # each row's path of segments, root first
+    idx = np.arange(fin.sum())
+    cur = kseg[fin][np.argsort(kept[fin])]     # FINAL segments, FINAL order
+    row_of, seg_of, depth = [idx], [cur], [np.zeros(len(cur), np.int64)]
+    while (up := parent[cur] >= 0).any():
+        idx, cur = idx[up], parent[cur[up]]
+        row_of.append(idx)
+        seg_of.append(cur)
+        depth.append(np.full(len(cur), len(depth)))
+    path = np.concatenate(seg_of)[np.lexsort((-np.concatenate(depth),
+                                              np.concatenate(row_of)))]
+    n = size[path]
+    pos = np.repeat(begin[path] - (np.cumsum(n) - n), n) + np.arange(n.sum())
+    return t[kept[pos]], lane[kept[pos]]
+
+
+def _stage_rows(prog: Program, cycles_per_block: int = 128) -> Stream:
+    """Stage the resident kernel's row-serial stream (`kernel` docstring).
+
+    The words of `_row_chains`, each decoded into its `kernel.ROW_PLANES`
+    planes (`kernel.SRC`, `kernel.DST`) and its pre-gathered value, cut
+    into blocks of ``k`` entries, only the last one short and padded with
+    the filler entry (the spare row as source and destination, value 0: a
+    FINAL of the spare row, which stores only there and leaves a zero
+    sum).
+    ``cycles_per_block`` counts the uncompacted lane slots (``slot_words``)
+    only.  Raises ``ValueError`` on a row outside the x buffer, a row
+    finalised twice, or an EDGE reading a row whose FINAL does not come
+    earlier in this order.
+    """
+    t, lane = _row_chains(prog)
+    op, src, _, _ = (f[:, 0] for f in decode_instructions(
+        prog.instr[t, :, lane][..., None], prog.planes))
+    src = src.astype(np.int64)
+    fin = op == OP_FINAL
+    spare = _resident_rows(prog) - 1
+    bad = np.flatnonzero((src < 0) | (src >= spare))
+    if len(bad):
+        i = bad[0]
+        raise ValueError(
+            f"word at cycle {t[i]}, lane {lane[i]} addresses x row {src[i]}, "
+            f"outside the {spare} rows its x buffer holds")
+    m = len(src)
+    done = np.full(spare, m, np.int64)     # stream position of each FINAL
+    rows, first = np.unique(src[fin], return_index=True)
+    if len(rows) < fin.sum():
+        twice = np.setdiff1d(np.arange(fin.sum()), first)[0]
+        raise ValueError(f"x row {src[fin][twice]} is finalised twice")
+    done[rows] = np.flatnonzero(fin)[first]
+    early = np.flatnonzero(~fin & (done[src] > np.arange(m)))
+    if len(early):
+        i = early[0]
+        raise ValueError(
+            f"EDGE at cycle {t[i]}, lane {lane[i]} reads x row {src[i]}, "
+            f"whose FINAL does not come before it")
+
+    k = min(ROW_BLOCK, _round_up(max(m, 1), SEGMENT_ALIGN))
+    g = max(-(-m // k), 1)
+    instr = np.empty((ROW_PLANES, g * k), np.int32)
+    instr[:, m:] = spare
+    instr[SRC, :m] = src
+    instr[DST, :m] = np.where(fin, src, spare)
+    vals = np.zeros(g * k, np.float32)
+    vals[:m] = prog.stream[prog.val_idx[t, lane]]
+    counts = np.full(g, k, np.int32)
+    counts[-1] = m - (g - 1) * k
+    return Stream(
+        instr=instr.reshape(ROW_PLANES, g, k).transpose(1, 0, 2).reshape(-1),
+        values=vals, counts=counts, k=k,
+        slot_words=_round_up(prog.cycles, cycles_per_block) * prog.num_cus)
+
+
+def instr_buffer_bytes(prog: Program, cycles_per_block: int = 128,
+                       placement: str = "resident") -> int:
+    """SMEM bytes of a kernel's double-buffered instruction streaming.
+
+    Two segment buffers of K entries, each entry its int32 planes and its
+    pre-gathered f32 value.  The resident kernel's row-serial stream has
+    `kernel.ROW_PLANES` planes and K of at most `kernel.ROW_BLOCK` entries
+    (192 KiB).  The blocked kernel's lane-state stream has
+    `kernel.STREAM_PLANES` planes and K the largest cycle block's count of
+    active words rounded up to `kernel.SEGMENT_ALIGN` (1024): at worst
+    (every lane active in every cycle) ``cycles_per_block * P``, 448 KiB at
+    128 cycles of 64 lanes.  A TPU v5e core has 1 MiB of SMEM.
+    """
+    if placement == "resident":
+        return 2 * _stage_rows(prog, cycles_per_block).k * (4 * ROW_PLANES + 4)
+    if placement != "blocked":
+        raise ValueError(f"unknown placement {placement!r}")
     _, active = _active_blocks(prog, cycles_per_block)
     return 2 * _segment_len(active) * (4 * STREAM_PLANES + 4)
 
@@ -384,24 +540,22 @@ def state_bytes(prog: Program, nb: int, *, placement: str,
     Returns ``{"xb", "vmem", "instr"}`` for ``nb`` RHS columns under
     ``placement`` (``"blocked"`` needs the `WindowPlan`): ``xb`` is the
     solve state (resident: the one x buffer b is copied into; blocked: the
-    x and b windows), ``vmem`` all of the kernel's VMEM (the solve state
-    plus the feedback/psum lane state), ``instr`` the SMEM instruction
-    buffers.
+    x and b windows), ``vmem`` all of the kernel's VMEM (the solve state,
+    plus the blocked kernel's feedback/psum lane state; the resident kernel
+    has none), ``instr`` the SMEM instruction buffers.
     """
-    slots = _psum_slots(prog)
     if placement == "blocked":
         if plan is None or not plan.feasible:
             raise ValueError("blocked accounting needs a feasible WindowPlan")
         xb = plan.state_bytes(nb)
-        vmem = blocked_state_bytes(plan.window, nb, prog.num_cus, slots)
+        vmem = blocked_state_bytes(plan.window, nb, prog.num_cus,
+                                   _psum_slots(prog))
     elif placement == "resident":
-        rows = _resident_rows(prog)
-        xb = tiled_bytes(rows, nb)
-        vmem = resident_state_bytes(rows, nb, prog.num_cus, slots)
+        xb = vmem = resident_state_bytes(_resident_rows(prog), nb)
     else:
         raise ValueError(f"unknown placement {placement!r}")
     return {"xb": xb, "vmem": vmem,
-            "instr": instr_buffer_bytes(prog, cycles_per_block)}
+            "instr": instr_buffer_bytes(prog, cycles_per_block, placement)}
 
 
 def build_solver_cols(
@@ -430,26 +584,29 @@ def build_solver_cols(
         prog, width, placement=placement, vmem_limit_bytes=vmem_limit_bytes,
         cycles_per_block=cycles_per_block, x_block_rows=x_block_rows,
     )
-    stream = _stage_instructions(prog, cycles_per_block, plan)
+    if mode == "resident":
+        stream = _stage_rows(prog, cycles_per_block)
+        n_rows = _resident_rows(prog)
+    else:
+        stream = _stage_instructions(prog, cycles_per_block, plan)
+        n_rows = plan.n_hbm
     instr = jnp.asarray(stream.instr)
     values = jnp.asarray(stream.values)
     counts = jnp.asarray(stream.counts)
     n = prog.n
-    n_slots = _psum_slots(prog)
-    n_rows = _resident_rows(prog) if mode == "resident" else plan.n_hbm
     interpret = resolve_interpret(interpret)
-    kw = dict(num_cus=prog.num_cus, num_slots=n_slots, interpret=interpret)
 
     @jax.jit  # fold the pad/slice into the kernel dispatch
     def solve_cols(bmat: jnp.ndarray) -> jnp.ndarray:
         bp = jnp.zeros((n_rows, width), jnp.float32)
         bp = bp.at[:n].set(jnp.asarray(bmat, jnp.float32))
         if mode == "resident":
-            x = sptrsv_pallas(instr, values, counts, bp, **kw)
+            x = sptrsv_pallas(instr, values, counts, bp, interpret=interpret)
         else:
-            x = sptrsv_pallas_blocked(instr, values, counts, bp,
-                                      window=plan.window, stride=plan.stride,
-                                      **kw)
+            x = sptrsv_pallas_blocked(
+                instr, values, counts, bp, num_cus=prog.num_cus,
+                num_slots=_psum_slots(prog), window=plan.window,
+                stride=plan.stride, interpret=interpret)
         return x[:n]
 
     solve_cols.placement = mode

@@ -18,7 +18,7 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from repro.core import api
+from repro.core import api, executor
 from repro.core.csr import random_rhs, serial_solve
 from repro.core.matrices import generate
 from repro.core.program import (
@@ -229,18 +229,25 @@ def test_vmem_instruction_buffers_halved():
     halved by the packed encoding (it is 3x smaller: 8 vs 24 B)."""
     from repro.kernels.sptrsv import ops
 
+    from repro.kernels.sptrsv import kernel
+
     prog = api.compile(generate("band_cz"))
-    now = ops.instr_buffer_bytes(prog, 128)
+    # the lane-state stream (blocked kernel) streams per-lane words
+    now = ops.instr_buffer_bytes(prog, 128, "blocked")
     five_plane = 2 * 128 * prog.num_cus * 24
     assert now * 2 <= five_plane
+    # the instruction buffers live in SMEM; the resident kernel's VMEM is
+    # its x buffer alone, the blocked kernel's adds the lane state
     acct = ops.state_bytes(prog, 8, placement="resident")
-    # the instruction buffers live in SMEM; VMEM holds x+b plus lane state
-    assert acct["instr"] == now and acct["vmem"] > acct["xb"]
+    assert acct["instr"] == ops.instr_buffer_bytes(prog, 128)
+    assert acct["vmem"] == acct["xb"]
     plan = ops.plan_window(prog, 64)
     acct_b = ops.state_bytes(prog, 8, placement="blocked", plan=plan,
                              cycles_per_block=64)
     assert acct_b["xb"] == plan.state_bytes(8)
-    assert acct_b["vmem"] - acct_b["xb"] == acct["vmem"] - acct["xb"]
+    assert acct_b["instr"] == ops.instr_buffer_bytes(prog, 64, "blocked")
+    lanes = kernel.lane_rows(prog.num_cus, executor._psum_slots(prog))
+    assert acct_b["vmem"] - acct_b["xb"] == kernel.tiled_bytes(lanes, 8)
 
 
 def test_instruction_breakdown_smoke():

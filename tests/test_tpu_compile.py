@@ -64,14 +64,20 @@ def progs():
             for name in ("ckt_add20", "band_huge64k")}
 
 
-def _kernel_args(prog, rows, one_chip, batch=B):
-    stream = ops._stage_instructions(prog, CPB)
+def _kernel_args(prog, rows, one_chip, batch=B, blocked=False):
+    """Argument shapes of the resident kernel (its row-serial stream) or,
+    with ``blocked``, of the blocked kernel (its lane-state stream)."""
+    if blocked:
+        stream = ops._stage_instructions(prog, CPB)
+        kw = dict(num_cus=prog.num_cus, num_slots=executor._psum_slots(prog),
+                  interpret=False)
+    else:
+        stream = ops._stage_rows(prog, CPB)
+        kw = dict(interpret=False)
 
     def spec(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    kw = dict(num_cus=prog.num_cus, num_slots=executor._psum_slots(prog),
-              interpret=False)
     return (spec(stream.instr), spec(stream.values), spec(stream.counts),
             spec(np.zeros((rows, batch), np.float32))), kw
 
@@ -98,10 +104,8 @@ def test_resident_kernel_compiles_at_hpcg_rows(progs, one_chip,
     args, kw = _kernel_args(prog, rows, one_chip, batch=1)
     compiled = kernel.sptrsv_pallas.lower(*args, **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    state = kernel.resident_state_bytes(rows, 1, prog.num_cus,
-                                        kw["num_slots"])
-    assert state == kernel.tiled_bytes(rows, 1) + kernel.resident_state_bytes(
-        0, 1, prog.num_cus, kw["num_slots"])
+    state = kernel.resident_state_bytes(rows, 1)
+    assert state == kernel.tiled_bytes(rows, 1)  # no lane state
     assert state <= 60 * 10 ** 6
     assert state < kernel.vmem_limit(state) <= V5E_VMEM
 
@@ -110,7 +114,7 @@ def test_blocked_kernel_compiles_for_v5e(progs, one_chip, no_compile_cache):
     prog = progs["band_huge64k"]
     mode, plan = ops.resolve_placement(prog, B)
     assert mode == "blocked"  # auto: x+b of 64k rows is past the threshold
-    args, kw = _kernel_args(prog, plan.n_hbm, one_chip)
+    args, kw = _kernel_args(prog, plan.n_hbm, one_chip, blocked=True)
     compiled = kernel.sptrsv_pallas_blocked.lower(
         *args, window=plan.window, stride=plan.stride, **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
