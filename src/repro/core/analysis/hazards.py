@@ -88,6 +88,14 @@ def packed_structure(prog: Program):
                           f"{stream.shape}"))
         return diags, False, False
 
+    perm = prog.row_perm
+    if perm is not None and (
+            np.asarray(perm).shape != (prog.n,)
+            or not np.array_equal(np.sort(perm), np.arange(prog.n))):
+        diags.append(_err("SPT101", f"row_perm is not a permutation of the "
+                          f"{prog.n} rows", hint="recompile; do not execute"))
+        return diags, False, False
+
     values_ok = True
     if not np.isfinite(stream).all():
         bad = int(np.count_nonzero(~np.isfinite(stream)))

@@ -136,7 +136,13 @@ def compile(mat: TriCSR, cfg: AccelConfig | None = None, *,  # noqa: A001
             schedule: str = "paper",
             verify_ir: bool = False) -> Program:
     """Compile ``mat``; ``schedule="auto"`` picks the predicted-cheapest
-    scheduler strategy per matrix (`compiler.strategies`, DESIGN.md §11)."""
+    scheduler strategy per matrix (`compiler.strategies`, DESIGN.md §11).
+
+    When one column of x cannot stay in a core's VMEM the compiler relabels
+    rows by DAG level, so the Pallas kernel's row window can slide
+    (DESIGN.md §1); solves take b and return x in the matrix's order
+    either way.
+    """
     return compile_program(mat, cfg, schedule=schedule, verify_ir=verify_ir)
 
 
@@ -242,7 +248,9 @@ class CompiledWorkload:
     unknowns (e.g. the reversed upper-triangular solve) carry ``perm``:
     internal node ``k`` solves user unknown ``perm[k]``, so the program
     consumes ``b[perm]`` and its solution scatters back through ``perm``.
-    ``perm=None`` means the identity (lower-tri, circuits).
+    ``perm=None`` means the identity (lower-tri, circuits).  It composes
+    with the program's own row order (``Program.row_perm``), which every
+    executor applies inside the solve.
 
     `solve` accepts ``[n]`` or ``[n, B]`` right-hand sides and runs any
     executor: ``backend`` in {"numpy", "jax", "pallas"} plus the usual

@@ -3,8 +3,8 @@
 Replaces the historical monolithic SpTRSV compiler with an
 explicit pass pipeline over documented IR dataclasses (`ir.py`)::
 
-    ComputeDag → partition → cu-assign → psum-cache schedule (+ per-cycle
-    ICR reorder) → stall-elide → pack/emit → Program
+    ComputeDag → level order → partition → cu-assign → psum-cache schedule
+    (+ per-cycle ICR reorder) → stall-elide → pack/emit → Program
 
 `compile_dag` is the generic entry point: it accepts any workload lowered
 to the `ComputeDag` frontend contract (`core/frontends/`) and emits the
@@ -29,6 +29,7 @@ from jax.profiler import TraceAnnotation
 
 from ..program import AccelConfig, Program
 from . import assign, elide, emit, partition, sched, strategies
+from . import order as row_order
 from .ir import (  # noqa: F401  (re-exported IR surface)
     AssignIR,
     ComputeDag,
@@ -54,8 +55,8 @@ __all__ = [
     "MAX_PSUM_SLOT",
 ]
 
-PASS_NAMES = ("partition", "cu_assign", "psum_schedule", "icr_reorder",
-              "stall_elide", "pack_emit")
+PASS_NAMES = ("level_order", "partition", "cu_assign", "psum_schedule",
+              "icr_reorder", "stall_elide", "pack_emit")
 
 
 def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
@@ -63,6 +64,12 @@ def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
                 schedule: str = "paper",
                 verify_ir: bool = False) -> Program:
     """Compile a `ComputeDag` workload into a packed VLIW `Program`.
+
+    The first pass picks the row order (`order.py`): it relabels nodes by
+    DAG level only when one column of x cannot stay in a core's VMEM.  A
+    relabelled program carries ``row_perm`` (its node k is the workload's
+    node ``row_perm[k]``); every executor takes b and returns x in the
+    workload's order.
 
     ``planes`` forces the packed-word layout (1 = single-word, 2 = the
     large-n fallback); ``None`` auto-selects via `program.packed_planes`.
@@ -111,6 +118,10 @@ def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
         return out, time.perf_counter() - t
 
     _check(lambda: contracts.verify_frontend(dag), "frontend")
+    (dag, perm, edge, order_metrics), t_order = _timed(
+        "level_order", row_order.run, dag)
+    if perm is not None:
+        _check(lambda: contracts.verify_frontend(dag), "level_order")
     pir, t_part = _timed("partition", partition.run, dag)
     _check(lambda: contracts.verify_partition(pir), "partition")
     air, t_assign = _timed("cu_assign", assign.run, pir, cfg)
@@ -143,7 +154,12 @@ def compile_dag(dag: ComputeDag, cfg: AccelConfig | None = None, *,
     # metrics in the trace, reported here as its own stage
     t_icr = sir.icr_metrics.get("seconds", 0.0)
     icr_metrics = {k: v for k, v in sir.icr_metrics.items() if k != "seconds"}
+    if perm is not None:
+        prog.row_perm = perm
+        prog.stream_src = row_order.user_provenance(prog.stream_src, perm,
+                                                    edge)
     prog.stats.pass_stats = [
+        PassStats("level_order", t_order, order_metrics),
         PassStats("partition", t_part, pir.metrics),
         PassStats("cu_assign", t_assign, air.metrics),
         PassStats("psum_schedule", t_sched - t_icr, sir.metrics),
@@ -172,7 +188,8 @@ def compile_program(mat, cfg: AccelConfig | None = None, *,
 
     Lowers ``mat`` through the SpTRSV frontend
     (`frontends.sptrsv.lower_tri`) and runs `compile_dag` with the same
-    keywords.  The frozen copy of the original monolithic compiler in
+    keywords; a level-ordered program compiles ``P L Pᵀ`` for the level
+    permutation P.  The frozen copy of the original monolithic compiler in
     `tests/legacy_schedule.py` pins its output bit for bit.
     """
     from ..frontends.sptrsv import lower_tri

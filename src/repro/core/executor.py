@@ -86,6 +86,7 @@ __all__ = [
     "make_jax_executor",
     "make_pallas_executor",
     "pad_batch",
+    "permute_rows",
     "trace_count",
     "validate_backend",
 ]
@@ -148,6 +149,9 @@ def execute_numpy(prog: Program, b: np.ndarray) -> np.ndarray:
     """
     bmat, single = as_batch(b, dtype=np.float64)
     nb = bmat.shape[1]
+    perm = prog.row_perm
+    if perm is not None:
+        bmat = bmat[perm]
 
     n, p = prog.n, prog.num_cus
     x = np.zeros((n + 1, nb), dtype=np.float64)
@@ -183,6 +187,9 @@ def execute_numpy(prog: Program, b: np.ndarray) -> np.ndarray:
             x[src[fin]] = (bmat[src[fin]] - pv[fin]) * v[fin]
         feedback = pv
     xr = x[:n]
+    if perm is not None:
+        xr = np.empty_like(xr)
+        xr[perm] = x[:n]
     return xr[:, 0] if single else xr
 
 
@@ -196,6 +203,8 @@ def build_solve_cols(prog: Program, width: int):
     This is the trace target shared by the local jit path below and the
     multi-device `shard_map` path (`repro.core.shard`), which maps it over
     per-device column blocks with the instruction constants replicated.
+    A program with a ``row_perm`` gathers b into its row order and x back
+    out of it inside the trace (`permute_rows`).
     """
     n, p = prog.n, prog.num_cus
     planes = prog.planes
@@ -244,7 +253,30 @@ def build_solve_cols(prog: Program, width: int):
         (x, _, _), _ = jax.lax.scan(step, (x0, f0, rf0), (instr_words, vidx))
         return x[:n]
 
-    return solve_cols
+    return permute_rows(prog, solve_cols)
+
+
+def permute_rows(prog: Program, solve_cols):
+    """Wrap an internal-order ``solve_cols`` so that it takes b and returns
+    x in the workload's row order: two row gathers by constant indices,
+    traced into the caller's jit under the name scope ``sptrsv.row_perm``
+    (in each op's HLO metadata).  A program in its workload's order gets
+    ``solve_cols`` back unchanged, so its trace gains nothing."""
+    if prog.row_perm is None:
+        return solve_cols
+    perm = np.asarray(prog.row_perm, np.int32)
+    inv = np.empty_like(perm)
+    inv[perm] = np.arange(perm.size, dtype=np.int32)
+
+    def rows(a, idx):
+        with jax.named_scope("sptrsv.row_perm"):
+            return a.at[jnp.asarray(idx)].get(mode="promise_in_bounds",
+                                               unique_indices=True)
+
+    def solve_user(b):
+        return rows(solve_cols(rows(b, perm)), inv)
+
+    return solve_user
 
 
 def _build_jax_executor(prog: Program, width: int):

@@ -5,8 +5,9 @@ generate matrices spanning the same *structural archetypes* as the paper's
 Table III (FEM bands, circuit Jacobians, power networks, chemical-process
 chains, near-empty wide DAGs).  Every generator produces a well-conditioned
 lower-triangular system (unit-ish diagonal, bounded off-diagonals) so the
-f32 executor comparison against the f64 oracle stays tight.  `stencil27` is
-the exception to "archetype": it is HPCG's own problem, pattern and values.
+f32 executor comparison against the f64 oracle stays tight.  `stencil27` and
+`laplace7_ic0` are the exceptions to "archetype": HPCG's own problem, and the
+IC(0) factor of hypre's 7-point Laplacian, pattern and values.
 """
 
 from __future__ import annotations
@@ -18,7 +19,7 @@ import numpy as np
 from .csr import TriCSR, from_coo
 
 __all__ = ["SUITE", "generate", "suite_names", "paper_like_suite",
-           "stencil27", "stencil27_coo"]
+           "stencil27", "stencil27_coo", "laplace7_ic0", "laplace7_ic0_coo"]
 
 
 def _finish(n, rows, cols, rng, name, scale=0.5) -> TriCSR:
@@ -199,6 +200,46 @@ def stencil27(nx: int, name: str) -> TriCSR:
     return from_coo(nx ** 3, *stencil27_coo(nx), name=name)
 
 
+def laplace7_ic0_coo(nx: int):
+    """The IC(0) factor L̃ of the 3D 7-point Laplacian on an nx³ grid
+    (hypre ``ij -laplacian``: diagonal 6, every in-grid neighbour -1, rows
+    in natural order, x fastest).
+
+    The 7-point graph has no triangles, so the incomplete Cholesky factor
+    with no fill has a closed form: with ``d_i = 6 - Σ_j 1/d_j`` over the
+    lower neighbours j of i, ``L̃_ii = √d_i`` and ``L̃_ij = -1/√d_j``, and
+    ``L̃ L̃ᵀ`` equals A on A's pattern.  ``d`` is computed one wavefront
+    (``ix + iy + iz``) at a time, since a row depends only on the one
+    before it.  Returns ``(rows, cols, vals, diag)`` as `stencil27_coo`.
+    """
+    n = nx ** 3
+    g = np.arange(n, dtype=np.int64)
+    ix, iy, iz = g % nx, (g // nx) % nx, g // (nx * nx)
+    offs = ((ix > 0, 1), (iy > 0, nx), (iz > 0, nx * nx))
+    inv_d = np.zeros(n)
+    front = np.argsort(ix + iy + iz, kind="stable")
+    ends = np.cumsum(np.bincount(ix + iy + iz))
+    for lo, hi in zip(ends - np.diff(ends, prepend=0), ends):
+        r = front[lo:hi]
+        d = np.full(r.size, 6.0)
+        for has, off in offs:
+            d -= np.where(has[r], inv_d[r - off * has[r]], 0.0)
+        inv_d[r] = 1.0 / d
+    rows, cols = [], []
+    for has, off in offs:
+        rows.append(g[has])
+        cols.append(g[has] - off)
+    rows, cols = np.concatenate(rows), np.concatenate(cols)
+    order = np.lexsort((cols, rows))
+    rows, cols = rows[order], cols[order]
+    return rows, cols, -np.sqrt(inv_d[cols]), 1.0 / np.sqrt(inv_d)
+
+
+def laplace7_ic0(nx: int, name: str) -> TriCSR:
+    """The IC(0) factor of the 7-point Laplacian on an nx³ grid."""
+    return from_coo(nx ** 3, *laplace7_ic0_coo(nx), name=name)
+
+
 # ---------------------------------------------------------------------------
 # Registry.  Sizes bracket the paper's Table III (n = 628 .. 7479) plus larger
 # entries toward the 85k upper end of the 245-matrix sweep, and one HPCG
@@ -258,6 +299,11 @@ def _build_suite() -> None:
     # hpcg_symgs48.json says why 48)
     _reg("hpcg_8", lambda: stencil27(8, "hpcg_8"))
     _reg("hpcg_symgs48", lambda: stencil27(48, "hpcg_symgs48"))
+    # ICCG's preconditioner on the 3D 7-point Laplacian: a small grid, and
+    # the benchmark's 72³ local grid, whose x does not fit a core's VMEM
+    # (benchmarks/chip/configs/lap7_ic0_72.json says why 72)
+    _reg("lap7_ic0_8", lambda: laplace7_ic0(8, "lap7_ic0_8"))
+    _reg("lap7_ic0_72", lambda: laplace7_ic0(72, "lap7_ic0_72"))
 
 
 _build_suite()

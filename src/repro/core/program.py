@@ -319,6 +319,13 @@ class Program:
     # from this plane without rescheduling; None on pre-provenance
     # programs (they take the full recompile path).
     stream_src: np.ndarray | None = None  # [S] int64
+    # Row order the compiler chose (DESIGN.md §1, `compiler/order.py`):
+    # internal row k solves the workload's row row_perm[k]; None is the
+    # identity.  Every executor permutes b on the way in and x on the way
+    # out, so callers see the workload's order.  The row indices of
+    # `instr`, `row_lo` and `row_hi` are internal; `stream_src` indexes
+    # the workload as given.
+    row_perm: np.ndarray | None = None  # [n] int32
 
     @property
     def cycles(self) -> int:

@@ -60,6 +60,7 @@ _ARRAYS = (
     ("row_lo", False),
     ("row_hi", False),
     ("stream_src", False),  # value provenance (values-only recompile path)
+    ("row_perm", False),    # the compiler's row order (None: the identity)
 )
 _STATS_ARRAYS = (("per_cu_edges", False),)
 # ScheduleStats fields that do NOT round-trip as JSON scalars
@@ -193,6 +194,7 @@ def loads_program(data: bytes, *, verify: bool = True) -> Program:
             row_lo=arrays.get("row_lo"),
             row_hi=arrays.get("row_hi"),
             stream_src=arrays.get("stream_src"),
+            row_perm=arrays.get("row_perm"),
         )
     except (KeyError, TypeError) as e:
         raise _corrupt(f"header schema mismatch ({e})") from e

@@ -109,7 +109,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import resolve_interpret
+from repro.kernels.common import (
+    LANES,
+    resident_state_bytes,
+    resolve_interpret,
+    tiled_bytes,
+)
 
 __all__ = [
     "ROW_BLOCK",
@@ -121,13 +126,9 @@ __all__ = [
     "sptrsv_pallas_blocked",
     "blocked_state_bytes",
     "lane_rows",
-    "resident_state_bytes",
-    "tiled_bytes",
     "vmem_limit",
 ]
 
-# Mosaic lays a 2-D f32 VMEM array out in (8, 128) tiles.
-_SUBLANES, _LANES = 8, 128
 # Default scoped-VMEM limit of a TPU v5e kernel; a kernel whose accounted
 # state needs more raises its own limit (`vmem_limit`).
 _SCOPED_VMEM_DEFAULT = 16 << 20
@@ -151,11 +152,6 @@ ROW_PLANES = 2
 ROW_BLOCK = 8192
 
 
-def tiled_bytes(rows: int, cols: int) -> int:
-    """VMEM bytes of a ``[rows, cols]`` f32 array in (8, 128) tiles."""
-    return (-(-rows // _SUBLANES) * _SUBLANES) * (-(-cols // _LANES) * _LANES) * 4
-
-
 def vmem_limit(state_bytes: int) -> int:
     """Scoped-VMEM limit for a kernel whose solve state takes ``state_bytes``."""
     return max(_SCOPED_VMEM_DEFAULT, state_bytes + _VMEM_HEADROOM)
@@ -166,12 +162,6 @@ def lane_rows(p: int, num_slots: int) -> int:
     ``p * num_slots`` psum slot rows, the zero row and the trash row, in
     that order."""
     return p * (num_slots + 1) + 2
-
-
-def resident_state_bytes(n_pad: int, nb: int) -> int:
-    """VMEM bytes of the resident kernel: the x buffer (b in place); the
-    running sum is a loop value, not a ref."""
-    return tiled_bytes(n_pad, nb)
 
 
 def blocked_state_bytes(window: int, nb: int, p: int, num_slots: int) -> int:
@@ -494,7 +484,7 @@ def sptrsv_pallas_blocked(
     p = num_cus
     num_blocks, k = _stream_shape(instr, values, counts, STREAM_PLANES)
     n_hbm, nb = b.shape
-    lanes = -(-nb // _LANES) * _LANES
+    lanes = -(-nb // LANES) * LANES
     assert stride >= 1 and window >= 2 * stride, (window, stride)
     assert n_hbm == (num_blocks - 1) * stride + window, \
         f"b rows {n_hbm} != window sweep {(num_blocks - 1) * stride + window}"

@@ -14,8 +14,8 @@ resident x footprint is under ``vmem_limit_bytes``, blocked beyond it
 whenever the program's row-access envelope admits a sliding window
 (`plan_window`) whose x and b windows take less.
 Footprints are counted as the chip lays them out: a ``[rows, B]`` f32 array
-takes whole (8, 128) tiles (`kernel.tiled_bytes`), so at B=16 it occupies
-8x its logical bytes.
+takes whole (8, 128) tiles (`kernels.common.tiled_bytes`), so at B=16 it
+occupies 8x its logical bytes.
 
 The wrapper performs the compiler-side data staging the hardware's stream
 memory provides: values are pre-gathered per instruction word so the kernel
@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.errors import PlacementInfeasibleError
-from repro.core.executor import _psum_slots, as_batch
+from repro.core.executor import _psum_slots, as_batch, permute_rows
 from repro.core.program import (
     OP_EDGE,
     OP_FINAL,
@@ -57,7 +57,14 @@ from repro.core.program import (
     decode_instructions,
 )
 
-from repro.kernels.common import resolve_interpret
+from repro.kernels.common import (
+    VMEM_CAPACITY,
+    resident_fits,
+    resident_rows,
+    resident_state_bytes,
+    resolve_interpret,
+    tiled_bytes,
+)
 
 from .kernel import (
     DST,
@@ -75,10 +82,8 @@ from .kernel import (
     X_DST,
     blocked_state_bytes,
     lane_rows,
-    resident_state_bytes,
     sptrsv_pallas,
     sptrsv_pallas_blocked,
-    tiled_bytes,
 )
 
 __all__ = [
@@ -231,9 +236,8 @@ def resolve_placement(
 
 
 def _resident_rows(prog: Program) -> int:
-    """Rows of the resident x buffer: n and at least one spare row on top,
-    which takes the x stores of words that finalize nothing."""
-    return _round_up(prog.n + 1, _ROW_ALIGN)
+    """Rows of the resident x buffer (`kernels.common.resident_rows`)."""
+    return resident_rows(prog.n)
 
 
 def _pad_to(arr: np.ndarray, t_pad: int, fill=0) -> np.ndarray:
@@ -579,11 +583,26 @@ def build_solver_cols(
     (entries executed per solve) against ``closure.slot_words`` (the
     ``T_pad * P`` lane slots of the uncompacted grid), for tests and
     diagnostics.
+
+    A compiled resident solve whose x buffer cannot fit a core's VMEM
+    (`kernels.common.resident_fits`) raises `PlacementInfeasibleError`
+    naming its rows and bytes, before Mosaic is asked for it; the
+    interpreter, which has no VMEM, runs it.
     """
     mode, plan = resolve_placement(
         prog, width, placement=placement, vmem_limit_bytes=vmem_limit_bytes,
         cycles_per_block=cycles_per_block, x_block_rows=x_block_rows,
     )
+    interpret = resolve_interpret(interpret)
+    if mode == "resident" and not interpret and \
+            not resident_fits(prog.n, width):
+        rows = _resident_rows(prog)
+        vmem = resident_state_bytes(rows, width)
+        raise PlacementInfeasibleError(
+            f"resident x buffer of {rows} rows needs {vmem} bytes of VMEM, "
+            f"over the {VMEM_CAPACITY} a core has (placement={placement!r})",
+            detail={"rows": rows, "vmem_bytes": vmem,
+                    "vmem_capacity": VMEM_CAPACITY})
     if mode == "resident":
         stream = _stage_rows(prog, cycles_per_block)
         n_rows = _resident_rows(prog)
@@ -594,9 +613,7 @@ def build_solver_cols(
     values = jnp.asarray(stream.values)
     counts = jnp.asarray(stream.counts)
     n = prog.n
-    interpret = resolve_interpret(interpret)
 
-    @jax.jit  # fold the pad/slice into the kernel dispatch
     def solve_cols(bmat: jnp.ndarray) -> jnp.ndarray:
         bp = jnp.zeros((n_rows, width), jnp.float32)
         bp = bp.at[:n].set(jnp.asarray(bmat, jnp.float32))
@@ -609,6 +626,9 @@ def build_solver_cols(
                 stride=plan.stride, interpret=interpret)
         return x[:n]
 
+    # one jit folds the row permutation, the pad and the slice into the
+    # kernel dispatch
+    solve_cols = jax.jit(permute_rows(prog, solve_cols))
     solve_cols.placement = mode
     solve_cols.plan = plan
     solve_cols.interpret = interpret

@@ -14,7 +14,7 @@ from repro.core import api
 from repro.core.csr import random_rhs, serial_solve
 from repro.core.matrices import generate
 from repro.kernels.sptrsv import ops
-from repro.kernels.sptrsv.kernel import tiled_bytes
+from repro.kernels.common import tiled_bytes
 
 
 def _refs(mat, bmat):

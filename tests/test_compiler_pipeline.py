@@ -69,9 +69,10 @@ def test_pipeline_matches_legacy(name):
 @pytest.mark.slow
 def test_pipeline_matches_legacy_full_suite():
     """Acceptance: identical Program.instr/stats on the FULL bundled suite,
-    up to band_huge64k's 65,536 rows.  Past it is only hpcg_symgs48, the
-    benchmark's HPCG grid, over a minute per compile on a CPU; hpcg_8 is the
-    same generator at a test size."""
+    up to band_huge64k's 65,536 rows.  Past it are only the benchmark's
+    grids, hpcg_symgs48 and lap7_ic0_72, each up to a minute per compile
+    on a CPU; hpcg_8 and lap7_ic0_8 are the same generators at a test
+    size."""
     for name in suite_names(max_n=65_536):
         mat = generate(name)
         assert_programs_identical(

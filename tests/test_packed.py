@@ -229,6 +229,7 @@ def test_vmem_instruction_buffers_halved():
     halved by the packed encoding (it is 3x smaller: 8 vs 24 B)."""
     from repro.kernels.sptrsv import ops
 
+    from repro.kernels import common
     from repro.kernels.sptrsv import kernel
 
     prog = api.compile(generate("band_cz"))
@@ -247,7 +248,7 @@ def test_vmem_instruction_buffers_halved():
     assert acct_b["xb"] == plan.state_bytes(8)
     assert acct_b["instr"] == ops.instr_buffer_bytes(prog, 64, "blocked")
     lanes = kernel.lane_rows(prog.num_cus, executor._psum_slots(prog))
-    assert acct_b["vmem"] - acct_b["xb"] == kernel.tiled_bytes(lanes, 8)
+    assert acct_b["vmem"] - acct_b["xb"] == common.tiled_bytes(lanes, 8)
 
 
 def test_instruction_breakdown_smoke():

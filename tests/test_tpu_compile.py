@@ -18,8 +18,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
-from repro.core import api, executor, shard
+from repro.core import api, compiler, executor, shard
 from repro.core.matrices import generate
+from repro.kernels import common
 from repro.kernels.sptrsv import kernel, ops
 
 B = 16
@@ -104,8 +105,8 @@ def test_resident_kernel_compiles_at_hpcg_rows(progs, one_chip,
     args, kw = _kernel_args(prog, rows, one_chip, batch=1)
     compiled = kernel.sptrsv_pallas.lower(*args, **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    state = kernel.resident_state_bytes(rows, 1)
-    assert state == kernel.tiled_bytes(rows, 1)  # no lane state
+    state = common.resident_state_bytes(rows, 1)
+    assert state == common.tiled_bytes(rows, 1)  # no lane state
     assert state <= 60 * 10 ** 6
     assert state < kernel.vmem_limit(state) <= V5E_VMEM
 
@@ -128,6 +129,45 @@ def test_blocked_kernel_compiles_for_v5e(progs, one_chip, no_compile_cache):
     hbm = (mem.argument_size_in_bytes + mem.output_size_in_bytes
            + mem.temp_size_in_bytes)
     assert 0 < hbm < V5E_HBM
+
+
+def test_blocked_kernel_compiles_at_lap7_rows(one_chip, no_compile_cache):
+    """The blocked kernel at the geometry `plan_window` gives lap7_ic0_72 in
+    level order (373,248 rows: window 21,832 at stride 1,032 over 346
+    cycle blocks), one column, and the largest segment a 128-cycle block
+    can have (every lane active).  Shapes alone: nothing that large is
+    compiled by the program here."""
+    blocks, k, window, stride = 346, CPB * 64, 21832, 1032
+    rows = (blocks - 1) * stride + window
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (spec((blocks * kernel.STREAM_PLANES * k,), jnp.int32),
+            spec((blocks * k,), jnp.float32), spec((blocks,), jnp.int32),
+            spec((rows, 1), jnp.float32))
+    compiled = kernel.sptrsv_pallas_blocked.lower(
+        *args, num_cus=64, num_slots=12, window=window, stride=stride,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    state = kernel.blocked_state_bytes(window, 128, 64, 12)
+    assert state < kernel.vmem_limit(state) <= V5E_VMEM
+    assert 2 * k * (4 * kernel.STREAM_PLANES + 4) <= 1 << 20  # SMEM
+
+
+def test_level_ordered_solve_compiles_for_v5e(one_chip, no_compile_cache,
+                                              monkeypatch):
+    """A level-ordered program's whole jitted solve: b gathered into the
+    program's row order, the blocked kernel, x gathered back.  The level
+    order is forced at this small size."""
+    monkeypatch.setattr(compiler.order, "wants_level", lambda n: True)
+    prog = api.compile(generate("lap7_ic0_8"))
+    assert prog.row_perm is not None
+    solve = ops.build_solver_cols(prog, 1, cycles_per_block=32,
+                                  placement="blocked", interpret=False)
+    b = jax.ShapeDtypeStruct((prog.n, 1), jnp.float32, sharding=one_chip)
+    text = solve.lower(b).compile().as_text()
+    assert "tpu_custom_call" in text and "gather" in text
 
 
 def test_scan_executor_compiles_for_v5e(progs, one_chip, no_compile_cache):
