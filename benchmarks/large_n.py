@@ -34,7 +34,6 @@ from .common import emit, timeit
 
 DEFAULT_LADDER = ["band_cz", "band_wide4k", "band_big16k"]
 BATCH = 16
-CYCLES_PER_BLOCK = 128
 
 
 def main() -> None:
@@ -46,7 +45,7 @@ def main() -> None:
         mat = api.matrix(name)
         prog = api.compile(mat)
         bmat = rng.standard_normal((mat.n, BATCH)).astype(np.float32)
-        plan = sptrsv_ops.plan_window(prog, CYCLES_PER_BLOCK)
+        plan = sptrsv_ops.plan_window(prog)
         if not plan.feasible:
             print(f"# {name}: blocked placement infeasible ({plan.reason})")
             continue
@@ -55,8 +54,7 @@ def main() -> None:
         solvers = {"jax_scan": jax_solver}
         for placement in ("resident", "blocked"):
             solvers[placement] = make_pallas_executor(
-                prog, batch=BATCH, cycles_per_block=CYCLES_PER_BLOCK,
-                placement=placement,
+                prog, batch=BATCH, placement=placement,
             )
 
         row = {
@@ -67,10 +65,9 @@ def main() -> None:
             "resident_state_bytes": sptrsv_ops.state_bytes(
                 prog, BATCH, placement="resident")["xb"],
             "blocked_state_bytes": plan.state_bytes(BATCH),
-            # the blocked kernel's double-buffered instruction SMEM of
-            # lane-state words (was 3x larger with 5 unpacked planes)
-            "instr_buffer_bytes": sptrsv_ops.instr_buffer_bytes(
-                prog, CYCLES_PER_BLOCK, "blocked"),
+            # either kernel's double-buffered instruction SMEM of
+            # row-serial entries
+            "instr_buffer_bytes": sptrsv_ops.instr_buffer_bytes(prog),
             "instr_traffic_kib": round(prog.instr_bytes() / 1024, 1),
         }
         for label, solver in solvers.items():

@@ -5,9 +5,9 @@
     python chip_smoke.py --four-chips  # RHS columns sharded over four chips
 
 One chip: the VMEM-resident Pallas kernel on ``ckt_add20`` (n=2,395), the
-row-blocked kernel on ``band_huge64k`` (n=65,536, chosen by
-``placement="auto"``), then a resilient `SolveService` answering requests
-for both matrices.  Four chips: ``api.solve_batch(..., mesh=...)`` shards
+row-blocked kernel on ``band_huge64k`` (n=65,536, forced: its x fits a
+core's VMEM, so ``placement="auto"`` would keep it resident), then a
+resilient `SolveService` answering requests for both matrices.  Four chips: ``api.solve_batch(..., mesh=...)`` shards
 the RHS columns over the chips with the ``jax`` and the ``pallas``
 backends and is compared with the one-device solve of the same columns;
 no other phase runs.
@@ -237,7 +237,7 @@ def main() -> None:
         four_chip_phase()
     else:
         small, _ = pallas_phase("ckt_add20", "resident", "resident")
-        large, _ = pallas_phase("band_huge64k", "auto", "blocked")
+        large, _ = pallas_phase("band_huge64k", "blocked", "blocked")
         service_phase({"ckt_add20": small, "band_huge64k": large})
     report("done", total_s=time.perf_counter() - t0)
     print(json.dumps({"ok": True, "device": device}), flush=True)

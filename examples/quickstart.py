@@ -55,18 +55,18 @@ def main() -> None:
     print(f"sharded over {mesh.size} device(s) max err:",
           float(np.abs(x_sh - refs).max()))
 
-    # 6. large n: past a VMEM footprint threshold the Pallas kernel keeps
-    #    x and b in HBM and slides a row-blocked VMEM window over them
-    #    (flush/refill at cycle-block boundaries, DESIGN.md §1).  Forced
-    #    here on a small band so it runs quickly; on `band_big16k` and up
-    #    placement="auto" picks it by itself.
+    # 6. large n: where x cannot fit a core's VMEM the Pallas kernel keeps
+    #    x and b in HBM and slides a row window over them (a VMEM ring
+    #    filled from b and flushed to x as blocks run, DESIGN.md §1).
+    #    Forced here on a small band so it runs quickly; placement="auto"
+    #    picks it by itself past a core's VMEM.
     band = api.matrix("band_cz")
     bprog = api.compile(band)
     solver_big = api.make_solver(bprog, batch=B, backend="pallas",
                                  placement="blocked")
     print(f"row-blocked solve: window={solver_big.plan.window} rows "
           f"(of n={band.n}), stride={solver_big.plan.stride}, "
-          f"{solver_big.plan.num_blocks} cycle blocks")
+          f"{solver_big.plan.num_blocks} blocks")
     bb = rng.standard_normal((band.n, B))
     x_blk = np.asarray(solver_big(bb))
     refs_b = np.stack([serial_solve(band, bb[:, i]) for i in range(B)], axis=1)

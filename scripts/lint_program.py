@@ -37,7 +37,7 @@ from repro.core.analysis import LintConfig, analyze_program  # noqa: E402
 
 
 def _reports(args):
-    lc = LintConfig(cycles_per_block=args.cycles_per_block)
+    lc = LintConfig()
     names = list(args.matrix)
     if args.suite:
         names += matrices.suite_names(max_n=args.max_n)
@@ -84,9 +84,6 @@ def main(argv=None) -> int:
                          "SPT2xx performance lints")
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="emit one JSON document instead of text")
-    ap.add_argument("--cycles-per-block", type=int, default=128,
-                    help="block granularity for the SPT205 placement "
-                         "feasibility lint (default 128)")
     args = ap.parse_args(argv)
     if not args.programs and not args.matrix and not args.suite:
         ap.error("nothing to lint: pass program files, --matrix, or "

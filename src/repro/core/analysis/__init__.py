@@ -7,10 +7,10 @@ Three layers, one diagnostic vocabulary (`diagnostics.CODES`):
    and raises `IRValidationError` naming the guilty pass.
 2. **Schedule hazard/race detector** (`hazards.py` over `trace.py`
    views) — RAW hazards, psum-slot lifetime races, FINAL multiplicity,
-   bank pressure, envelope consistency; the single implementation
+   bank pressure; the single implementation
    `core.robust.verify_program` now wraps.
 3. **Performance linter** (`perf.py`) — SPT2xx warn/info lints over
-   schedule statistics and row envelopes.
+   schedule statistics and the staged stream.
 
 `analyze_program` is the everything entry point (structure + hazards +
 lints → `AnalysisReport`); `scripts/lint_program.py` is the CLI.

@@ -6,7 +6,7 @@ One verifier per IR the pipeline produces::
     partition     verify_partition(pir)           consumer adjacency
     cu_assign     verify_assign(air, cfg)         owner/task-list coherence
     psum_schedule verify_schedule(sir, air, cfg)  hazards + completeness
-    stall_elide   verify_emit(eir, sir)           elision + envelopes
+    stall_elide   verify_emit(eir, sir)           elision
     pack_emit     verify_packed_program(prog, eir, cfg)  packed roundtrip
 
 Each returns a list of `Diagnostic`s whose ``pass_name`` blames the stage
@@ -29,7 +29,7 @@ import numpy as np
 from ..errors import IRValidationError
 from ..program import OP_EDGE, OP_FINAL, OP_NOP
 from .diagnostics import SEV_ERROR, Diagnostic
-from .hazards import envelope_diags, packed_structure, trace_hazards
+from .hazards import packed_structure, trace_hazards
 from .trace import view_emit, view_program, view_schedule
 
 __all__ = [
@@ -285,7 +285,7 @@ def _schedule_completeness(sir, air) -> list[Diagnostic]:
 # stall_elide: EmitIR
 # ---------------------------------------------------------------------------
 def verify_emit(eir, sir=None) -> list[Diagnostic]:
-    """No stall row may survive; envelopes and stats must re-derive."""
+    """No stall row may survive; stats must re-derive."""
     diags: list[Diagnostic] = []
     nop_rows = ~(eir.ops != OP_NOP).any(axis=1)
     if nop_rows.any():
@@ -299,12 +299,6 @@ def verify_emit(eir, sir=None) -> list[Diagnostic]:
                           f"{eir.stats.emitted_cycles} but "
                           f"{eir.ops.shape[0]} rows were emitted",
                           "stall_elide"))
-    if eir.row_lo is None or eir.row_hi is None or \
-            eir.row_lo.shape != (eir.ops.shape[0],) or \
-            eir.row_hi.shape != (eir.ops.shape[0],):
-        diags.append(_err("SPT121", "row envelopes missing or mis-shaped",
-                          "stall_elide"))
-        return diags
     same = False
     if sir is not None:
         keep = (sir.ops != OP_NOP).any(axis=1)
@@ -317,13 +311,10 @@ def verify_emit(eir, sir=None) -> list[Diagnostic]:
         if not same:
             diags.append(_err("SPT121", "emitted rows are not the dense "
                               "trace's active rows in order", "stall_elide"))
-    if same and eir.num_slots == sir.num_slots:
-        # the emitted planes ARE the verified dense trace's active rows:
-        # every hazard check is order-relative, and dropping all-NOP rows
-        # preserves order, so only the field elision *adds* — the row
-        # envelopes — needs checking
-        diags += envelope_diags(view_emit(eir))
-    else:
+    if not (same and eir.num_slots == sir.num_slots):
+        # unless the emitted planes ARE the verified dense trace's active
+        # rows (every hazard check is order-relative, and dropping all-NOP
+        # rows preserves order), re-run the hazard detector
         diags += trace_hazards(view_emit(eir))
     return diags
 
@@ -355,17 +346,12 @@ def verify_packed_program(prog, eir=None, cfg=None) -> list[Diagnostic]:
                               "emitted schedule's stream", "pack_emit"))
         roundtrip_ok = (
             same and stream_ok and values_ok
-            and v.num_slots == eir.num_slots
-            and v.row_lo is not None and v.row_hi is not None
-            and np.array_equal(np.asarray(v.row_lo),
-                               np.asarray(eir.row_lo))
-            and np.array_equal(np.asarray(v.row_hi),
-                               np.asarray(eir.row_hi)))
+            and v.num_slots == eir.num_slots)
     if not roundtrip_ok:
         # standalone program (no eir) or an imperfect roundtrip: run the
         # full hazard detector over the decoded planes.  When the decode
-        # matches the already-verified EmitIR field-for-field (envelopes
-        # and stream included), the detector would only re-prove what
+        # matches the already-verified EmitIR field-for-field (stream
+        # included), the detector would only re-prove what
         # `verify_emit` just proved on identical arrays — skip it.
         diags += trace_hazards(v, cfg if cfg is not None else prog.config,
                                check_values=values_ok)

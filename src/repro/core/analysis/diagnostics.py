@@ -50,7 +50,7 @@ CODES: dict[str, str] = {
     "SPT111": "RAW hazard: EDGE reads a row not yet finalized",
     "SPT112": "psum slot lifetime race (read-before-store / WAW overwrite)",
     "SPT113": "psum slot id beyond the register-file capacity",
-    "SPT114": "row-envelope metadata inconsistent with instruction words",
+    # SPT114 is retired: the row envelopes it checked are gone
     "SPT115": "bank pressure: distinct reads in one cycle exceed the banks",
     # -- cross-IR pass contracts -------------------------------------------
     "SPT116": "node executed on a CU other than its assigned owner",
@@ -64,7 +64,7 @@ CODES: dict[str, str] = {
     "SPT202": "psum spill pressure (overflow slots / emergency parks)",
     "SPT203": "stall-row density above threshold",
     "SPT204": "two-plane instruction fallback doubles instruction traffic",
-    "SPT205": "row envelope admits no blocked placement window",
+    "SPT205": "blocked stream admits no sliding row window",
     "SPT206": "PE utilization below threshold",
     "SPT207": "bank-conflict replay density above threshold",
     "SPT208": "scheduler strategy leaves cycles on the table vs the frontier",

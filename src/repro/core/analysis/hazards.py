@@ -13,8 +13,6 @@ Every check the executors' correctness rests on, run statically over a
   * SPT112 — psum slot lifetime races per CU: a LOAD/SWAP reading a slot
     no earlier STORE/SWAP filled (use-before-def), and a STORE_RESET
     overwriting a slot still live (WAW);
-  * SPT114 — ``row_lo/row_hi`` envelope metadata that does not re-derive
-    from the instruction words it summarizes;
   * SPT115 — more distinct x-reads in one cycle than the banked
     interconnect has banks (requires an `AccelConfig`).
 
@@ -44,7 +42,7 @@ from ..program import (
 from .diagnostics import SEV_ERROR, Diagnostic
 from .trace import TraceView
 
-__all__ = ["packed_structure", "trace_hazards", "envelope_diags"]
+__all__ = ["packed_structure", "trace_hazards"]
 
 
 def _err(code: str, message: str, *, pass_name: str = "program",
@@ -156,9 +154,7 @@ def trace_hazards(v: TraceView, cfg=None, *,
     active = op != OP_NOP
 
     # SPT105 — solution-row bounds
-    src_ok = True
     if active.any() and int(src[active].max()) >= n:
-        src_ok = False
         diags.append(_err("SPT105", f"active lane reads row >= n={n}",
                           row=int(src[active].max()), **blame))
 
@@ -222,10 +218,6 @@ def trace_hazards(v: TraceView, cfg=None, *,
     if ev_t.size and _psum_lifetime_broken(ctl, slot, ev_t, ev_p):
         diags += _psum_lifetime_diags(ctl, slot, ev_t, ev_p, blame)
 
-    # SPT114 — row-envelope metadata re-derived from the words it summarizes
-    if src_ok:
-        diags += envelope_diags(v, blame)
-
     # SPT115 — banked-read pressure: every distinct x-read address in a
     # cycle needs its own bank; more distinct reads than banks cannot issue
     if cfg is not None and edges.any():
@@ -242,29 +234,6 @@ def trace_hazards(v: TraceView, cfg=None, *,
                               hint="the ICR/bank model cannot issue this "
                                    "row; reschedule", **blame))
     return diags
-
-
-def envelope_diags(v: TraceView, blame: dict | None = None) -> list:
-    """SPT114 — ``row_lo/row_hi`` must re-derive from the instruction words.
-
-    Split out of `trace_hazards` so the per-pass verifiers can run just
-    this check on a trace whose planes are already proven identical to a
-    verified upstream IR (the envelope metadata is the only field such a
-    trace adds).  Callers must have established ``src < n`` first.
-    """
-    if v.row_lo is None or v.row_hi is None:
-        return []
-    blame = blame if blame is not None else dict(pass_name=v.origin)
-    active = v.op != OP_NOP
-    lo = np.where(active, v.src, v.n).min(axis=1).astype(np.int32)
-    hi_env = np.where(active, v.src, -1).max(axis=1).astype(np.int32)
-    if np.array_equal(lo, v.row_lo) and np.array_equal(hi_env, v.row_hi):
-        return []
-    bad = int(np.argmax((lo != v.row_lo) | (hi_env != v.row_hi)))
-    return [_err("SPT114", f"row-envelope metadata inconsistent with the "
-                 f"instruction words at cycle {bad}", cycle=bad,
-                 hint="window planning would misplace the VMEM window; "
-                      "recompile", **blame)]
 
 
 def _psum_lifetime_broken(ctl, slot, ev_t, ev_p) -> bool:

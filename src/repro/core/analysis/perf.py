@@ -1,6 +1,6 @@
 """Performance linter over compiled `Program`s (DESIGN.md §8, SPT2xx).
 
-Static pathologies the schedule statistics and row envelopes expose —
+Static pathologies the schedule statistics and the staged stream expose —
 nothing here affects correctness, every diagnostic is a throughput or
 footprint observation with a suggested knob:
 
@@ -9,8 +9,9 @@ footprint observation with a suggested knob:
     double-buffer parks (`dm_escapes`);
   * SPT203 — stall-row density (elided all-NOP cycles / total cycles);
   * SPT204 — the 2-plane packed fallback doubled instruction traffic;
-  * SPT205 — the row envelope admits no blocked placement window, so the
-    HBM-resident large-n path is unavailable;
+  * SPT205 — the blocked kernel's stream admits no sliding row window over
+    its blocks of `kernel.ROW_BLOCK` entries, so the HBM-resident large-n
+    path is unavailable (a stream of one block always has one);
   * SPT206 — PE utilization below threshold;
   * SPT207 — bank-conflict replay density (bnop share of all lanes);
   * SPT208 — the compiled scheduler strategy's predicted cycles exceed
@@ -27,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 
+from ..program import OP_NOP
 from .diagnostics import SEV_INFO, SEV_WARN, Diagnostic
 
 __all__ = ["LintConfig", "lint_program"]
@@ -41,7 +43,6 @@ class LintConfig:
     stall_warn: float = 0.25       # SPT203: elided stall rows / cycles
     util_warn: float = 0.10        # SPT206: exec lanes / total lanes
     conflict_warn: float = 0.05    # SPT207: bnop lanes / total lanes
-    cycles_per_block: int = 128    # SPT205: blocked-placement granularity
     frontier_warn: float = 0.10    # SPT208: predicted cycles over the best
                                    # frontier strategy, as a fraction
 
@@ -121,17 +122,24 @@ def lint_program(prog, lint_cfg: LintConfig | None = None):
             "fallback doubles instruction-stream HBM traffic",
             planes=2))
 
-    # SPT205 — blocked-placement feasibility
-    if prog.row_lo is not None:
-        from ...kernels.sptrsv.ops import plan_window
+    # SPT205 — blocked-placement feasibility, judged as the blocked kernel
+    # runs: a window per block of `kernel.ROW_BLOCK` row-serial entries.  A
+    # stream of one block always has one (the whole stream), so a program
+    # with no more op words than a block is not staged.
+    from ...kernels.sptrsv import ops as sptrsv_ops
 
-        plan = plan_window(prog, lc.cycles_per_block)
+    if int((prog.opcode != OP_NOP).sum()) > sptrsv_ops.ROW_BLOCK:
+        try:
+            plan = sptrsv_ops.plan_window(prog)
+        except ValueError as e:  # a program the row-serial stream refuses
+            plan = sptrsv_ops.WindowPlan(False, reason=str(e))
         if not plan.feasible:
             diags.append(_diag(
                 "SPT205", SEV_WARN,
-                f"row envelope admits no blocked placement window "
-                f"({plan.reason}); large-n solves must keep the whole x "
-                f"vector VMEM-resident",
+                f"the blocked stream admits no sliding row window over its "
+                f"blocks of {sptrsv_ops.ROW_BLOCK} entries ({plan.reason}); "
+                f"large-n solves must keep the whole x vector "
+                f"VMEM-resident",
                 hint="hub-free orderings (e.g. RCM pre-permutation) "
                      "restore window feasibility",
                 reason=plan.reason))

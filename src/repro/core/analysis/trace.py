@@ -34,8 +34,6 @@ class TraceView:
     val_idx: np.ndarray    # [T, P] index into `stream`
     stream: np.ndarray     # [S]
     num_slots: int         # executor psum register-file size
-    row_lo: np.ndarray | None = None   # [T] per-row envelopes (emitted only)
-    row_hi: np.ndarray | None = None
     dense: bool = False    # True when stall rows are present (ScheduleIR)
 
     @property
@@ -59,14 +57,13 @@ def view_schedule(sir) -> TraceView:
 
 
 def view_emit(eir) -> TraceView:
-    """Elided `EmitIR` trace (row envelopes attached)."""
+    """Elided `EmitIR` trace."""
     return TraceView(
         origin="stall_elide", name=eir.name, n=eir.n,
         op=np.asarray(eir.ops), src=np.asarray(eir.src),
         ctl=np.asarray(eir.ctl), slot=np.asarray(eir.slot),
         val_idx=np.asarray(eir.val_idx), stream=np.asarray(eir.stream),
         num_slots=eir.num_slots,
-        row_lo=np.asarray(eir.row_lo), row_hi=np.asarray(eir.row_hi),
     )
 
 
@@ -88,5 +85,4 @@ def view_program(prog: Program) -> TraceView:
         op=np.asarray(op), src=np.asarray(src), ctl=np.asarray(ctl),
         slot=np.asarray(slot), val_idx=np.asarray(prog.val_idx),
         stream=np.asarray(prog.stream), num_slots=nslots,
-        row_lo=prog.row_lo, row_hi=prog.row_hi,
     )

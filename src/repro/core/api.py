@@ -213,8 +213,8 @@ def make_solver(prog: Program, batch: int | None = None, mesh=None,
 
     ``backend="pallas"`` executes through the TPU kernel instead of the
     `lax.scan` program; extra keywords are the kernel knobs
-    (``cycles_per_block``, ``placement`` in {"auto", "resident",
-    "blocked"}, ``vmem_limit_bytes``, ``x_block_rows``, ``interpret`` —
+    (``placement`` in {"auto", "resident", "blocked"},
+    ``vmem_limit_bytes``, ``x_block_rows``, ``interpret`` —
     see `executor.make_pallas_executor`).  The ``placement="blocked"`` /
     auto-over-threshold regime keeps x and b HBM-resident with a sliding
     VMEM row window, lifting the VMEM cap on solvable n (DESIGN.md §1).

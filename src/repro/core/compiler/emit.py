@@ -31,7 +31,5 @@ def run(eir: EmitIR, cfg: AccelConfig, planes: int | None = None) -> Program:
         val_idx=eir.val_idx,
         stream=np.array(eir.stream, dtype=np.float32),
         stats=eir.stats,
-        row_lo=eir.row_lo,
-        row_hi=eir.row_hi,
         stream_src=eir.stream_src,
     )

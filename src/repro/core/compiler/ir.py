@@ -9,7 +9,7 @@ explicit IR dataclass::
             └─ psum-cache schedule + ICR reorder
                       →  ScheduleIR     (dense cycle trace, incl. stall rows)
                └─ stall-elide
-                      →  EmitIR         (all-NOP rows dropped, row envelopes)
+                      →  EmitIR         (all-NOP rows dropped)
                   └─ pack/emit
                       →  Program        (packed VLIW words, core/program.py)
 
@@ -178,9 +178,7 @@ class EmitIR:
     """Output of the stall-elide pass: the rows actually streamed.
 
     All-NOP rows are dropped (they change no state — streaming them would
-    be pure HBM traffic); ``row_lo/row_hi`` are the per-emitted-row
-    touched-solution-row envelopes the row-blocked Pallas placement plans
-    its VMEM window from (DESIGN.md §1).
+    be pure HBM traffic).
     """
 
     name: str
@@ -190,8 +188,6 @@ class EmitIR:
     src: np.ndarray            # int32 [T, P]
     ctl: np.ndarray            # uint8 [T, P]
     slot: np.ndarray           # uint8 [T, P]
-    row_lo: np.ndarray         # int32 [T]
-    row_hi: np.ndarray         # int32 [T]
     stream: np.ndarray         # float64 [S]
     num_slots: int
     stats: ScheduleStats

@@ -4,14 +4,16 @@ A lower-triangular solve may run its rows in any topological order, so the
 compiler is free to relabel them.  The level order numbers rows by DAG
 level (the longest path from a source), keeping the given order within a
 level: the system ``P L Pᵀ`` it compiles is still lower triangular and its
-solution is ``x`` permuted.  In level order the rows a cycle block touches
-lie close together, so `kernels.sptrsv.ops.plan_window` finds a window that
-slides where the natural order of a 3D stencil keeps nearly every row live
-(at 32³: 6,192 against 27,408 of 32,768 rows).
+solution is ``x`` permuted.  In level order a row's sources lie at most
+about a level's width behind it, so `kernels.sptrsv.ops.plan_window` finds
+a window that slides where the natural order of a 3D stencil keeps a
+plane's worth of rows behind each row (at 32³: 3,936 against 13,200 of
+32,768 rows).
 
 The pass keeps the workload's order unless the resident kernel's x buffer
-of one column cannot fit a core's VMEM (`kernels.common.resident_fits`);
-every program that fits compiles exactly as it would without this pass.
+of one column, with the headroom the kernel asks Mosaic for, cannot fit a
+core's VMEM (`kernels.common.resident_fits`: from 253,952 rows); every
+program that fits compiles exactly as it would without this pass.
 """
 
 from __future__ import annotations

@@ -401,7 +401,6 @@ def make_pallas_executor(
     prog: Program,
     batch: int | None = None,
     *,
-    cycles_per_block: int = 128,
     placement: str = "auto",
     vmem_limit_bytes: int | None = None,
     x_block_rows: int | None = None,
@@ -414,9 +413,11 @@ def make_pallas_executor(
     but executing `repro.kernels.sptrsv` instead of the `lax.scan` program.
 
     ``placement`` selects the kernel's memory regime: ``"resident"`` keeps
-    x and b VMEM-resident, ``"blocked"`` forces the HBM-resident row-window
-    path (large n), ``"auto"`` switches on the x+b footprint crossing
-    ``vmem_limit_bytes`` (see `repro.kernels.sptrsv.ops.resolve_placement`).
+    x VMEM-resident, ``"blocked"`` forces the HBM-resident row-window path
+    (large n), ``"auto"`` goes blocked where x does not fit the solve-state
+    budget (a core's VMEM less Mosaic's headroom, or ``vmem_limit_bytes``)
+    and a smaller window exists (see
+    `repro.kernels.sptrsv.ops.resolve_placement`).
     Executors are cached per (program identity, padded batch width, all
     placement knobs, interpret) — the window plan and the staged
     instruction tensors are computed once per cache entry, so repeated
@@ -424,11 +425,9 @@ def make_pallas_executor(
     """
     from repro.kernels.sptrsv import ops as sptrsv_ops  # lazy: ops imports us
 
-    if vmem_limit_bytes is None:
-        vmem_limit_bytes = sptrsv_ops.DEFAULT_STATE_BYTES
     width = pad_batch(batch if batch is not None else 1)
-    key = ("pallas", width, cycles_per_block, placement, vmem_limit_bytes,
-           x_block_rows, interpret)
+    key = ("pallas", width, placement, vmem_limit_bytes, x_block_rows,
+           interpret)
     per_prog = _EXEC_CACHE.get(prog)
     if per_prog is None:
         per_prog = {}
@@ -438,8 +437,8 @@ def make_pallas_executor(
         try:
             with TraceAnnotation("sptrsv.executor_build"):
                 core = sptrsv_ops.build_solver_cols(
-                    prog, width, cycles_per_block=cycles_per_block,
-                    placement=placement, vmem_limit_bytes=vmem_limit_bytes,
+                    prog, width, placement=placement,
+                    vmem_limit_bytes=vmem_limit_bytes,
                     x_block_rows=x_block_rows, interpret=interpret,
                 )
         except Exception as e:

@@ -303,14 +303,6 @@ class Program:
     stream: np.ndarray     # [S] float32: L_ij / 1/L_ii in schedule order
     stats: ScheduleStats
     num_slots: int = 0     # executor psum RF size (psum_words + overflow used)
-    # Per-cycle solution-row access ranges (DESIGN.md §1, row-blocked x):
-    # row_lo[t]/row_hi[t] = min/max row index touched by any active lane in
-    # cycle t (EDGE reads x[src]; FINAL reads b[row] and writes x[row]).
-    # Cycles with no active lane carry the empty sentinel (n, -1).  The
-    # Pallas wrapper reduces these to per-cycle-block VMEM window bounds
-    # that drive the level-boundary flush/shift DMAs.
-    row_lo: np.ndarray | None = None  # [T] int32
-    row_hi: np.ndarray | None = None  # [T] int32
     # Value provenance of `stream` (values-only recompilation, DESIGN.md
     # §10): stream_src[s] >= 0 is the global edge index into the frontend
     # ComputeDag's weight array whose coefficient was streamed at slot s;
@@ -323,8 +315,7 @@ class Program:
     # internal row k solves the workload's row row_perm[k]; None is the
     # identity.  Every executor permutes b on the way in and x on the way
     # out, so callers see the workload's order.  The row indices of
-    # `instr`, `row_lo` and `row_hi` are internal; `stream_src` indexes
-    # the workload as given.
+    # `instr` are internal; `stream_src` indexes the workload as given.
     row_perm: np.ndarray | None = None  # [n] int32
 
     @property

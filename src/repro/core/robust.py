@@ -7,8 +7,7 @@ Three layers over the existing compile/execute stack (DESIGN.md §7):
     checked explicitly: packed-field ranges, zero-word NOP lanes,
     value-index bounds, finite stream values with non-zero FINAL
     reciprocals, psum slot capacity and slot *lifetimes*, each solution
-    row finalized exactly once, dependency order, and the row-envelope
-    metadata (``row_lo/row_hi``) re-derived from the words it summarizes.
+    row finalized exactly once, and dependency order.
     Since the static-analysis subsystem landed (DESIGN.md §8) this is a
     thin wrapper over `core.analysis.program_diagnostics` — one shared
     implementation with `compile_dag(verify_ir=True)` and the linter CLI;
@@ -184,7 +183,7 @@ class RobustSolver:
         a rung that exceeds it is recorded and disabled for subsequent
         solves.  ``None`` (default) disables deadlines.
     clock : injectable monotonic clock (seconds), for deterministic tests.
-    backend_opts : forwarded to the Pallas rungs (``cycles_per_block``,
+    backend_opts : forwarded to the Pallas rungs (``placement``,
         ``vmem_limit_bytes``, ``interpret``, ...).
 
     Solves accept ``b`` of shape ``[n]`` or ``[n, B]``.  Every detection
@@ -390,8 +389,6 @@ def _copy_program(prog: Program) -> Program:
         instr=prog.instr.copy(),
         val_idx=prog.val_idx.copy(),
         stream=prog.stream.copy(),
-        row_lo=None if prog.row_lo is None else prog.row_lo.copy(),
-        row_hi=None if prog.row_hi is None else prog.row_hi.copy(),
     )
 
 
@@ -547,21 +544,12 @@ class FaultInjector:
         return dataclasses.replace(sir, ops=ops, src=src, ctl=ctl, slot=slot)
 
     def corrupt_emit(self, eir, mode: str):
-        """Mutate the emitted trace (``mode``: envelope | stall_row)."""
-        if mode == "envelope":
-            row_lo = eir.row_lo.copy()
-            t = int(self.rng.integers(row_lo.size))
-            row_lo[t] += 1
-            return dataclasses.replace(eir, row_lo=row_lo)
+        """Mutate the emitted trace (``mode``: stall_row)."""
         if mode == "stall_row":
             t = int(self.rng.integers(eir.ops.shape[0] + 1))
             ins = {f: np.insert(getattr(eir, f), t, 0, axis=0)
                    for f in ("ops", "src", "ctl", "slot", "val_idx")}
-            return dataclasses.replace(
-                eir,
-                row_lo=np.insert(eir.row_lo, t, eir.n),
-                row_hi=np.insert(eir.row_hi, t, -1),
-                **ins)
+            return dataclasses.replace(eir, **ins)
         raise ValueError(f"unknown emit corruption mode {mode!r}")
 
 
@@ -662,7 +650,6 @@ IR_FAULT_CLASSES = (
     "sched_dup_final",
     "sched_slot_cap",
     "sched_use_before_def",
-    "emit_envelope",
     "emit_stall_row",
     "pack_val_idx_oob",
 )
@@ -676,7 +663,6 @@ _IR_EXPECTED = {
     "sched_dup_final": "SPT110",
     "sched_slot_cap": "SPT113",
     "sched_use_before_def": "SPT112",
-    "emit_envelope": "SPT114",
     "emit_stall_row": "SPT121",
     "pack_val_idx_oob": "SPT106",
 }

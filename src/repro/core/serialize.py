@@ -57,8 +57,6 @@ _ARRAYS = (
     ("instr", True),
     ("val_idx", True),
     ("stream", True),
-    ("row_lo", False),
-    ("row_hi", False),
     ("stream_src", False),  # value provenance (values-only recompile path)
     ("row_perm", False),    # the compiler's row order (None: the identity)
 )
@@ -191,8 +189,6 @@ def loads_program(data: bytes, *, verify: bool = True) -> Program:
             stream=arrays["stream"],
             stats=stats,
             num_slots=header["num_slots"],
-            row_lo=arrays.get("row_lo"),
-            row_hi=arrays.get("row_hi"),
             stream_src=arrays.get("stream_src"),
             row_perm=arrays.get("row_perm"),
         )

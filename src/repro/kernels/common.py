@@ -4,9 +4,9 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["LANES", "VMEM_CAPACITY", "default_interpret", "resident_fits",
-           "resident_rows", "resident_state_bytes", "resolve_interpret",
-           "tiled_bytes"]
+__all__ = ["LANES", "VMEM_CAPACITY", "VMEM_HEADROOM", "default_interpret",
+           "resident_fits", "resident_rows", "resident_state_bytes",
+           "resolve_interpret", "tiled_bytes"]
 
 # Mosaic lays a 2-D f32 VMEM array out in (8, 128) tiles.
 SUBLANES, LANES = 8, 128
@@ -14,6 +14,9 @@ SUBLANES, LANES = 8, 128
 # it, whatever its scoped limit.  It lives here, away from the Pallas
 # imports, so the compiler can read it cheaply.
 VMEM_CAPACITY = 128 << 20
+# What a kernel asks Mosaic for on top of its solve state, for Mosaic's own
+# internal scratch (`sptrsv.kernel.vmem_limit`).
+VMEM_HEADROOM = 4 << 20
 
 
 def tiled_bytes(rows: int, cols: int) -> int:
@@ -34,13 +37,18 @@ def resident_state_bytes(n_pad: int, nb: int) -> int:
     return tiled_bytes(n_pad, nb)
 
 
-def resident_fits(n: int, nb: int = 1) -> bool:
+def resident_fits(n: int, nb: int = 1, budget: int | None = None) -> bool:
     """Whether the resident SpTRSV kernel's x buffer for ``n`` unknowns and
-    ``nb`` columns fits a core's VMEM.  The one rule for both sides of it:
+    ``nb`` columns fits ``budget`` bytes of solve state; by default what a
+    core's VMEM leaves past `VMEM_HEADROOM`, i.e. whether the scoped limit
+    the kernel asks for fits the core.  The one rule for all sides of it:
     the compiler relabels rows by level when one column does not fit
-    (`core.compiler.order`), and a Pallas solve that would need it is
-    refused (`sptrsv.ops.build_solver_cols`)."""
-    return resident_state_bytes(resident_rows(n), nb) <= VMEM_CAPACITY
+    (`core.compiler.order`), ``auto`` placement goes blocked past it, and a
+    compiled resident solve that would need it is refused
+    (`sptrsv.ops.resolve_placement`, `sptrsv.ops.build_solver_cols`)."""
+    if budget is None:
+        budget = VMEM_CAPACITY - VMEM_HEADROOM
+    return resident_state_bytes(resident_rows(n), nb) <= budget
 
 
 def default_interpret() -> bool:

@@ -6,72 +6,49 @@ TPU adaptation of the paper's accelerator (DESIGN.md §1):
     memory ... is accessed sequentially", §III-B);
   * stream-memory values are pre-gathered per instruction word by the
     compiler wrapper (ops.py), so the kernel reads them sequentially too;
-  * the solution vector (and, in the blocked kernel, the feedback registers
-    and the psum register file) live in VMEM refs, the software-managed
-    scratchpads of the paper.
+  * the solution vector (all of it, or the blocked kernel's windows of it)
+    lives in VMEM, the software-managed scratchpad of the paper; the psum
+    register file is gone: a row's running sum is a loop value.
 
-Both kernels run one word after another on the scalar unit: the cycle x
+Both kernels run one entry after another on the scalar unit: the cycle x
 lane grid is a hardware notion the kernels do not need.  The scalar unit
-sets the pace (one word costs the same at B=1 and B=16), so the wrapper
-hands each word over pre-decoded as the rows its accesses use and the
+sets the pace (one entry costs the same at B=1 and B=16), so the wrapper
+hands each entry over pre-decoded as the rows its accesses use and the
 kernel only loads, computes and stores.  Every access is a dynamically
 indexed ``[1, B]`` row of a VMEM ref, which Mosaic lowers directly.
 
-Row-serial stream (`sptrsv_pallas`, the resident kernel).  Every word of a
-program belongs to exactly one row's accumulation chain: psum control parks
-a chain in a slot and resumes it later, but never splits or merges one.  On
-a serial executor parking buys nothing, so the wrapper
-(`ops._stage_rows`) follows each lane's psum control once and stages the
-rows in the order of their FINALs, each row as its EDGE words in stream
-order and then its FINAL.  A row's arithmetic and its order are those of
-the `lax.scan` executor, so the result is bit-identical to it, and psum-only
-words disappear.  Each entry has `ROW_PLANES` int32 planes and a value:
+Row-serial stream (both kernels).  Every word of a program belongs to
+exactly one row's accumulation chain: psum control parks a chain in a slot
+and resumes it later, but never splits or merges one.  On a serial executor
+parking buys nothing, so the wrapper (`ops._row_chains`) follows each
+lane's psum control once and stages each row as its EDGE words in stream
+order and then its FINAL; the resident kernel gets the rows in the order of
+their FINALs (`ops._stage_rows`), the blocked kernel in ascending row order
+(`ops._stage_blocked`), which in a level-ordered program is level order.  A
+row's arithmetic and its order are those of the `lax.scan` executor, so the
+result is bit-identical to it, and psum-only words disappear.  Each entry
+has `ROW_PLANES` int32 planes and a value:
 
   * ``SRC`` — the x row it loads (on a FINAL its own row, which still holds
-    b: the x buffer starts as b and only a row's FINAL writes it);
+    b: x starts as b and only a row's FINAL writes it);
   * ``DST`` — the x row it stores ``(x[SRC] - psum) * v`` to: its own row on
-    a FINAL, else the x ref's spare top row, which no entry loads.
+    a FINAL, else the x ref's spare row, which no entry loads.
 
 So an entry is a FINAL exactly when ``SRC == DST``: the running sum then
 becomes zero, else ``psum + v * x[SRC]``.  The running sum is a ``[1, B]``
 value carried through the loop and across blocks (a row may straddle two),
 so an entry makes two row accesses and three SMEM reads; there is no lane
 state.  Staging refuses a program in which an EDGE reads a row whose FINAL
-does not come earlier in this order.
+does not come earlier in its order.  One entry function (`_run_rows`) serves
+both kernels: the rows it addresses are staged relative to the ref it runs
+on.
 
-Lane-state stream (`sptrsv_pallas_blocked`).  The blocked kernel keeps the
-paper's lanes: row-serial order would move a row's EDGE words into its
-FINAL's cycle block, where `ops.plan_window`'s window may no longer hold
-their sources.  It runs a cycle block's *active* lane-words (op not NOP, or
-psum control not KEEP) in cycle-major, lane-minor order
-(`ops._stage_instructions`), each with `STREAM_PLANES` int32 planes:
-
-  * the lane state is one VMEM ref ``ls`` of `lane_rows` rows: the
-    feedback rows (one per lane), the psum register file (``num_slots``
-    rows per lane), a zero row and a trash row;
-  * the running sum starts from the row ``PV_SRC`` names: the lane's
-    feedback row (KEEP), the zero row (RESET, STORE_RESET) or a psum slot
-    (LOAD, SWAP);
-  * feedback is parked into the row ``PARK`` names: the psum slot on
-    STORE_RESET/SWAP, else the trash row;
-  * ``(b[src] - psum) * v`` is stored to the x row ``X_DST`` names: the
-    FINAL's own row, else the window's top row, which no word loads;
-  * the one select left is the MAC: ``psum + v * x[src]`` where ``EDGE``
-    is set.
-
-Lanes of one cycle are independent (the scheduler guarantees an EDGE only
-reads x rows finalized in earlier cycles and FINAL rows are distinct), so
-running them one after another is exactly the synchronous cycle semantics
-of the numpy oracle.  The filler entry (the zero row as its source, the
-trash rows as its destinations) changes no row that is read.
-
-In both kernels the loop body is one basic block, `UNROLL` entries per
-iteration, so the scheduler can overlap one entry's SMEM reads with the
-previous entry's row work; a per-block count table in SMEM says how many
-entries block g holds.  Each kernel owns two SMEM stream buffers and, while
-executing block g out of one, prefetches block g+1 into the other
-(`pltpu.make_async_copy` + per-slot DMA semaphores), so instruction HBM
-traffic overlaps compute.
+The loop body is one basic block, `UNROLL` entries per iteration, so the
+scheduler can overlap one entry's SMEM reads with the previous entry's row
+work; a per-block count table in SMEM says how many entries block g holds.
+Each kernel owns two SMEM stream buffers and, while executing block g out of
+one, prefetches block g+1 into the other (`pltpu.make_async_copy` + per-slot
+DMA semaphores), so instruction HBM traffic overlaps compute.
 
 Multi-RHS batching: every row carries a trailing batch axis (``x[n_pad,
 B]``), so one pass over the stream solves B right-hand sides.
@@ -81,23 +58,24 @@ Two memory-placement regimes for the solve state (DESIGN.md §1):
   * `sptrsv_pallas` — x fully VMEM-resident, in one ``[n_pad, B]`` buffer
     that starts as b: b arrives in HBM and one DMA copies it into the x
     buffer.  Fastest while ``x[n_pad, B]`` fits.
-  * `sptrsv_pallas_blocked` — x and b stay HBM-resident (`pl.ANY`); the
-    kernel owns a row-blocked VMEM *window* of `window` solution rows that
-    slides forward by a fixed `stride` rows per cycle block.  At each block
-    boundary the `stride` rows that leave the window are flushed to HBM
-    (they are final — the schedule metadata proves no later block touches
-    them) and the shared `window - stride` rows are carried across by a
-    VMEM-to-VMEM copy.  The rows entering the window need no refill: no
-    earlier block touched them, and the schedule writes each row (FINAL)
-    before any lane reads it.
+  * `sptrsv_pallas_blocked` — x and b stay HBM-resident (`pl.ANY`); block g
+    runs against x rows ``[g*stride, g*stride + window)``, held in a VMEM
+    *ring* of ``window + 2*stride`` rows in which x row ``i`` lives at ring
+    row ``i % ring`` (`ring_rows`).  x starts as b here too: block 0's
+    window is DMA'd from b, the `stride` rows that enter block g+1's window
+    are DMA'd from b while block g runs, and the `stride` rows that leave
+    the window after block g are flushed to HBM (final: no later block
+    touches them) while block g+1 runs.  No row moves inside VMEM, and the
+    fill and flush never wrap, since window and ring are multiples of the
+    stride.  A row enters the ring before any entry touches it and only its
+    FINAL writes it, so every FINAL loads b, as in the resident kernel.
 
-The feasibility conditions (every block's touched-row envelope inside its
-window; see `ops.plan_window`) are checked by the wrapper against the
-compiler-emitted per-cycle row ranges (`Program.row_lo/row_hi`).  The
-wrapper stages every x row relative to the ref it addresses (the blocked
-kernel's rows relative to their block's window) and checks it lies in that
-ref, so a corrupt program cannot address VMEM outside the solve state; the
-kernels use the staged rows as they are.
+The window geometry comes from the staged stream itself (`ops.plan_window`:
+every block's rows inside its window).  The wrapper stages every x row
+relative to the ref it addresses (the blocked kernel's rows as their ring
+rows) and checks it lies in that ref and, in the blocked kernel, in its
+block's window, so a corrupt program cannot address VMEM outside the solve
+state; the kernels use the staged rows as they are.
 """
 
 from __future__ import annotations
@@ -111,6 +89,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (
     LANES,
+    VMEM_HEADROOM,
     resident_state_bytes,
     resolve_interpret,
     tiled_bytes,
@@ -120,32 +99,24 @@ __all__ = [
     "ROW_BLOCK",
     "ROW_PLANES",
     "SEGMENT_ALIGN",
-    "STREAM_PLANES",
     "UNROLL",
     "sptrsv_pallas",
     "sptrsv_pallas_blocked",
     "blocked_state_bytes",
-    "lane_rows",
+    "ring_rows",
     "vmem_limit",
 ]
 
 # Default scoped-VMEM limit of a TPU v5e kernel; a kernel whose accounted
 # state needs more raises its own limit (`vmem_limit`).
 _SCOPED_VMEM_DEFAULT = 16 << 20
-_VMEM_HEADROOM = 4 << 20  # Mosaic's own internal scratch
 # Entries executed per iteration of the kernels' stream loops.
 UNROLL = 8
 # Segment length granularity: Mosaic slices a 1-D HBM array only in whole
 # (1024,) tiles, and a multiple of it is a multiple of UNROLL.
 SEGMENT_ALIGN = 1024
-# The int32 planes of a lane-state entry (blocked kernel): the x row it
-# loads, the lane-state rows its running sum starts from, its feedback lives
-# in and feedback is parked in, the x row it stores, and whether it is an
-# EDGE (see the module docstring).
-ROW, PV_SRC, FB, PARK, X_DST, EDGE = range(6)
-STREAM_PLANES = 6
-# The int32 planes of a row-serial entry (resident kernel): the x row it
-# loads and the x row it stores; the two are equal on a FINAL alone.
+# The int32 planes of a row-serial entry: the x row it loads and the x row
+# it stores; the two are equal on a FINAL alone.
 SRC, DST = range(2)
 ROW_PLANES = 2
 # Entries per block of the row-serial stream (a SEGMENT_ALIGN multiple).
@@ -154,19 +125,22 @@ ROW_BLOCK = 8192
 
 def vmem_limit(state_bytes: int) -> int:
     """Scoped-VMEM limit for a kernel whose solve state takes ``state_bytes``."""
-    return max(_SCOPED_VMEM_DEFAULT, state_bytes + _VMEM_HEADROOM)
+    return max(_SCOPED_VMEM_DEFAULT, state_bytes + VMEM_HEADROOM)
 
 
-def lane_rows(p: int, num_slots: int) -> int:
-    """Rows of the blocked kernel's lane state: ``p`` feedback rows,
-    ``p * num_slots`` psum slot rows, the zero row and the trash row, in
-    that order."""
-    return p * (num_slots + 1) + 2
+def ring_rows(window: int, stride: int) -> int:
+    """Rows of the blocked kernel's x ring: block g's window, the ``stride``
+    rows entering the next window from b, and the ``stride`` rows the
+    previous boundary flushes to HBM.  The x ref holds one spare row past
+    the ring, which takes the x stores of entries that finalize nothing;
+    no DMA carries it."""
+    return window + 2 * stride
 
 
-def blocked_state_bytes(window: int, nb: int, p: int, num_slots: int) -> int:
-    """VMEM bytes of the blocked kernel: two x and two b windows + lane state."""
-    return 4 * tiled_bytes(window, nb) + tiled_bytes(lane_rows(p, num_slots), nb)
+def blocked_state_bytes(window: int, stride: int, nb: int) -> int:
+    """VMEM bytes of the blocked kernel: its x ring and the spare row; b is
+    read from HBM as rows enter the ring, and there is no lane state."""
+    return tiled_bytes(ring_rows(window, stride) + 1, nb)
 
 
 def _run_rows(ibuf, vbuf, slot, count, x_ref, pv, *, k):
@@ -195,42 +169,6 @@ def _run_rows(ibuf, vbuf, slot, count, x_ref, pv, *, k):
         return pv
 
     return jax.lax.fori_loop(0, (count + UNROLL - 1) // UNROLL, step, pv)
-
-
-def _run_block(ibuf, vbuf, slot, count, x_ref, b_ref, ls_ref, *, k):
-    """Execute the ``count`` lane-state entries of the cycle block in buffer
-    ``slot``.
-
-    ``x_ref``/``b_ref`` hold the block's window of solution/RHS rows; the
-    top one is spare.  ``ls_ref`` is the lane state (`lane_rows`).  ``ibuf``
-    is the flat SMEM buffer ``[2 * STREAM_PLANES * k]`` of staged planes,
-    ``vbuf`` the flat SMEM value buffer ``[2 * k]``.  Entries past ``count``
-    up to the next `UNROLL` multiple are filler.
-    """
-
-    def entry(e):
-        def plane(j):
-            return ibuf[(slot * STREAM_PLANES + j) * k + e]
-
-        # the psum control mux (S1/S2 of Fig. 4b) is resolved into PV_SRC
-        # and PARK by staging
-        row, fb_row = plane(ROW), plane(FB)
-        v = vbuf[slot * k + e]
-        x_row = x_ref[pl.ds(row, 1), :]
-        b_row = b_ref[pl.ds(row, 1), :]
-        pv = ls_ref[pl.ds(plane(PV_SRC), 1), :]
-        fb = ls_ref[pl.ds(fb_row, 1), :]
-        ls_ref[pl.ds(plane(PARK), 1), :] = fb
-        pv = jnp.where(plane(EDGE) != 0, pv + v * x_row, pv)
-        x_ref[pl.ds(plane(X_DST), 1), :] = (b_row - pv) * v
-        ls_ref[pl.ds(fb_row, 1), :] = pv
-
-    def step(i, carry):
-        for u in range(UNROLL):
-            entry(i * UNROLL + u)
-        return carry
-
-    jax.lax.fori_loop(0, (count + UNROLL - 1) // UNROLL, step, 0)
 
 
 def _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem, vsem, *, k, planes):
@@ -352,143 +290,149 @@ def sptrsv_pallas(
 # ---------------------------------------------------------------------------
 def _blocked_kernel(
     # inputs
-    instr_ref,   # [G * STREAM_PLANES * K] int32, HBM (streamed by DMA)
-    val_ref,     # [G * K]                f32,   HBM (pre-gathered values)
-    cnt_ref,     # [G]                    int32, SMEM (entries per block)
-    b_hbm_ref,   # [n_hbm, lanes]         f32,   HBM (windowed by DMA)
+    instr_ref,   # [G * ROW_PLANES * K] int32, HBM (streamed by DMA)
+    val_ref,     # [G * K]              f32,   HBM (pre-gathered values)
+    cnt_ref,     # [G]                  int32, SMEM (entries per block)
+    b_hbm_ref,   # [n_hbm, lanes]       f32,   HBM (read as rows enter)
     # outputs
-    x_hbm_ref,   # [n_hbm, lanes]         f32,   HBM (windowed by DMA)
+    x_hbm_ref,   # [n_hbm, lanes]       f32,   HBM (written as rows leave)
     # scratch
     ibuf, vbuf, isem, vsem,
-    ls_ref,      # [lane_rows, lanes] — feedback, psum slots, zero, trash
-    xwin,        # [2, window, lanes] — two x windows, top row spare
-    bwin,        # [2, window, lanes] — two b windows (read-only, refetched)
-    bsem, xssem, xfsem,
+    ring,        # [ring_rows + 1, lanes] — x row i at i % ring_rows, spare
+    bsem, fsem,
     *,
     k: int,
     num_blocks: int,
     window: int,
     stride: int,
 ):
-    """x/b HBM-resident solve over a sliding VMEM row window.
+    """x/b HBM-resident solve over a sliding window of a VMEM row ring.
 
-    Cycle block g executes against window rows ``[g*stride, g*stride +
-    window)`` of x and b held in VMEM.  Boundary g -> g+1 (async DMA):
+    Block g runs against x rows ``[g*stride, g*stride + window)``; x row i
+    lives at ring row ``i % rows`` (``rows = window + 2*stride``; staging
+    addressed every entry there).  x starts as b: block 0's window is
+    DMA'd from b before the loop.  Around block g (async DMA):
 
-      * flush — rows ``[g*stride, (g+1)*stride)`` leave every later window;
-        the schedule's feasibility check proves no later block touches
-        them, so they are final and stream out to HBM;
-      * shift — the ``window - stride`` shared rows are copied into the
-        other window buffer (VMEM -> VMEM, cheap).
+      * fill — before it runs, the ``stride`` rows entering block g+1's
+        window, ``[g*stride + window, (g+1)*stride + window)``, are DMA'd
+        from b into the ring rows of ``[(g-2)*stride, (g-1)*stride)``,
+        flushed at boundary g-2;
+      * flush — after it runs, rows ``[g*stride, (g+1)*stride)`` leave
+        every later window (staging proved no later block touches them),
+        so they are final and stream out to HBM while block g+1 runs.
 
-    The b window of block g+1 and its instruction block are prefetched at
-    the top of block g.  Hazard ordering: block g+1 waits on the boundary
-    shift and flush before it runs, and ``window >= 2*stride`` (checked by
-    the wrapper) keeps the flushed rows out of the shifted range.
+    Block g waits for its own fill (started at the top of block g-1) and
+    for flush g-2 (whose ring rows its fill for g+1 reuses).  Window and
+    ring are stride multiples, so no fill or flush wraps; flushes of odd
+    and even blocks take their own semaphore, since two may be in flight.
     """
-    w, r = window, stride
+    w, r, g_n = window, stride, num_blocks
+    rows = ring_rows(w, r)
     instr_dma, val_dma = _stream_dmas(instr_ref, val_ref, ibuf, vbuf, isem,
-                                      vsem, k=k, planes=STREAM_PLANES)
+                                      vsem, k=k, planes=ROW_PLANES)
 
-    def b_dma(slot, g):
+    def fill_dma(g):
+        # rows entering block g's window (g >= 1), from b
+        row = (g - 1) * r + w
+        at = pl.multiple_of(jax.lax.rem(row, rows), 8)
         return pltpu.make_async_copy(
-            b_hbm_ref.at[pl.ds(g * r, w)], bwin.at[slot], bsem.at[slot])
+            b_hbm_ref.at[pl.ds(row, r)], ring.at[pl.ds(at, r)], bsem)
 
-    def x_shift_dma(src_slot, dst_slot):
-        # carry the shared rows of boundary g -> g+1 across buffers
-        return pltpu.make_async_copy(
-            xwin.at[src_slot, pl.ds(r, w - r)],
-            xwin.at[dst_slot, pl.ds(0, w - r)], xssem)
-
-    def x_flush_dma(slot, g):
+    def flush_dma(g):
         # retire rows [g*r, g*r + r) — final, never touched again
+        at = pl.multiple_of(jax.lax.rem(g * r, rows), 8)
         return pltpu.make_async_copy(
-            xwin.at[slot, pl.ds(0, r)], x_hbm_ref.at[pl.ds(g * r, r)], xfsem)
+            ring.at[pl.ds(at, r)], x_hbm_ref.at[pl.ds(g * r, r)],
+            fsem.at[jax.lax.rem(g, 2)])
 
-    ls_ref[...] = jnp.zeros(ls_ref.shape, jnp.float32)
-
-    # warm-up: block 0 inputs in flight before the block loop starts
+    # warm-up: block 0's stream and window in flight before the block loop
     instr_dma(0, 0).start()
     val_dma(0, 0).start()
-    b_dma(0, 0).start()
+    first = pltpu.make_async_copy(b_hbm_ref.at[pl.ds(0, w)],
+                                  ring.at[pl.ds(0, w)], bsem)
+    first.start()
+    first.wait()
 
-    def run_block(g, carry):
+    def run_block(g, pv):
         slot = jax.lax.rem(g, 2)
-        nxt = 1 - slot
 
         # inputs for block g (prefetched during g-1; warm-up for g=0)
         instr_dma(slot, g).wait()
         val_dma(slot, g).wait()
-        b_dma(slot, g).wait()
 
         @pl.when(g > 0)
-        def _assemble():
-            x_shift_dma(nxt, slot).wait()   # shared rows carried over
-            x_flush_dma(nxt, g - 1).wait()  # retired rows landed in HBM
+        def _filled():
+            fill_dma(g).wait()
 
-        @pl.when(g + 1 < num_blocks)
+        @pl.when(g > 1)
+        def _flushed():
+            flush_dma(g - 2).wait()
+
+        # prefetch block g+1: its stream, and the rows entering its window
+        @pl.when(g + 1 < g_n)
         def _prefetch():
-            instr_dma(nxt, g + 1).start()
-            val_dma(nxt, g + 1).start()
-            b_dma(nxt, g + 1).start()
+            instr_dma(1 - slot, g + 1).start()
+            val_dma(1 - slot, g + 1).start()
+            fill_dma(g + 1).start()
 
-        _run_block(ibuf, vbuf, slot, cnt_ref[g], xwin.at[slot], bwin.at[slot],
-                   ls_ref, k=k)
+        pv = _run_rows(ibuf, vbuf, slot, cnt_ref[g], ring, pv, k=k)
 
-        @pl.when(g + 1 < num_blocks)
+        @pl.when(g + 1 < g_n)
         def _boundary():
-            x_flush_dma(slot, g).start()
-            x_shift_dma(slot, nxt).start()
+            flush_dma(g).start()
 
-        return carry
+        return pv
 
-    jax.lax.fori_loop(0, num_blocks, run_block, 0)
+    pv0 = jnp.zeros((1, ring.shape[1]), jnp.float32)
+    jax.lax.fori_loop(0, g_n, run_block, pv0)
+    if g_n > 1:
+        flush_dma(g_n - 2).wait()
 
-    # final window: every still-resident row flushed in one DMA
-    fin = pltpu.make_async_copy(
-        xwin.at[(num_blocks - 1) % 2],
-        x_hbm_ref.at[pl.ds((num_blocks - 1) * r, w)], xfsem)
-    fin.start()
-    fin.wait()
+    # last window: every still-resident row, in at most two pieces where it
+    # wraps around the ring
+    at, row = ((g_n - 1) * r) % rows, (g_n - 1) * r
+    for size in (min(w, rows - at), w - min(w, rows - at)):
+        if size:
+            fin = pltpu.make_async_copy(
+                ring.at[pl.ds(at, size)], x_hbm_ref.at[pl.ds(row, size)],
+                fsem.at[0])
+            fin.start()
+            fin.wait()
+            at, row = 0, row + size
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("num_cus", "num_slots", "window", "stride",
-                     "interpret"),
-)
+    jax.jit, static_argnames=("window", "stride", "interpret"))
 def sptrsv_pallas_blocked(
-    instr: jnp.ndarray,    # [G * STREAM_PLANES * K] int32 (staged planes)
+    instr: jnp.ndarray,    # [G * ROW_PLANES * K] int32 (staged planes)
     values: jnp.ndarray,   # [G * K] f32 (pre-gathered stream values)
-    counts: jnp.ndarray,   # [G] int32 (active entries per cycle block)
+    counts: jnp.ndarray,   # [G] int32 (entries per block)
     b: jnp.ndarray,        # [n_hbm, B] f32 (padded to the window sweep)
     *,
-    num_cus: int,
     window: int,
     stride: int,
-    num_slots: int = 12,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Row-blocked HBM-resident solve (large n; see `ops.plan_window`).
 
-    ``instr``/``values``/``counts`` are the lane-state stream of
-    `ops._stage_instructions`.  ``b`` must be padded to ``n_hbm =
-    (num_blocks - 1) * stride + window`` rows so every window position is in
-    bounds; `ops.build_solver_cols` does this and derives a feasible
-    (window, stride) pair from the program's row-range metadata.  The batch
-    axis is padded to whole 128-lane tiles inside: Mosaic only slices row
-    windows out of HBM arrays whose minor dimension is tile-aligned, and the
-    chip's (8, 128) layout stores the padding anyway.
+    ``instr``/``values``/``counts`` are the row-serial stream of
+    `ops._stage_blocked`, its rows staged into the ring of this (window,
+    stride).  ``b`` must be padded to ``n_hbm = (num_blocks - 1) *
+    stride + window`` rows so every window position is in bounds;
+    `ops.build_solver_cols` does this.  The batch axis is padded to whole
+    128-lane tiles inside: Mosaic only slices row windows out of HBM arrays
+    whose minor dimension is tile-aligned, and the chip's (8, 128) layout
+    stores the padding anyway.
     """
     interpret = resolve_interpret(interpret)
-    p = num_cus
-    num_blocks, k = _stream_shape(instr, values, counts, STREAM_PLANES)
+    num_blocks, k = _stream_shape(instr, values, counts, ROW_PLANES)
     n_hbm, nb = b.shape
     lanes = -(-nb // LANES) * LANES
     assert stride >= 1 and window >= 2 * stride, (window, stride)
+    assert stride % 8 == 0 and window % stride == 0, (window, stride)
     assert n_hbm == (num_blocks - 1) * stride + window, \
         f"b rows {n_hbm} != window sweep {(num_blocks - 1) * stride + window}"
-    state = blocked_state_bytes(window, lanes, p, num_slots)
+    state = blocked_state_bytes(window, stride, lanes)
 
     kernel = functools.partial(
         _blocked_kernel,
@@ -507,13 +451,11 @@ def sptrsv_pallas_blocked(
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.HBM),  # x stays in HBM
         out_shape=jax.ShapeDtypeStruct((n_hbm, lanes), jnp.float32),
-        scratch_shapes=_stream_scratch(k, STREAM_PLANES) + [
-            pltpu.VMEM((lane_rows(p, num_slots), lanes), jnp.float32),  # ls
-            pltpu.VMEM((2, window, lanes), jnp.float32),  # xwin
-            pltpu.VMEM((2, window, lanes), jnp.float32),  # bwin
-            pltpu.SemaphoreType.DMA((2,)),                # bsem
-            pltpu.SemaphoreType.DMA,                      # xssem
-            pltpu.SemaphoreType.DMA,                      # xfsem
+        scratch_shapes=_stream_scratch(k, ROW_PLANES) + [
+            pltpu.VMEM((ring_rows(window, stride) + 1, lanes),
+                       jnp.float32),                      # ring, spare
+            pltpu.SemaphoreType.DMA,                      # bsem
+            pltpu.SemaphoreType.DMA((2,)),                # fsem
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(state)),
         interpret=interpret,

@@ -4,15 +4,17 @@ Two memory placements for the solve state (DESIGN.md §1):
 
   * ``resident`` — x lives in VMEM for the whole solve, in one buffer that
     b is copied into (`kernel.sptrsv_pallas`); fastest while it fits.
-  * ``blocked``  — x and b stay in HBM and the kernel slides a row-blocked
-    VMEM window over them (`kernel.sptrsv_pallas_blocked`), flushing and
-    shifting it at cycle-block boundaries with async DMA.  This is the
+  * ``blocked``  — x and b stay in HBM and the kernel slides a row window
+    over them (`kernel.sptrsv_pallas_blocked`), held in a VMEM ring that
+    async DMAs fill from b and flush to x as blocks run.  This is the
     large-n path: VMEM use is bounded by the window, not by n.
 
-``placement="auto"`` (the default) picks per solve: resident while the
-resident x footprint is under ``vmem_limit_bytes``, blocked beyond it
-whenever the program's row-access envelope admits a sliding window
-(`plan_window`) whose x and b windows take less.
+``placement="auto"`` (the default) picks per solve, by one rule: it goes
+blocked only where the resident x buffer does not fit the solve-state
+budget (`kernels.common.resident_fits`: by default what a core's VMEM leaves
+past Mosaic's headroom, the rule the compiler's level-order pass reads too;
+``vmem_limit_bytes`` when one is given), the program admits a sliding window
+(`plan_window`), and the window's ring takes less than the resident x.
 Footprints are counted as the chip lays them out: a ``[rows, B]`` f32 array
 takes whole (8, 128) tiles (`kernels.common.tiled_bytes`), so at B=16 it
 occupies 8x its logical bytes.
@@ -24,13 +26,12 @@ stream-memory design), and the compiler's packed instruction words
 (``Program.instr``, ``[T, planes, P]`` int32 — DESIGN.md §Perf,
 "Instruction encoding") are decoded once into the rows each access uses
 (`kernel`'s module docstring) and flattened, so each block arrives in SMEM
-with a single DMA per stream.  The resident kernel gets the row-serial
-stream (`_stage_rows`): the rows in the order of their FINALs, in blocks of
-`kernel.ROW_BLOCK` entries of ``4 * ROW_PLANES + 4`` bytes.  The blocked
-kernel gets the lane-state stream (`_stage_instructions`): per cycle block
-the words that do something, padded to one segment length K, in entries of
-``4 * STREAM_PLANES + 4`` bytes.  No-op lane slots are neither streamed nor
-executed.
+with a single DMA per stream.  Both kernels get a row-serial stream of the
+words of `_row_chains`, in blocks of `kernel.ROW_BLOCK` entries of ``4 *
+ROW_PLANES + 4`` bytes: the resident kernel's (`_stage_rows`) has the rows
+in the order of their FINALs, the blocked kernel's (`_stage_blocked`) in
+ascending row order, each entry's rows staged into its block's window.
+Psum-only words are neither streamed nor executed.
 """
 
 from __future__ import annotations
@@ -45,12 +46,10 @@ import jax.numpy as jnp
 from repro.core.errors import PlacementInfeasibleError
 from repro.core.executor import _psum_slots, as_batch, permute_rows
 from repro.core.program import (
-    OP_EDGE,
     OP_FINAL,
     OP_NOP,
     PS_KEEP,
     PS_LOAD,
-    PS_RESET,
     PS_STORE_RESET,
     PS_SWAP,
     Program,
@@ -59,6 +58,7 @@ from repro.core.program import (
 
 from repro.kernels.common import (
     VMEM_CAPACITY,
+    VMEM_HEADROOM,
     resident_fits,
     resident_rows,
     resident_state_bytes,
@@ -68,20 +68,13 @@ from repro.kernels.common import (
 
 from .kernel import (
     DST,
-    EDGE,
-    FB,
-    PARK,
-    PV_SRC,
-    ROW,
     ROW_BLOCK,
     ROW_PLANES,
     SEGMENT_ALIGN,
     SRC,
-    STREAM_PLANES,
     UNROLL,
-    X_DST,
     blocked_state_bytes,
-    lane_rows,
+    ring_rows,
     sptrsv_pallas,
     sptrsv_pallas_blocked,
 )
@@ -95,15 +88,7 @@ __all__ = [
     "state_bytes",
     "Stream",
     "WindowPlan",
-    "DEFAULT_STATE_BYTES",
 ]
-
-# auto-placement threshold for the tiled VMEM solve-state footprint.
-# A TPU v5e kernel gets 16 MiB of scoped VMEM by default, shared with the
-# feedback/psum lane state and Mosaic's own scratch; 4 MiB of solve state
-# keeps the resident kernel inside it.  Overridable per call
-# (``vmem_limit_bytes``).
-DEFAULT_STATE_BYTES = 4 << 20
 
 _ROW_ALIGN = 8  # window/stride row granularity (f32 sublane tile)
 
@@ -112,10 +97,11 @@ _ROW_ALIGN = 8  # window/stride row granularity (f32 sublane tile)
 class WindowPlan:
     """A feasible sliding-window placement for the blocked kernel.
 
-    Cycle block g executes against x/b rows ``[g*stride, g*stride +
-    window)``; ``n_hbm`` is the padded HBM row count covering the full
-    window sweep.  ``feasible=False`` carries a human-readable ``reason``
-    (the auto path then falls back to the VMEM-resident placement).
+    Block g of the blocked stream (`_stage_blocked`) executes against x/b
+    rows ``[g*stride, g*stride + window)``; ``n_hbm`` is the padded HBM row
+    count covering the full window sweep.  ``feasible=False`` carries a
+    human-readable ``reason`` (the auto path then keeps the VMEM-resident
+    placement).
     """
 
     feasible: bool
@@ -126,67 +112,55 @@ class WindowPlan:
     reason: str = ""
 
     def state_bytes(self, nb: int) -> int:
-        """Tiled VMEM bytes of the double-buffered x+b windows."""
-        return 4 * tiled_bytes(self.window, nb)
+        """Tiled VMEM bytes of the kernel's x ring and spare row."""
+        return blocked_state_bytes(self.window, self.stride, nb)
 
 
 def _round_up(v: int, m: int) -> int:
     return -(-v // m) * m
 
 
-def plan_window(
-    prog: Program,
-    cycles_per_block: int = 128,
-    min_window: int | None = None,
-) -> WindowPlan:
-    """Derive a (stride, window) pair from the program's row-range metadata.
+def _block_len(m: int) -> int:
+    """Entries per block of a row-serial stream of ``m`` entries."""
+    return min(ROW_BLOCK, _round_up(max(m, 1), SEGMENT_ALIGN))
 
-    The compiler records, per cycle, the min/max solution row any active
-    lane touches (`Program.row_lo/row_hi`).  Reducing those over each cycle
-    block gives the block's touched-row envelope ``[lo_g, hi_g]``; the
-    window for block g is placed at base ``g * stride``, so feasibility
-    requires ``g*stride <= lo_g`` and ``hi_g < g*stride + window`` for all
-    g.  The stride is maximized (smallest window), then the window sized to
-    the worst block plus one spare top row, which takes the x stores of
-    words that finalize nothing (`kernel`'s ``X_DST``) — both rounded to
-    the f32 sublane granularity.
 
-    Programs whose row envelope does not advance monotonically enough
-    (e.g. circuit matrices with hub columns read across the whole DAG)
-    yield ``feasible=False``; such DAGs genuinely need the whole x vector
-    live and must use the resident placement.
+def plan_window(prog: Program, *, min_window: int | None = None) -> WindowPlan:
+    """Derive a (stride, window) pair from the blocked stream's own rows.
+
+    The blocked kernel runs the row-serial stream in ascending row order
+    (`_blocked_order`), cut into blocks of ``k`` entries.  Reducing each
+    block's x rows gives its row envelope ``[lo_g, hi_g]``; the window for
+    block g is placed at base ``g * stride``, so feasibility requires
+    ``g*stride <= lo_g`` and ``hi_g < g*stride + window`` for all g.  The
+    stride is maximized (smallest window) and rounded down to the f32
+    sublane granularity, then the window sized to the worst block, floored
+    by ``min_window`` and by twice the stride, and rounded up to a multiple
+    of the stride, so that the kernel's ring fills and flushes whole
+    strides.
+
+    Programs whose rows do not advance monotonically enough (e.g. circuit
+    matrices with hub columns read across the whole DAG) yield
+    ``feasible=False``; such DAGs genuinely need the whole x vector live and
+    must use the resident placement.
     """
-    if prog.row_lo is None or prog.row_hi is None:
-        return WindowPlan(False, reason="program has no row-range metadata "
-                                        "(recompile with this version)")
-    t = prog.cycles
-    g = -(-t // cycles_per_block)
-    lo = np.full(g * cycles_per_block, prog.n, dtype=np.int64)
-    hi = np.full(g * cycles_per_block, -1, dtype=np.int64)
-    lo[:t] = prog.row_lo
-    hi[:t] = prog.row_hi
-    lo = lo.reshape(g, cycles_per_block).min(axis=1)
-    hi = hi.reshape(g, cycles_per_block).max(axis=1)
-    nonempty = hi >= 0
-
-    stride = prog.n
-    for gi in range(1, g):
-        if nonempty[gi]:
-            stride = min(stride, int(lo[gi]) // gi)
-    stride -= stride % _ROW_ALIGN
-    if g > 1 and stride <= 0:
-        return WindowPlan(False, reason="row envelope not monotone: an "
-                                        "early row stays live across the "
-                                        "whole schedule")
-    if g == 1:
+    src = _blocked_order(prog)[2]
+    k = _block_len(len(src))
+    g = -(-len(src) // k)
+    lo = np.minimum.reduceat(src, np.arange(g) * k)
+    hi = np.maximum.reduceat(src, np.arange(g) * k)
+    if g > 1:
+        stride = int((lo[1:] // np.arange(1, g)).min())
+        stride -= stride % _ROW_ALIGN
+        if stride <= 0:
+            return WindowPlan(False, reason="row envelope not monotone: an "
+                                            "early row stays live across "
+                                            "the whole stream")
+    else:
         stride = _ROW_ALIGN  # unused by a single-block sweep, but traced
-
-    w_req = 0
-    for gi in range(g):
-        if nonempty[gi]:
-            w_req = max(w_req, int(hi[gi]) - gi * stride + 1)
-    window = max(w_req + 1, 2 * stride, min_window or 0, 2 * _ROW_ALIGN)
-    window = _round_up(window, _ROW_ALIGN)
+    w_req = int((hi - np.arange(g) * stride).max()) + 1
+    window = max(w_req, 2 * stride, min_window or 0, 2 * _ROW_ALIGN)
+    window = _round_up(window, stride)
     n_hbm = (g - 1) * stride + window
     return WindowPlan(True, stride=stride, window=window, n_hbm=n_hbm,
                       num_blocks=g)
@@ -198,26 +172,26 @@ def resolve_placement(
     *,
     placement: str = "auto",
     vmem_limit_bytes: int | None = None,
-    cycles_per_block: int = 128,
     x_block_rows: int | None = None,
 ) -> tuple[str, WindowPlan | None]:
     """Pick ``("resident", None)`` or ``("blocked", plan)`` for a solve.
 
     ``placement`` forces a regime (``"blocked"`` raises if the program's
-    row envelope admits no window); ``"auto"`` compares the VMEM-resident
-    x footprint for ``nb`` RHS columns against ``vmem_limit_bytes``
-    (``None`` -> `DEFAULT_STATE_BYTES`) and only goes blocked when that
-    saves memory and a window exists.  ``x_block_rows`` floors the planned
-    window (perf knob; the planner still enlarges it to whatever the
-    schedule requires).
+    rows admit no window); ``"auto"`` goes blocked only when the resident x
+    buffer for ``nb`` RHS columns does not fit the solve-state budget
+    (`kernels.common.resident_fits`: ``vmem_limit_bytes`` if given, else
+    what a core's VMEM leaves past Mosaic's headroom), a window exists, and
+    its ring takes less than the resident x.  ``x_block_rows`` floors the
+    planned window (perf knob; the planner still enlarges it to whatever
+    the stream requires).
     """
-    if vmem_limit_bytes is None:
-        vmem_limit_bytes = DEFAULT_STATE_BYTES
     if placement == "resident":
         return "resident", None
     if placement not in ("auto", "blocked"):
         raise ValueError(f"unknown placement {placement!r}")
-    plan = plan_window(prog, cycles_per_block, min_window=x_block_rows)
+    if placement == "auto" and resident_fits(prog.n, nb, vmem_limit_bytes):
+        return "resident", None
+    plan = plan_window(prog, min_window=x_block_rows)
     if placement == "blocked":
         if not plan.feasible:
             # taxonomy leaf (DESIGN.md §7); still a ValueError for
@@ -227,11 +201,9 @@ def resolve_placement(
                 f"row-blocked placement infeasible: {plan.reason}",
                 detail={"reason": plan.reason})
         return "blocked", plan
-    resident_bytes = tiled_bytes(_resident_rows(prog), nb)
-    if resident_bytes <= vmem_limit_bytes or not plan.feasible:
-        return "resident", None
-    if plan.state_bytes(nb) >= resident_bytes:
-        return "resident", None  # window as big as the vector: no point
+    if not plan.feasible or \
+            plan.state_bytes(nb) >= tiled_bytes(_resident_rows(prog), nb):
+        return "resident", None  # no window, or one as big as the vector
     return "blocked", plan
 
 
@@ -240,140 +212,27 @@ def _resident_rows(prog: Program) -> int:
     return resident_rows(prog.n)
 
 
-def _pad_to(arr: np.ndarray, t_pad: int, fill=0) -> np.ndarray:
-    t = arr.shape[0]
-    if t == t_pad:
-        return arr
-    out = np.full((t_pad,) + arr.shape[1:], fill, dtype=arr.dtype)
-    out[:t] = arr
-    return out
-
-
 @dataclasses.dataclass(frozen=True)
 class Stream:
-    """A program staged for a kernel: its entries, compacted, in blocks.
+    """A program staged for a kernel: its row-serial entries, in blocks.
 
-    ``instr`` is ``[G, planes, k]`` flattened, ``values`` ``[G, k]``
-    flattened, ``counts[g]`` the entries of block g; ``k`` is a
-    `kernel.SEGMENT_ALIGN` multiple and each block is padded with a filler
-    entry.  Lane-state streams (`_stage_instructions`): block g holds the
-    active words of ``cycles_per_block`` cycles in the planes `kernel.ROW`
-    .. `kernel.EDGE`.  Row-serial streams (`_stage_rows`): blocks of ``k``
-    entries, only the last one short, in the planes `kernel.SRC` and
-    `kernel.DST`.
+    ``instr`` is ``[G, ROW_PLANES, k]`` flattened (the planes `kernel.SRC`
+    and `kernel.DST`), ``values`` ``[G, k]`` flattened, ``counts[g]`` the
+    entries of block g: blocks of ``k`` entries (`_block_len`), only the
+    last one short and padded with the filler entry.
     """
 
     instr: np.ndarray
     values: np.ndarray
     counts: np.ndarray
     k: int
-    slot_words: int  # T_pad * P: the lane slots of the uncompacted grid
+    slot_words: int  # T * P: the lane slots of the uncompacted grid
 
     @property
     def stream_words(self) -> int:
         """Entries the kernel executes per solve (counts rounded up to the
         unroll factor, filler included)."""
         return int((-(-self.counts // UNROLL) * UNROLL).sum())
-
-
-def _active_blocks(prog: Program, cycles_per_block: int):
-    """``(words [G, planes, tb*P], active [G, tb*P])`` of the padded program,
-    each block's lane slots in cycle-major, lane-minor order."""
-    tb, p, planes = cycles_per_block, prog.num_cus, prog.planes
-    g = -(-prog.cycles // tb)
-    words = _pad_to(prog.instr, g * tb)                  # [T_pad, planes, P]
-    op, _, ctl, _ = decode_instructions(words, planes)   # [T_pad, P]
-    active = ((op != OP_NOP) | (ctl != PS_KEEP)).reshape(g, tb * p)
-    words = words.reshape(g, tb, planes, p).transpose(0, 2, 1, 3)
-    return words.reshape(g, planes, tb * p), active
-
-
-def _segment_len(active: np.ndarray) -> int:
-    return _round_up(max(int(active.sum(axis=1).max()), 1), SEGMENT_ALIGN)
-
-
-def _planes(op, row, ctl, slot, lane, p: int, num_slots: int,
-            spare: int) -> np.ndarray:
-    """``[STREAM_PLANES, m]`` staged planes of decoded words (see `kernel`);
-    ``row`` is each word's x row in the ref the kernel addresses, ``spare``
-    that ref's top row.
-
-    Lane-state rows: feedback of lane l at l, psum slot j of lane l at ``p +
-    l * num_slots + j`` (overflow slots share the last), then the zero row
-    and the trash row (`kernel.lane_rows`).
-    """
-    zero = lane_rows(p, num_slots) - 2
-    trash = zero + 1
-    slot_row = p + lane * num_slots + np.minimum(slot, num_slots - 1)
-    out = np.empty((STREAM_PLANES, len(op)), np.int64)
-    out[ROW] = np.where(op == OP_NOP, 0, row)
-    out[PV_SRC] = np.where((ctl == PS_LOAD) | (ctl == PS_SWAP), slot_row,
-                           np.where((ctl == PS_RESET) | (ctl == PS_STORE_RESET),
-                                    zero, lane))
-    out[FB] = lane
-    out[PARK] = np.where((ctl == PS_STORE_RESET) | (ctl == PS_SWAP),
-                         slot_row, trash)
-    out[X_DST] = np.where(op == OP_FINAL, row, spare)
-    out[EDGE] = op == OP_EDGE
-    return out
-
-
-def _filler(p: int, num_slots: int, spare: int) -> np.ndarray:
-    """The filler entry's planes: it sums from the zero row and stores to
-    the trash row and the spare x row only, so it changes no row that is
-    read."""
-    zero = lane_rows(p, num_slots) - 2
-    out = np.zeros(STREAM_PLANES, np.int64)
-    out[[PV_SRC, FB, PARK, X_DST]] = [zero, zero + 1, zero + 1, spare]
-    return out
-
-
-def _stage_instructions(prog: Program, cycles_per_block: int,
-                        plan: WindowPlan | None = None) -> Stream:
-    """Stage the blocked kernel's lane-state stream: compact the packed
-    instruction words and pre-gather the values.
-
-    The program already carries the packed ``[T, planes, P]`` words — the
-    pack happens once at compile time; staging drops the no-op lane slots
-    of each cycle block (pad cycles are all no-op), decodes every word it
-    keeps into the rows the kernel's accesses use (`_planes`), gathers the
-    f32 values per kept word so the kernel streams them positionally, and
-    pads each block's segment to the common length (see `Stream`).  x rows
-    index the resident x buffer, or with a blocked ``plan`` the window of
-    their block; a row outside it raises ``ValueError``.
-    """
-    tb, p, planes = cycles_per_block, prog.num_cus, prog.planes
-    words, active = _active_blocks(prog, tb)
-    g = active.shape[0]
-    values = _pad_to(prog.stream[prog.val_idx].astype(np.float32), g * tb)
-    values = values.reshape(g, tb * p)
-    k = _segment_len(active)
-    slots = _psum_slots(prog)
-
-    blk, slot = np.nonzero(active)               # row-major: in block order
-    pos = (np.cumsum(active, axis=1) - 1)[blk, slot]
-    kept = words[blk, :, slot][..., None]        # [m, planes, 1]
-    op, src, ctl, ps = (f[:, 0] for f in decode_instructions(kept, planes))
-    row = src.astype(np.int64)
-    if plan is None:
-        spare = _resident_rows(prog) - 1
-    else:
-        spare = plan.window - 1
-        row = row - blk * plan.stride
-    bad = (op != OP_NOP) & ((row < 0) | (row >= spare))
-    if bad.any():
-        i = int(np.flatnonzero(bad)[0])
-        raise ValueError(
-            f"word of cycle block {blk[i]} addresses x row {src[i]}, outside "
-            f"the {spare} rows its {'window' if plan else 'x buffer'} holds")
-    instr = np.empty((g, STREAM_PLANES, k), np.int32)
-    instr[...] = _filler(p, slots, spare)[:, None]
-    instr[blk, :, pos] = _planes(op, row, ctl, ps, slot % p, p, slots, spare).T
-    vals = np.zeros((g, k), np.float32)
-    vals[blk, pos] = values[blk, slot]
-    return Stream(instr=instr.reshape(-1), values=vals.reshape(-1),
-                  counts=active.sum(axis=1).astype(np.int32), k=k,
-                  slot_words=g * tb * p)
 
 
 def _row_chains(prog: Program) -> tuple[np.ndarray, np.ndarray]:
@@ -459,20 +318,11 @@ def _row_chains(prog: Program) -> tuple[np.ndarray, np.ndarray]:
     return t[kept[pos]], lane[kept[pos]]
 
 
-def _stage_rows(prog: Program, cycles_per_block: int = 128) -> Stream:
-    """Stage the resident kernel's row-serial stream (`kernel` docstring).
-
-    The words of `_row_chains`, each decoded into its `kernel.ROW_PLANES`
-    planes (`kernel.SRC`, `kernel.DST`) and its pre-gathered value, cut
-    into blocks of ``k`` entries, only the last one short and padded with
-    the filler entry (the spare row as source and destination, value 0: a
-    FINAL of the spare row, which stores only there and leaves a zero
-    sum).
-    ``cycles_per_block`` counts the uncompacted lane slots (``slot_words``)
-    only.  Raises ``ValueError`` on a row outside the x buffer, a row
-    finalised twice, or an EDGE reading a row whose FINAL does not come
-    earlier in this order.
-    """
+def _row_words(prog: Program):
+    """``(t, lane, src, fin)`` of the row-serial entries in FINAL order
+    (`_row_chains`): each entry's word, the x row it addresses and whether
+    it is a FINAL.  Raises ``ValueError`` on a row outside the resident x
+    buffer or a row finalised twice."""
     t, lane = _row_chains(prog)
     op, src, _, _ = (f[:, 0] for f in decode_instructions(
         prog.instr[t, :, lane][..., None], prog.planes))
@@ -485,13 +335,19 @@ def _stage_rows(prog: Program, cycles_per_block: int = 128) -> Stream:
         raise ValueError(
             f"word at cycle {t[i]}, lane {lane[i]} addresses x row {src[i]}, "
             f"outside the {spare} rows its x buffer holds")
-    m = len(src)
-    done = np.full(spare, m, np.int64)     # stream position of each FINAL
     rows, first = np.unique(src[fin], return_index=True)
     if len(rows) < fin.sum():
         twice = np.setdiff1d(np.arange(fin.sum()), first)[0]
         raise ValueError(f"x row {src[fin][twice]} is finalised twice")
-    done[rows] = np.flatnonzero(fin)[first]
+    return t, lane, src, fin
+
+
+def _check_reads(t, lane, src, fin, rows: int) -> None:
+    """Refuse an EDGE that reads an x row whose FINAL does not come earlier
+    in the entries' order (``rows`` bounds the row numbers)."""
+    m = len(src)
+    done = np.full(rows, m, np.int64)      # stream position of each FINAL
+    done[src[fin]] = np.flatnonzero(fin)
     early = np.flatnonzero(~fin & (done[src] > np.arange(m)))
     if len(early):
         i = early[0]
@@ -499,12 +355,20 @@ def _stage_rows(prog: Program, cycles_per_block: int = 128) -> Stream:
             f"EDGE at cycle {t[i]}, lane {lane[i]} reads x row {src[i]}, "
             f"whose FINAL does not come before it")
 
-    k = min(ROW_BLOCK, _round_up(max(m, 1), SEGMENT_ALIGN))
+
+def _pack(prog: Program, t, lane, src, dst, spare: int) -> Stream:
+    """The entries' staged rows ``src``/``dst`` and pre-gathered values, cut
+    into blocks of `_block_len` entries, only the last one short and padded
+    with the filler entry (the spare row as source and destination, value
+    0: a FINAL of the spare row, which stores only there and leaves a zero
+    sum)."""
+    m = len(src)
+    k = _block_len(m)
     g = max(-(-m // k), 1)
     instr = np.empty((ROW_PLANES, g * k), np.int32)
     instr[:, m:] = spare
     instr[SRC, :m] = src
-    instr[DST, :m] = np.where(fin, src, spare)
+    instr[DST, :m] = dst
     vals = np.zeros(g * k, np.float32)
     vals[:m] = prog.stream[prog.val_idx[t, lane]]
     counts = np.full(g, k, np.int32)
@@ -512,61 +376,110 @@ def _stage_rows(prog: Program, cycles_per_block: int = 128) -> Stream:
     return Stream(
         instr=instr.reshape(ROW_PLANES, g, k).transpose(1, 0, 2).reshape(-1),
         values=vals, counts=counts, k=k,
-        slot_words=_round_up(prog.cycles, cycles_per_block) * prog.num_cus)
+        slot_words=prog.cycles * prog.num_cus)
 
 
-def instr_buffer_bytes(prog: Program, cycles_per_block: int = 128,
-                       placement: str = "resident") -> int:
+def _stage_rows(prog: Program) -> Stream:
+    """Stage the resident kernel's row-serial stream (`kernel` docstring).
+
+    The words of `_row_chains` in FINAL order, each decoded into its
+    `kernel.ROW_PLANES` planes (`kernel.SRC`, `kernel.DST`: a FINAL stores
+    to its own row, an EDGE to the x buffer's spare top row) and its
+    pre-gathered value (`_pack`).  Raises ``ValueError`` on a row outside
+    the x buffer, a row finalised twice, or an EDGE reading a
+    row whose FINAL does not come earlier in this order.
+    """
+    t, lane, src, fin = _row_words(prog)
+    spare = _resident_rows(prog) - 1
+    _check_reads(t, lane, src, fin, spare)
+    return _pack(prog, t, lane, src, np.where(fin, src, spare), spare)
+
+
+def _blocked_order(prog: Program):
+    """``(t, lane, src, fin)`` of the blocked kernel's entries: the rows of
+    `_row_words` in ascending row order, each still its EDGE words in
+    stream order and then its FINAL.  A row's sources are smaller rows, so
+    in a level-ordered program this is level order, and a row's sources sit
+    at most about a level's width back.  Raises ``ValueError`` as
+    `_row_words` does, and on an EDGE reading a row whose FINAL does not
+    come earlier in this order."""
+    t, lane, src, fin = _row_words(prog)
+    if len(src):
+        ends = np.flatnonzero(fin)
+        row = src[ends][np.searchsorted(ends, np.arange(len(src)))]
+        order = np.argsort(row, kind="stable")
+        t, lane, src, fin = t[order], lane[order], src[order], fin[order]
+    _check_reads(t, lane, src, fin, _resident_rows(prog) - 1)
+    return t, lane, src, fin
+
+
+def _stage_blocked(prog: Program, plan: WindowPlan) -> Stream:
+    """Stage the blocked kernel's row-serial stream for ``plan``.
+
+    The entries of `_blocked_order`, in blocks of `_block_len` entries as
+    `plan_window` cut them.  Each entry's x rows are staged into the x ref
+    of `kernel.sptrsv_pallas_blocked`: row ``r`` at ring row ``r %
+    kernel.ring_rows(window, stride)``, and an EDGE stores to the spare row
+    past the ring.  Raises ``ValueError`` on a row outside its block's
+    window, and as `_blocked_order` does.
+    """
+    t, lane, src, fin = _blocked_order(prog)
+    m, w = len(src), plan.window
+    k = _block_len(m)
+    if -(-m // k) != plan.num_blocks:
+        raise ValueError(f"plan of {plan.num_blocks} blocks for a stream of "
+                         f"{-(-m // k)}")
+    blk = np.arange(m) // k
+    row = src - blk * plan.stride
+    bad = np.flatnonzero((row < 0) | (row >= w))
+    if len(bad):
+        i = bad[0]
+        lo = blk[i] * plan.stride
+        raise ValueError(
+            f"word at cycle {t[i]}, lane {lane[i]} addresses x row {src[i]}, "
+            f"outside its block {blk[i]}'s window of rows [{lo}, {lo + w})")
+    ring = ring_rows(w, plan.stride)
+    row = src % ring
+    return _pack(prog, t, lane, row, np.where(fin, row, ring), ring)
+
+
+def instr_buffer_bytes(prog: Program) -> int:
     """SMEM bytes of a kernel's double-buffered instruction streaming.
 
-    Two segment buffers of K entries, each entry its int32 planes and its
-    pre-gathered f32 value.  The resident kernel's row-serial stream has
-    `kernel.ROW_PLANES` planes and K of at most `kernel.ROW_BLOCK` entries
-    (192 KiB).  The blocked kernel's lane-state stream has
-    `kernel.STREAM_PLANES` planes and K the largest cycle block's count of
-    active words rounded up to `kernel.SEGMENT_ALIGN` (1024): at worst
-    (every lane active in every cycle) ``cycles_per_block * P``, 448 KiB at
-    128 cycles of 64 lanes.  A TPU v5e core has 1 MiB of SMEM.
+    Two segment buffers of K entries, each entry its `kernel.ROW_PLANES`
+    int32 planes and its pre-gathered f32 value; K is at most
+    `kernel.ROW_BLOCK` (192 KiB), the same for both kernels, which run the
+    same entries.  A TPU v5e core has 1 MiB of SMEM.
     """
-    if placement == "resident":
-        return 2 * _stage_rows(prog, cycles_per_block).k * (4 * ROW_PLANES + 4)
-    if placement != "blocked":
-        raise ValueError(f"unknown placement {placement!r}")
-    _, active = _active_blocks(prog, cycles_per_block)
-    return 2 * _segment_len(active) * (4 * STREAM_PLANES + 4)
+    return 2 * _block_len(len(_row_chains(prog)[0])) * (4 * ROW_PLANES + 4)
 
 
 def state_bytes(prog: Program, nb: int, *, placement: str,
-                plan: WindowPlan | None = None,
-                cycles_per_block: int = 128) -> dict:
+                plan: WindowPlan | None = None) -> dict:
     """Memory accounting of one Pallas solve, in tiled bytes.
 
     Returns ``{"xb", "vmem", "instr"}`` for ``nb`` RHS columns under
     ``placement`` (``"blocked"`` needs the `WindowPlan`): ``xb`` is the
     solve state (resident: the one x buffer b is copied into; blocked: the
-    x and b windows), ``vmem`` all of the kernel's VMEM (the solve state,
-    plus the blocked kernel's feedback/psum lane state; the resident kernel
-    has none), ``instr`` the SMEM instruction buffers.
+    x ring and its spare row, b read from HBM), ``vmem`` all of the
+    kernel's VMEM (the solve state alone: neither kernel has lane state),
+    ``instr`` the SMEM instruction buffers.
     """
     if placement == "blocked":
         if plan is None or not plan.feasible:
             raise ValueError("blocked accounting needs a feasible WindowPlan")
         xb = plan.state_bytes(nb)
-        vmem = blocked_state_bytes(plan.window, nb, prog.num_cus,
-                                   _psum_slots(prog))
     elif placement == "resident":
-        xb = vmem = resident_state_bytes(_resident_rows(prog), nb)
+        xb = resident_state_bytes(_resident_rows(prog), nb)
     else:
         raise ValueError(f"unknown placement {placement!r}")
-    return {"xb": xb, "vmem": vmem,
-            "instr": instr_buffer_bytes(prog, cycles_per_block, placement)}
+    return {"xb": xb, "vmem": xb, "instr": instr_buffer_bytes(prog)}
 
 
 def build_solver_cols(
     prog: Program,
     width: int,
     *,
-    cycles_per_block: int = 128,
     placement: str = "auto",
     vmem_limit_bytes: int | None = None,
     x_block_rows: int | None = None,
@@ -581,17 +494,17 @@ def build_solver_cols(
     ``closure.plan``, the resolved interpreter flag as
     ``closure.interpret``, and the compaction as ``closure.stream_words``
     (entries executed per solve) against ``closure.slot_words`` (the
-    ``T_pad * P`` lane slots of the uncompacted grid), for tests and
+    ``T * P`` lane slots of the uncompacted grid), for tests and
     diagnostics.
 
-    A compiled resident solve whose x buffer cannot fit a core's VMEM
-    (`kernels.common.resident_fits`) raises `PlacementInfeasibleError`
-    naming its rows and bytes, before Mosaic is asked for it; the
-    interpreter, which has no VMEM, runs it.
+    A compiled resident solve whose x buffer, with Mosaic's headroom,
+    cannot fit a core's VMEM (`kernels.common.resident_fits`) raises
+    `PlacementInfeasibleError` naming its rows and bytes, before Mosaic is
+    asked for it; the interpreter, which has no VMEM, runs it.
     """
     mode, plan = resolve_placement(
         prog, width, placement=placement, vmem_limit_bytes=vmem_limit_bytes,
-        cycles_per_block=cycles_per_block, x_block_rows=x_block_rows,
+        x_block_rows=x_block_rows,
     )
     interpret = resolve_interpret(interpret)
     if mode == "resident" and not interpret and \
@@ -599,15 +512,17 @@ def build_solver_cols(
         rows = _resident_rows(prog)
         vmem = resident_state_bytes(rows, width)
         raise PlacementInfeasibleError(
-            f"resident x buffer of {rows} rows needs {vmem} bytes of VMEM, "
-            f"over the {VMEM_CAPACITY} a core has (placement={placement!r})",
+            f"resident x buffer of {rows} rows needs {vmem} bytes of VMEM "
+            f"and {VMEM_HEADROOM} of headroom, over the {VMEM_CAPACITY} a "
+            f"core has (placement={placement!r})",
             detail={"rows": rows, "vmem_bytes": vmem,
+                    "vmem_headroom": VMEM_HEADROOM,
                     "vmem_capacity": VMEM_CAPACITY})
     if mode == "resident":
-        stream = _stage_rows(prog, cycles_per_block)
+        stream = _stage_rows(prog)
         n_rows = _resident_rows(prog)
     else:
-        stream = _stage_instructions(prog, cycles_per_block, plan)
+        stream = _stage_blocked(prog, plan)
         n_rows = plan.n_hbm
     instr = jnp.asarray(stream.instr)
     values = jnp.asarray(stream.values)
@@ -621,8 +536,7 @@ def build_solver_cols(
             x = sptrsv_pallas(instr, values, counts, bp, interpret=interpret)
         else:
             x = sptrsv_pallas_blocked(
-                instr, values, counts, bp, num_cus=prog.num_cus,
-                num_slots=_psum_slots(prog), window=plan.window,
+                instr, values, counts, bp, window=plan.window,
                 stride=plan.stride, interpret=interpret)
         return x[:n]
 
@@ -641,10 +555,9 @@ def solve(
     prog: Program,
     b: np.ndarray,
     *,
-    cycles_per_block: int = 128,
     interpret: bool | None = None,
     placement: str = "auto",
-    vmem_limit_bytes: int = DEFAULT_STATE_BYTES,
+    vmem_limit_bytes: int | None = None,
     x_block_rows: int | None = None,
 ) -> np.ndarray:
     """Solve Lx=b by executing `prog` in the Pallas kernel.
@@ -664,8 +577,7 @@ def solve(
 
     bmat, single = as_batch(b)
     solver = make_pallas_executor(
-        prog, batch=bmat.shape[1], cycles_per_block=cycles_per_block,
-        placement=placement, vmem_limit_bytes=vmem_limit_bytes,
+        prog, batch=bmat.shape[1], placement=placement, vmem_limit_bytes=vmem_limit_bytes,
         x_block_rows=x_block_rows, interpret=interpret,
     )
     x = np.asarray(solver(bmat))

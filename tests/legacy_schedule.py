@@ -237,8 +237,6 @@ def compile_program(mat: TriCSR, cfg: AccelConfig | None = None, *,
             startable[c][cus[c].pos_of[nd.nid]] = nd.nid
 
     ops_t, val_t, src_t, pct_t, psl_t = [], [], [], [], []
-    rlo_t: list[int] = []  # per-cycle min/max solution row touched
-    rhi_t: list[int] = []  # (row-blocked executor metadata, DESIGN.md §1)
     stream: list[float] = []
     stats = ScheduleStats(name=mat.name, n=n, nnz=mat.nnz, cycles=0,
                           exec_edges=0, exec_finals=0)
@@ -502,13 +500,6 @@ def compile_program(mat: TriCSR, cfg: AccelConfig | None = None, *,
             src_t.append(src_row)
             pct_t.append(pct_row)
             psl_t.append(psl_row)
-            # Solution rows touched this cycle: EDGE lanes read x[src],
-            # FINAL lanes read b[src] and write x[src].  The per-cycle
-            # [lo, hi] envelope is what the row-blocked Pallas path needs
-            # to place its VMEM window.
-            touched = src_row[op_row != 0]
-            rlo_t.append(int(touched.min()))
-            rhi_t.append(int(touched.max()))
         # else: all-NOP stall cycle — counts as hardware time but is elided
         # from the emitted stream (no state changes, no traffic needed)
         cycle += 1
@@ -532,6 +523,4 @@ def compile_program(mat: TriCSR, cfg: AccelConfig | None = None, *,
         val_idx=np.stack(val_t),
         stream=np.array(stream, dtype=np.float32),
         stats=stats,
-        row_lo=np.array(rlo_t, dtype=np.int32),
-        row_hi=np.array(rhi_t, dtype=np.int32),
     )

@@ -242,6 +242,27 @@ def test_linter_silent_on_balanced_band():
     assert lint_program(prog) == []
 
 
+@pytest.mark.parametrize("name,block,warns", [
+    ("band_cz", 1024, False),       # the window slides over its blocks
+    ("ckt_rajat04", 1024, True),    # hub rows stay live across all blocks
+    ("ckt_rajat04", None, False),   # one block: the stream is its window
+])
+def test_linter_judges_blocked_windows_per_row_block(name, block, warns,
+                                                     monkeypatch):
+    """SPT205 judges the blocked kernel's stream as it runs, a window per
+    block of `kernel.ROW_BLOCK` entries: a hub DAG whose stream spans
+    several blocks has no window, and one whose stream fits a block is its
+    own window.  Small programs span several blocks of 1,024 entries."""
+    from repro.kernels.sptrsv import ops
+
+    if block is not None:
+        monkeypatch.setattr(ops, "ROW_BLOCK", block)
+    prog = api.compile(matrices.generate(name))
+    assert (int((prog.opcode != 0).sum()) > ops.ROW_BLOCK) == \
+        (block is not None)
+    assert ("SPT205" in {d.code for d in lint_program(prog)}) == warns
+
+
 def test_analyze_program_report_shape(pipeline):
     *_, prog = pipeline
     report = analyze_program(prog)

@@ -1,16 +1,19 @@
 """The kernels' compacted instruction streams.
 
-The blocked kernel's lane-state stream (`ops._stage_instructions`) keeps,
-per cycle block, only the lane-words that do something, in cycle-major,
-lane-minor order, each pre-decoded into the rows its accesses use and its
-value.  The resident kernel's row-serial stream (`ops._stage_rows`) follows
-each lane's psum control once and runs the rows in the order of their
-FINALs, each as its EDGE words in stream order and then its FINAL.  Either
-kernel executes its entries and nothing else, branch-free, and the answer
-must stay bit-identical to the float32 `lax.scan` executor.
+Both kernels run a row-serial stream: staging follows each lane's psum
+control once (`ops._row_chains`) and runs each row as its EDGE words in
+stream order and then its FINAL, each entry pre-decoded into the rows its
+accesses use and its value.  The resident kernel's stream (`ops._stage_rows`)
+has the rows in the order of their FINALs; the blocked kernel's
+(`ops._stage_blocked`) in ascending row order, its rows staged into the
+window of their block.  Either kernel executes its entries and nothing else,
+branch-free, and the answer must stay bit-identical to the float32
+`lax.scan` executor.
 """
 
+import dataclasses
 import functools
+import hashlib
 import json
 from pathlib import Path
 
@@ -37,7 +40,6 @@ from repro.core.program import (
 from repro.core.compiler import compile_program
 from repro.kernels.sptrsv import kernel, ops
 
-CPB = 64
 BENCH_CONFIG = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
                 / "configs" / "ckt_add20.json")
 
@@ -46,132 +48,6 @@ BENCH_CONFIG = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 def _prog(name: str, planes: int | None = None, psum_words: int | None = None):
     cfg = AccelConfig(psum_words=psum_words) if psum_words else None
     return compile_program(generate(name), cfg, planes=planes)
-
-
-def _unpack(stream: ops.Stream):
-    """Per-block ``(planes [STREAM_PLANES, k], values [k])``."""
-    g = stream.counts.shape[0]
-    instr = stream.instr.reshape(g, kernel.STREAM_PLANES, stream.k)
-    return instr, stream.values.reshape(g, -1)
-
-
-def _expected_planes(prog, t, lane):
-    """The staged planes of the words at (cycle t, lane), one word at a
-    time: which rows each access of the kernel uses."""
-    p, s = prog.num_cus, _psum_slots(prog)
-    zero, trash = p * (s + 1), p * (s + 1) + 1
-    op, src, ctl, slot = decode_instructions(prog.instr, prog.planes)
-    out = []
-    for ti, li in zip(t.tolist(), lane.tolist()):
-        o, r, c = int(op[ti, li]), int(src[ti, li]), int(ctl[ti, li])
-        slot_row = p + li * s + min(int(slot[ti, li]), s - 1)
-        pv_src = {PS_KEEP: li, PS_RESET: zero, PS_STORE_RESET: zero,
-                  PS_LOAD: slot_row, PS_SWAP: slot_row}[c]
-        park = slot_row if c in (PS_STORE_RESET, PS_SWAP) else trash
-        x_dst = r if o == OP_FINAL else ops._resident_rows(prog) - 1
-        out.append([r, pv_src, li, park, x_dst, int(o == OP_EDGE)])
-    return np.asarray(out, np.int64).T.reshape(kernel.STREAM_PLANES, -1)
-
-
-def _active_in_order(prog, cpb):
-    """(block, cycle, lane) of every active word, cycle-major, lane-minor."""
-    op, _, ctl, _ = decode_instructions(prog.instr, prog.planes)
-    t, lane = np.nonzero((op != OP_NOP) | (ctl != PS_KEEP))
-    return t // cpb, t, lane
-
-
-@pytest.mark.parametrize("name,planes", [
-    ("ckt_rajat04", 1), ("band_cz", 2), ("hub_small", 1),
-])
-def test_staging_keeps_active_words_in_order(name, planes):
-    prog = _prog(name, planes)
-    stream = ops._stage_instructions(prog, CPB)
-    staged, values = _unpack(stream)
-    blk, t, lane = _active_in_order(prog, CPB)
-    assert stream.counts.sum() == len(t)
-    assert stream.counts.tolist() == np.bincount(
-        blk, minlength=len(stream.counts)).tolist()
-    assert stream.k % kernel.SEGMENT_ALIGN == 0
-    assert stream.k >= stream.counts.max()
-    vals = prog.stream[prog.val_idx].astype(np.float32)
-    for g, c in enumerate(stream.counts):
-        sel = blk == g
-        assert np.array_equal(staged[g, :, :c],
-                              _expected_planes(prog, t[sel], lane[sel]))
-        assert np.array_equal(values[g, :c], vals[t[sel], lane[sel]])
-
-
-def test_filler_is_nop_keep_lane_zero():
-    """The filler (every slot past a block's count) is the no-op of the
-    staged form: it sums from the zero row, adds no MAC, and stores only to
-    the trash row and the spare x row, which no word reads."""
-    prog = _prog("ckt_rajat04")
-    stream = ops._stage_instructions(prog, CPB)
-    staged, values = _unpack(stream)
-    p, s = prog.num_cus, _psum_slots(prog)
-    zero, trash = p * (s + 1), p * (s + 1) + 1
-    assert kernel.lane_rows(p, s) == trash + 1
-    spare = ops._resident_rows(prog) - 1
-    filler = [0, zero, trash, trash, spare, 0]
-    for g, c in enumerate(stream.counts):
-        assert (staged[g, :, c:] == np.asarray(filler)[:, None]).all()
-        assert not values[g, c:].any()
-        real = staged[g, :, :c]
-        assert (real[kernel.PV_SRC] != trash).all()    # trash is never read
-        assert (real[kernel.FB] < p).all()
-        assert ((real[kernel.PARK] >= p) & (real[kernel.PARK] != zero)).all()
-
-
-@pytest.mark.parametrize("name,planes,psum_words", [
-    ("ckt_rajat04", 1, None), ("band_cz", 2, None), ("hub_small", 1, None),
-    ("hpcg_8", 1, None), ("ckt_rajat04", 1, 2), ("chain_1k", 1, None),
-])
-def test_staged_rows_stay_in_their_lane(name, planes, psum_words):
-    """Every entry reads and writes only its own lane's feedback and psum
-    rows, the zero row or the trash row, and stores x only to its FINAL row
-    or to the spare top row of the x ref, which no word loads; in the
-    blocked placement x rows are relative to their block's window."""
-    prog = _prog(name, planes, psum_words)
-    assert prog.planes == planes
-    p, s = prog.num_cus, _psum_slots(prog)
-    zero, trash = p * (s + 1), p * (s + 1) + 1
-    plan = ops.plan_window(prog, CPB)
-    placed = [(None, ops._resident_rows(prog), 0)]
-    if plan.feasible:
-        placed.append((plan, plan.window, plan.stride))
-    for pl_, rows, stride in placed:
-        stream = ops._stage_instructions(prog, CPB, pl_)
-        staged, _ = _unpack(stream)
-        blk, t, lane = _active_in_order(prog, CPB)
-        src = decode_instructions(prog.instr, prog.planes)[1][t, lane]
-        for g, c in enumerate(stream.counts):
-            row, pv_src, fb, park, x_dst, edge = staged[g, :, :c]
-            own = (p + fb * s <= pv_src) & (pv_src < p + fb * s + s)
-            assert ((pv_src == fb) | (pv_src == zero) | own).all()
-            own = (p + fb * s <= park) & (park < p + fb * s + s)
-            assert ((park == trash) | own).all()
-            assert ((x_dst == row) | (x_dst == rows - 1)).all()
-            assert (0 <= row).all() and (row < rows - 1).all()
-            assert np.array_equal(row + g * stride, src[blk == g])
-            assert set(edge.tolist()) <= {0, 1}
-
-
-def test_staging_refuses_rows_outside_the_ref():
-    """A word addressing an x row past n (a corrupt program) is refused at
-    staging: the kernel uses staged rows unchecked."""
-    import dataclasses
-
-    from repro.core.program import pack_instructions
-
-    prog = _prog("ckt_rajat04")
-    op, src, ctl, slot = decode_instructions(prog.instr, prog.planes)
-    t, lane = np.argwhere(op == OP_FINAL)[0]
-    src = src.copy()
-    src[t, lane] = ops._resident_rows(prog)
-    bad = dataclasses.replace(
-        prog, instr=pack_instructions(op, src, ctl, slot, planes=prog.planes))
-    with pytest.raises(ValueError, match="outside"):
-        ops._stage_instructions(bad, CPB)
 
 
 def _row_entries(stream: ops.Stream):
@@ -220,7 +96,7 @@ def test_row_serial_stream_order(name, planes, psum_words):
     every EDGE's source row is finished before it."""
     prog = _prog(name, planes, psum_words)
     assert prog.planes == planes
-    stream = ops._stage_rows(prog, CPB)
+    stream = ops._stage_rows(prog)
     (src, dst), values = _row_entries(stream)
     t, lane = _row_serial_reference(prog)
     op, x_row, _, _ = decode_instructions(prog.instr, prog.planes)
@@ -254,10 +130,89 @@ def test_row_serial_stream_order(name, planes, psum_words):
     assert not stream.values[len(src):].any()
 
 
+def _ascending(t, lane, prog):
+    """The row-serial reference's words regrouped row by row in ascending
+    row order, each row's words kept in their order."""
+    op, x_row, _, _ = decode_instructions(prog.instr, prog.planes)
+    chains, cur = [], []
+    for w in zip(t.tolist(), lane.tolist()):
+        cur.append(w)
+        if op[w] == OP_FINAL:
+            chains.append((int(x_row[w]), cur))
+            cur = []
+    chains.sort(key=lambda c: c[0])
+    return np.asarray([w for _, c in chains for w in c], np.int64).T
+
+
+@pytest.mark.parametrize("name,planes", [
+    ("ckt_rajat04", 1), ("band_cz", 2), ("hub_small", 1),
+])
+def test_blocked_staging_keeps_rows_in_ascending_order(name, planes):
+    """The blocked kernel's entries are the resident kernel's, regrouped in
+    ascending row order: each row its EDGE words in stream order, then its
+    FINAL; every EDGE's source row is finished before it."""
+    prog = _prog(name, planes)
+    assert prog.planes == planes
+    t, lane, src, fin = ops._blocked_order(prog)
+    want_t, want_lane = _ascending(*_row_serial_reference(prog), prog)
+    assert np.array_equal(t, want_t) and np.array_equal(lane, want_lane)
+    op, x_row, _, _ = decode_instructions(prog.instr, prog.planes)
+    assert np.array_equal(src, x_row[t, lane])
+    assert np.array_equal(fin, op[t, lane] == OP_FINAL)
+    assert (np.diff(src[fin]) > 0).all()
+    done = np.full(prog.n, len(src))
+    done[src[fin]] = np.flatnonzero(fin)
+    assert (done[src[~fin]] < np.flatnonzero(~fin)).all()
+
+
+@pytest.mark.usefixtures("small_row_blocks")
+def test_blocked_filler_is_a_final_of_the_spare_row():
+    """The filler past the last block's count loads and stores the spare row
+    past the ring with value 0, and no real entry loads that row."""
+    prog = _prog("band_cz")
+    plan = ops.plan_window(prog)
+    stream = ops._stage_blocked(prog, plan)
+    (src, dst), values = _row_entries(stream)
+    spare = kernel.ring_rows(plan.window, plan.stride)
+    tail = stream.instr.reshape(-1, kernel.ROW_PLANES, stream.k)[-1]
+    assert plan.num_blocks > 1 and stream.counts[-1] < stream.k
+    assert (tail[:, stream.counts[-1]:] == spare).all()
+    assert not stream.values[len(src):].any()
+    assert (src < spare).all() and ((dst == src) | (dst == spare)).all()
+
+
+@pytest.mark.usefixtures("small_row_blocks")
+@pytest.mark.parametrize("name,planes,psum_words", [
+    ("ckt_rajat04", 1, None), ("band_cz", 2, None), ("hub_small", 1, None),
+    ("hpcg_8", 1, None), ("ckt_rajat04", 1, 2), ("chain_1k", 1, None),
+])
+def test_blocked_rows_stay_in_their_window(name, planes, psum_words,
+                                           monkeypatch):
+    """Every entry loads and stores only rows of its block's window, at
+    their ring rows, or stores to the spare row past the ring.  Hub DAGs
+    keep an early row live across the whole stream: no window slides, and
+    one block holding the whole stream is their only plan."""
+    prog = _prog(name, planes, psum_words)
+    assert prog.planes == planes
+    plan = ops.plan_window(prog)
+    if not plan.feasible:
+        assert name in ("ckt_rajat04", "hub_small")
+        monkeypatch.setattr(ops, "ROW_BLOCK", 8 * kernel.SEGMENT_ALIGN)
+        plan = ops.plan_window(prog)
+        assert plan.feasible and plan.num_blocks == 1
+    stream = ops._stage_blocked(prog, plan)
+    _, _, rows, fin = ops._blocked_order(prog)
+    (src, dst), _ = _row_entries(stream)
+    blk = np.arange(len(src)) // stream.k
+    lo = blk * plan.stride
+    assert ((lo <= rows) & (rows < lo + plan.window)).all()
+    ring = kernel.ring_rows(plan.window, plan.stride)
+    assert np.array_equal(src, rows % ring)
+    assert np.array_equal(dst, np.where(fin, src, ring))
+
+
 def _edit_word(prog, t, lane, **fields):
     """``prog`` with the word at (t, lane) given new decoded fields."""
-    import dataclasses
-
     from repro.core.program import pack_instructions
 
     op, src, ctl, slot = (f.copy() for f in decode_instructions(
@@ -277,7 +232,7 @@ def test_row_staging_refuses_a_read_before_the_final():
     t, lane = np.argwhere(op == OP_EDGE)[0]               # an early EDGE
     bad = _edit_word(prog, t, lane, src=src[t_fin, l_fin])
     with pytest.raises(ValueError, match="FINAL does not come before"):
-        ops._stage_rows(bad, CPB)
+        ops._stage_rows(bad)
 
 
 def test_row_staging_refuses_a_word_after_its_final():
@@ -295,7 +250,7 @@ def test_row_staging_refuses_a_word_after_its_final():
     else:
         pytest.fail("no FINAL followed by a RESET word on its lane")
     with pytest.raises(ValueError, match="after the FINAL"):
-        ops._stage_rows(bad, CPB)
+        ops._stage_rows(bad)
 
 
 def test_row_staging_refuses_a_row_finalised_twice():
@@ -306,7 +261,7 @@ def test_row_staging_refuses_a_row_finalised_twice():
     (t0, l0), (t1, l1) = np.argwhere(op == OP_FINAL)[:2]
     bad = _edit_word(prog, t1, l1, src=src[t0, l0])
     with pytest.raises(ValueError, match="finalised twice"):
-        ops._stage_rows(bad, CPB)
+        ops._stage_rows(bad)
 
 
 def test_row_staging_refuses_rows_outside_the_buffer():
@@ -317,47 +272,96 @@ def test_row_staging_refuses_rows_outside_the_buffer():
     t, lane = np.argwhere(op == OP_FINAL)[0]
     bad = _edit_word(prog, t, lane, src=ops._resident_rows(prog))
     with pytest.raises(ValueError, match="outside"):
-        ops._stage_rows(bad, CPB)
+        ops._stage_rows(bad)
+
+
+@pytest.mark.usefixtures("small_row_blocks")
+@pytest.mark.parametrize("fault", ["row past the buffer", "window too small",
+                                   "edge before its final"])
+def test_blocked_staging_refuses(fault):
+    """The blocked kernel uses staged rows unchecked too: staging refuses a
+    row past the x buffer, a plan whose window does not hold a block's
+    rows, and an EDGE whose source row is finalised after it."""
+    prog = _prog("band_cz")
+    plan = ops.plan_window(prog)
+    op, src, _, _ = decode_instructions(prog.instr, prog.planes)
+    if fault == "row past the buffer":
+        t, lane = np.argwhere(op == OP_FINAL)[0]
+        prog = _edit_word(prog, t, lane, src=ops._resident_rows(prog))
+        match = "outside"
+    elif fault == "window too small":
+        plan = dataclasses.replace(plan, window=8)
+        match = "outside its block"
+    else:
+        t_fin, l_fin = np.argwhere(op == OP_FINAL)[-1]   # the last row
+        t, lane = np.argwhere(op == OP_EDGE)[0]          # an early EDGE
+        prog = _edit_word(prog, t, lane, src=src[t_fin, l_fin])
+        match = "FINAL does not come before"
+    with pytest.raises(ValueError, match=match):
+        ops._stage_blocked(prog, plan)
 
 
 def test_ckt_add20_compaction_counts():
-    """The benchmark's circuit: 9,373 active words of 254 x 64 lane slots
-    padded to 256 cycles."""
+    """The benchmark's circuit: 9,373 words that do arithmetic, of 254 x 64
+    lane slots."""
     config = json.loads(BENCH_CONFIG.read_text())
     mat = generate("ckt_add20")
     assert (mat.n, mat.nnz) == (config["n"], config["fingerprint"]["nnz"])
     prog = api.compile(mat)
     assert prog.stats.emitted_cycles == 254
-    stream = ops._stage_instructions(prog, 128)
-    assert stream.counts.sum() == 9373
-    assert stream.slot_words == 16384
-    assert 9373 <= stream.stream_words <= 9373 + 2 * (kernel.UNROLL - 1)
+    op = decode_instructions(prog.instr, prog.planes)[0]
+    assert (op != OP_NOP).sum() == 9373
     # the resident kernel runs the row-serial stream: the same 9,373 words
     # (no psum-only ones) in blocks of ROW_BLOCK, the last group of 8 of
     # the short last block filled out
-    rows = ops._stage_rows(prog, 128)
+    rows = ops._stage_rows(prog)
     assert rows.counts.tolist() == [kernel.ROW_BLOCK, 9373 - kernel.ROW_BLOCK]
-    assert rows.stream_words == 9376 and rows.slot_words == 16384
+    assert rows.stream_words == 9376 and rows.slot_words == 254 * 64
     solver = executor.make_pallas_executor(prog, batch=1, interpret=True)
     assert solver.placement == "resident"
-    assert (solver.stream_words, solver.slot_words) == (9376, 16384)
+    assert (solver.stream_words, solver.slot_words) == (9376, 254 * 64)
 
 
-def test_instr_buffer_bytes_counts_compacted_segments():
-    prog = _prog("ckt_rajat04")
-    stream = ops._stage_instructions(prog, 128)
-    got = ops.instr_buffer_bytes(prog, 128, "blocked")
-    assert got == 2 * stream.k * (4 * kernel.STREAM_PLANES + 4)
-    # never more than the whole lane grid of one block, double-buffered
-    assert got <= 2 * 128 * prog.num_cus * (4 * kernel.STREAM_PLANES + 4)
-    # the resident kernel streams row-serial entries: 3 planes + the value
-    rows = ops._stage_rows(prog, 128)
-    got = ops.instr_buffer_bytes(prog, 128)
+# sha256 of `_stage_rows` (instr, values, counts, k) at the commit before
+# the blocked kernel took the row-serial stream: the resident kernel's
+# stream must not move with it
+STAGE_ROWS_DIGESTS = {
+    "ckt_add20":
+        "3edefc30b2f78d3a8d9176d16a145d681798fbac6fcb54bc94ddf666ccb4f1f0",
+    "hpcg_8":
+        "76d2fd70c5f9ee64d90b6e2df22ced646a9432b944188ca7d14f2281a9b78677",
+}
+
+
+@pytest.mark.parametrize("name", sorted(STAGE_ROWS_DIGESTS))
+def test_resident_stream_unchanged(name):
+    stream = ops._stage_rows(api.compile(generate(name)))
+    h = hashlib.sha256()
+    for a in (stream.instr, stream.values, stream.counts):
+        h.update(np.ascontiguousarray(a).tobytes())
+        h.update(str(a.dtype).encode())
+    h.update(str(stream.k).encode())
+    assert h.hexdigest() == STAGE_ROWS_DIGESTS[name]
+
+
+@pytest.mark.parametrize("name", ["ckt_rajat04", "band_cz"])
+def test_instr_buffer_bytes_counts_row_blocks(name):
+    """Both kernels stream the same row-serial entries: two buffers of K
+    entries of ROW_PLANES int32 planes and a value, K at most ROW_BLOCK."""
+    prog = _prog(name)
+    rows = ops._stage_rows(prog)
+    got = ops.instr_buffer_bytes(prog)
     assert got == 2 * rows.k * (4 * kernel.ROW_PLANES + 4)
     assert got <= 2 * kernel.ROW_BLOCK * (4 * kernel.ROW_PLANES + 4)
     acct = ops.state_bytes(prog, 1, placement="resident")
     assert acct["instr"] == got
     assert acct["vmem"] == acct["xb"]      # no lane state
+    plan = ops.plan_window(prog)
+    if plan.feasible:
+        assert ops._stage_blocked(prog, plan).k == rows.k
+        acct = ops.state_bytes(prog, 1, placement="blocked", plan=plan)
+        assert acct["instr"] == got
+        assert acct["vmem"] == acct["xb"] == plan.state_bytes(1)
 
 
 @pytest.mark.parametrize("placement", ["resident", "blocked"])
@@ -365,17 +369,20 @@ def test_instr_buffer_bytes_counts_compacted_segments():
 @pytest.mark.parametrize("batch", [1, 16])
 @pytest.mark.parametrize("name", ["band_cz", "ckt_rajat04", "hub_small",
                                   "hpcg_8", "chain_1k"])
-def test_kernel_bit_identical_to_scan(name, batch, planes, placement):
+def test_kernel_bit_identical_to_scan(name, batch, planes, placement,
+                                      request):
+    if placement == "blocked":
+        request.getfixturevalue("small_row_blocks")
     prog = _prog(name, planes)
     assert prog.planes == planes
     b = np.stack([random_rhs(generate(name), 7 + j) for j in range(batch)],
                  axis=1).astype(np.float32)
-    if placement == "blocked" and not ops.plan_window(prog, CPB).feasible:
+    if placement == "blocked" and not ops.plan_window(prog).feasible:
         with pytest.raises(PlacementInfeasibleError):
-            ops.solve(prog, b, cycles_per_block=CPB, interpret=True,
+            ops.solve(prog, b, interpret=True,
                       placement=placement)
         return
-    x = ops.solve(prog, b, cycles_per_block=CPB, interpret=True,
+    x = ops.solve(prog, b, interpret=True,
                   placement=placement)
     assert np.array_equal(x, executor.execute_jax(prog, b))
 
@@ -384,34 +391,38 @@ def test_kernel_bit_identical_to_scan(name, batch, planes, placement):
     ("ckt_rajat04", "resident"), ("chem_bp", "resident"), ("chem_bp", "blocked"),
     ("hpcg_8", "resident"), ("chain_1k", "resident"), ("chain_1k", "blocked"),
 ])
-def test_psum_starved_program_bit_identical(name, placement):
+def test_psum_starved_program_bit_identical(name, placement, request):
     """Two psum words per lane force slot spills: SWAP, STORE_RESET and LOAD
-    entries run through the select-stores."""
+    words, whose chains staging follows into rows."""
+    if placement == "blocked":
+        request.getfixturevalue("small_row_blocks")
     prog = _prog(name, psum_words=2)
     _, _, ctl, _ = decode_instructions(prog.instr, prog.planes)
     for c in (PS_LOAD, PS_STORE_RESET, PS_SWAP):
         assert (ctl == c).any(), c
     if placement == "blocked":
-        assert ops.plan_window(prog, CPB).feasible
+        assert ops.plan_window(prog).feasible
     b = np.stack([random_rhs(generate(name), j) for j in range(3)],
                  axis=1).astype(np.float32)
-    x = ops.solve(prog, b, cycles_per_block=CPB, interpret=True,
+    x = ops.solve(prog, b, interpret=True,
                   placement=placement)
     assert np.array_equal(x, executor.execute_jax(prog, b))
 
 
 @pytest.mark.parametrize("placement", ["resident", "blocked"])
-def test_psum_starved_b16_bit_identical(placement):
-    """16 columns through a psum-starved HPCG program: the resident kernel's
-    running sum is one ``[1, 16]`` row carried across blocks."""
+def test_psum_starved_b16_bit_identical(placement, request):
+    """16 columns through a psum-starved HPCG program: the running sum is
+    one ``[1, 16]`` row carried across blocks."""
+    if placement == "blocked":
+        request.getfixturevalue("small_row_blocks")
     prog = _prog("hpcg_8", psum_words=2)
     b = np.stack([random_rhs(generate("hpcg_8"), 40 + j) for j in range(16)],
                  axis=1).astype(np.float32)
-    if placement == "blocked" and not ops.plan_window(prog, CPB).feasible:
+    if placement == "blocked" and not ops.plan_window(prog).feasible:
         with pytest.raises(PlacementInfeasibleError):
-            ops.solve(prog, b, cycles_per_block=CPB, interpret=True,
+            ops.solve(prog, b, interpret=True,
                       placement=placement)
         return
-    x = ops.solve(prog, b, cycles_per_block=CPB, interpret=True,
+    x = ops.solve(prog, b, interpret=True,
                   placement=placement)
     assert np.array_equal(x, executor.execute_jax(prog, b))

@@ -2,7 +2,7 @@
 
 The pipeline (`core/compiler/`) must reproduce the frozen pre-refactor
 monolith (`tests/legacy_schedule.py`) bit-for-bit: identical packed
-instruction stream, value stream, row envelopes and stats on the bundled
+instruction stream, value stream and stats on the bundled
 matrix suite — a fast subset in tier-1, the full suite marked ``slow``.
 """
 
@@ -47,8 +47,6 @@ def assert_programs_identical(a, b, ctx=""):
     assert np.array_equal(a.instr, b.instr), f"{ctx}: instr differs"
     assert np.array_equal(a.val_idx, b.val_idx), f"{ctx}: val_idx differs"
     assert np.array_equal(a.stream, b.stream), f"{ctx}: stream differs"
-    assert np.array_equal(a.row_lo, b.row_lo), f"{ctx}: row_lo differs"
-    assert np.array_equal(a.row_hi, b.row_hi), f"{ctx}: row_hi differs"
     assert a.num_slots == b.num_slots, ctx
     da, pa = _stats_dict(a.stats)
     db, pb = _stats_dict(b.stats)

@@ -64,11 +64,9 @@ def test_upper_solve_suite_matrix_all_executors():
     np.testing.assert_allclose(cw.solve(b, backend="numpy"), ref, **TOL)
     np.testing.assert_allclose(cw.solve(b, backend="jax"), ref, **TOL)
     np.testing.assert_allclose(
-        cw.solve(b, backend="pallas", placement="resident",
-                 cycles_per_block=64), ref, **TOL)
+        cw.solve(b, backend="pallas", placement="resident"), ref, **TOL)
     np.testing.assert_allclose(
-        cw.solve(b, backend="pallas", placement="blocked",
-                 cycles_per_block=64), ref, **TOL)
+        cw.solve(b, backend="pallas", placement="blocked"), ref, **TOL)
 
 
 def test_upper_batched_and_sharded():
@@ -118,8 +116,7 @@ def test_pair_pallas_blocked_placement():
     b = np.random.default_rng(13).standard_normal(mat.n)
     dense = mat.to_dense()
     ref = np.linalg.solve(dense @ dense.T, b)
-    got = pair.solve(b, backend="pallas", placement="blocked",
-                     cycles_per_block=64)
+    got = pair.solve(b, backend="pallas", placement="blocked")
     np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
 
 
@@ -144,21 +141,19 @@ def test_circuit_pallas_and_batched():
     ref = circ.eval(umat)
     np.testing.assert_allclose(cw.solve(umat), ref, **TOL)
     np.testing.assert_allclose(
-        cw.solve(umat, backend="pallas", placement="resident",
-                 cycles_per_block=32), ref, **TOL)
+        cw.solve(umat, backend="pallas", placement="resident"), ref, **TOL)
 
 
-def test_circuit_pallas_blocked_placement():
+def test_circuit_pallas_blocked_placement(small_row_blocks):
     """Strongly-local circuits admit the row-blocked window placement."""
     circ = random_circuit(1024, max_fan_in=4, seed=21, locality=48)
     cw = api.compile_circuit(circ)
     from repro.kernels.sptrsv import ops
 
-    plan = ops.plan_window(cw.program, 32)
+    plan = ops.plan_window(cw.program)
     assert plan.feasible and plan.num_blocks > 1
     u = np.random.default_rng(1).standard_normal((circ.n, 4))
-    got = cw.solve(u, backend="pallas", placement="blocked",
-                   cycles_per_block=32)
+    got = cw.solve(u, backend="pallas", placement="blocked")
     np.testing.assert_allclose(got, circ.eval(u), **TOL)
 
 

@@ -12,16 +12,15 @@ from repro.core.matrices import generate
 
 
 # ------------------------------------------------------------------ sptrsv
-@pytest.mark.parametrize("name,cpb", [
-    ("chain_1k", 128), ("band_cz", 64), ("ckt_rajat04", 256), ("chem_bp", 32),
-])
-def test_sptrsv_kernel_vs_oracle(name, cpb):
+@pytest.mark.parametrize("name", ["chain_1k", "band_cz", "ckt_rajat04",
+                                  "chem_bp"])
+def test_sptrsv_kernel_vs_oracle(name):
     from repro.kernels.sptrsv import ops
 
     mat = generate(name)
     prog = api.compile(mat)
     b = random_rhs(mat, 3)
-    x = ops.solve(prog, b, cycles_per_block=cpb, interpret=True)
+    x = ops.solve(prog, b, interpret=True)
     np.testing.assert_allclose(
         x, serial_solve(mat, b).astype(np.float32), rtol=2e-4, atol=2e-4
     )
@@ -42,8 +41,7 @@ def test_sptrsv_kernel_same_cycle_finals(name, placement):
     finals = (prog.opcode == OP_FINAL).sum(axis=1)
     assert finals.max() >= 2, finals.max()
     bmat = np.random.default_rng(5).standard_normal((mat.n, 3))
-    x = ops.solve(prog, bmat, cycles_per_block=64, interpret=True,
-                  placement=placement)
+    x = ops.solve(prog, bmat, interpret=True, placement=placement)
     # same float32 operations in the same order as the lax.scan executor
     np.testing.assert_array_equal(x, api.solve_batch(prog, bmat))
     ref = np.stack([serial_solve(mat, bmat[:, j]) for j in range(3)], axis=1)

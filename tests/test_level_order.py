@@ -98,7 +98,8 @@ def test_level_order_compiles_the_permuted_matrix():
 # ------------------------------------------------------------ executors
 @pytest.mark.parametrize("batch", [1, 16])
 @pytest.mark.parametrize("name", MATS)
-def test_every_executor_solves_in_the_users_order(name, batch, level_progs):
+def test_every_executor_solves_in_the_users_order(name, batch, level_progs,
+                                                 small_row_blocks):
     mat, prog = generate(name), level_progs[name]
     bmat = np.random.default_rng(batch).standard_normal((mat.n, batch))
     ref = _serial(mat, bmat)
@@ -108,9 +109,8 @@ def test_every_executor_solves_in_the_users_order(name, batch, level_progs):
         "resident": api.solve_batch(prog, bmat, backend="pallas",
                                     placement="resident", interpret=True),
     }
-    blocked = dict(backend="pallas", placement="blocked", interpret=True,
-                   cycles_per_block=32)
-    if ops.plan_window(prog, 32).feasible:
+    blocked = dict(backend="pallas", placement="blocked", interpret=True)
+    if ops.plan_window(prog).feasible:
         solver = api.make_solver(prog, batch=batch, **blocked)
         assert solver.placement == "blocked" and solver.plan.num_blocks > 1
         got["blocked"] = np.asarray(solver(bmat))
@@ -178,16 +178,37 @@ def test_identity_order_traces_no_gather():
 
 
 # ------------------------------------------------------------ the mechanism
-def test_level_order_makes_the_window_slide():
-    """At 32³ the natural order keeps over 80% of the rows in the window;
-    level order under 25% (27,408 and 6,192 of 32,768)."""
+@pytest.fixture(scope="module")
+def lap32():
+    """The 7-point Laplacian's IC(0) factor at 32³, in natural and in level
+    order."""
     mat = laplace7_ic0(32, "lap7_ic0_32")
-    natural = ops.plan_window(api.compile(mat))
-    level = ops.plan_window(level_compile(api.compile, mat))
+    return mat, api.compile(mat), level_compile(api.compile, mat)
+
+
+def test_level_order_makes_the_window_slide(lap32):
+    """At 32³ the blocked stream in natural order keeps 40% of the rows in
+    the window, in level order 12% (13,200 and 3,936 of 32,768)."""
+    mat, natural, level = lap32
+    natural, level = ops.plan_window(natural), ops.plan_window(level)
     assert natural.feasible and level.feasible
-    assert natural.window > 0.8 * mat.n
-    assert level.window < 0.25 * mat.n
-    assert (natural.window, level.window) == (27408, 6192)
+    assert natural.window > 0.35 * mat.n
+    assert level.window < 0.15 * mat.n
+    assert (natural.window, level.window) == (13200, 3936)
+
+
+def test_level_ordered_plan_at_32(lap32):
+    """The blocked stream of the level-ordered factor at 32³: 128,000
+    row-serial entries in 16 blocks of `kernel.ROW_BLOCK`, stride 1,968 (a
+    level is at most 1,024 rows wide, so a row's sources sit close behind
+    it), window 3,936 = 2 strides: its ring of 4 strides takes 4.0 MB at
+    128 lanes."""
+    mat, _, level = lap32
+    plan = ops.plan_window(level)
+    assert len(ops._blocked_order(level)[2]) == 128000
+    assert (plan.num_blocks, plan.stride, plan.window) == (16, 1968, 3936)
+    assert plan.n_hbm == 15 * 1968 + 3936 >= mat.n
+    assert plan.state_bytes(1) == common.tiled_bytes(4 * 1968 + 1, 128)
 
 
 # ------------------------------------------------------------ auto
@@ -204,10 +225,13 @@ def test_auto_keeps_every_suite_matrix_natural():
 
 
 def test_auto_picks_level_past_vmem_without_compiling():
+    """Level order starts where one column of resident x and the VMEM
+    headroom the kernel asks Mosaic for pass a core's 128 MiB."""
     assert order.wants_level(72 ** 3)          # lap7_ic0_72: 373,248 rows
-    assert order.wants_level(64 ** 3)          # over by one 4 KB tile row
-    assert not order.wants_level(64 ** 3 - 1)  # exactly 128 MiB
-    assert common.tiled_bytes(64 ** 3, 1) == common.VMEM_CAPACITY
+    assert order.wants_level(253_952)          # over by one 4 KB tile row
+    assert not order.wants_level(253_951)      # exactly 128 MiB
+    assert (common.tiled_bytes(253_952, 1) + common.VMEM_HEADROOM
+            == common.VMEM_CAPACITY)
 
 
 # ------------------------------------------------------------ life cycle
@@ -242,11 +266,12 @@ def test_recompile_values_in_level_order_equals_a_full_recompile():
 
 # ------------------------------------------------------------ refusal
 @pytest.mark.parametrize("placement", ["auto", "resident"])
-def test_placement_past_vmem_is_refused(placement):
+def test_placement_past_vmem_is_refused(placement, small_row_blocks):
     """A compiled resident solve whose x is past a core's VMEM, with no
     smaller window, raises before Mosaic is asked (the hub DAG has no
-    window; its n is stretched past 2^18 rows without compiling anything
-    that large).  The interpreter has no VMEM and builds it."""
+    window over its blocks; its n is stretched past 2^18 rows without
+    compiling anything that large).  The interpreter has no VMEM and builds
+    it."""
     prog = api.compile(generate("ckt_rajat04"))
     big = dataclasses.replace(prog, n=300_000)
     with pytest.raises(PlacementInfeasibleError, match="300008 rows") as e:

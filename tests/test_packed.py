@@ -18,7 +18,7 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from repro.core import api, executor
+from repro.core import api
 from repro.core.csr import random_rhs, serial_solve
 from repro.core.matrices import generate
 from repro.core.program import (
@@ -184,8 +184,7 @@ def test_two_plane_blocked_placement_parity():
     from repro.kernels.sptrsv import ops
 
     b = random_rhs(mat, 23)
-    x = ops.solve(prog, b, cycles_per_block=64, interpret=True,
-                  placement="blocked")
+    x = ops.solve(prog, b, interpret=True, placement="blocked")
     ref = serial_solve(mat, b)
     np.testing.assert_allclose(x, ref, rtol=1e-5,
                                atol=1e-5 * np.abs(ref).max())
@@ -201,7 +200,6 @@ def test_stall_rows_elided_with_parity():
     st_ = prog.stats
     assert st_.emitted_cycles < st_.cycles, "config did not provoke stalls"
     assert prog.cycles == st_.emitted_cycles
-    assert prog.row_lo.shape == (prog.cycles,)
     # elided rows carried no work: per-op totals are unchanged
     assert (prog.opcode == 1).sum() == st_.exec_edges
     assert (prog.opcode == 2).sum() == st_.exec_finals
@@ -227,28 +225,28 @@ def test_instr_bytes_accounting():
 def test_vmem_instruction_buffers_halved():
     """Acceptance: the Pallas double-buffer footprint must be at least
     halved by the packed encoding (it is 3x smaller: 8 vs 24 B)."""
-    from repro.kernels.sptrsv import ops
-
     from repro.kernels import common
-    from repro.kernels.sptrsv import kernel
+    from repro.kernels.sptrsv import kernel, ops
 
     prog = api.compile(generate("band_cz"))
-    # the lane-state stream (blocked kernel) streams per-lane words
-    now = ops.instr_buffer_bytes(prog, 128, "blocked")
+    # both kernels stream row-serial entries: 12 B each, K <= ROW_BLOCK,
+    # against a five-plane word for every lane of a 128-cycle block
+    now = ops.instr_buffer_bytes(prog)
+    assert now == 2 * ops._stage_rows(prog).k * (4 * kernel.ROW_PLANES + 4)
     five_plane = 2 * 128 * prog.num_cus * 24
     assert now * 2 <= five_plane
-    # the instruction buffers live in SMEM; the resident kernel's VMEM is
-    # its x buffer alone, the blocked kernel's adds the lane state
+    # the instruction buffers live in SMEM; either kernel's VMEM is its x
+    # state alone: the resident x buffer, or the blocked kernel's ring and
+    # its spare row (b is read from HBM)
     acct = ops.state_bytes(prog, 8, placement="resident")
-    assert acct["instr"] == ops.instr_buffer_bytes(prog, 128)
+    assert acct["instr"] == now
     assert acct["vmem"] == acct["xb"]
-    plan = ops.plan_window(prog, 64)
-    acct_b = ops.state_bytes(prog, 8, placement="blocked", plan=plan,
-                             cycles_per_block=64)
-    assert acct_b["xb"] == plan.state_bytes(8)
-    assert acct_b["instr"] == ops.instr_buffer_bytes(prog, 64, "blocked")
-    lanes = kernel.lane_rows(prog.num_cus, executor._psum_slots(prog))
-    assert acct_b["vmem"] - acct_b["xb"] == common.tiled_bytes(lanes, 8)
+    plan = ops.plan_window(prog)
+    acct_b = ops.state_bytes(prog, 8, placement="blocked", plan=plan)
+    assert acct_b["xb"] == acct_b["vmem"] == plan.state_bytes(8)
+    assert acct_b["instr"] == now
+    assert plan.state_bytes(8) == common.tiled_bytes(
+        plan.window + 2 * plan.stride + 1, 8)
 
 
 def test_instruction_breakdown_smoke():

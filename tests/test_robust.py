@@ -115,10 +115,8 @@ def test_verify_rejects_src_beyond_n(band):
     t, p = np.argwhere(op != 0)[0]
     src = src.copy()
     src[t, p] = prog.n + 5
-    bad = _repack(prog, op, src, ctl, slot)
-    bad = dataclasses.replace(bad, row_lo=None, row_hi=None)  # isolate check
     with pytest.raises(ProgramCorruptionError, match="reads row"):
-        verify_program(bad)
+        verify_program(_repack(prog, op, src, ctl, slot))
 
 
 def test_verify_rejects_duplicate_final(band):
@@ -128,21 +126,17 @@ def test_verify_rejects_duplicate_final(band):
     (t0, p0), (t1, p1) = finals[0], finals[1]
     src = src.copy()
     src[t1, p1] = src[t0, p0]  # row finalized twice, another never
-    bad = dataclasses.replace(_repack(prog, op, src, ctl, slot),
-                              row_lo=None, row_hi=None)
     with pytest.raises(ProgramCorruptionError, match="finalized"):
-        verify_program(bad)
+        verify_program(_repack(prog, op, src, ctl, slot))
 
 
 def test_verify_rejects_dependency_order_violation(band):
-    """Reversing the cycle axis (metadata kept consistent) breaks topology."""
+    """Reversing the cycle axis breaks topology."""
     prog = band[1]
     bad = dataclasses.replace(
         prog,
         instr=prog.instr[::-1].copy(),
         val_idx=prog.val_idx[::-1].copy(),
-        row_lo=prog.row_lo[::-1].copy(),
-        row_hi=prog.row_hi[::-1].copy(),
     )
     with pytest.raises(ProgramCorruptionError, match="dependency order"):
         verify_program(bad)
@@ -185,15 +179,6 @@ def test_verify_rejects_slot_beyond_register_file(ckt):
         verify_program(bad)  # slot range or lifetime, depending on rewrite
 
 
-def test_verify_rejects_stale_row_envelope(band):
-    prog = band[1]
-    bad = dataclasses.replace(prog, row_lo=prog.row_lo.copy())
-    t = int(np.argmax(prog.row_hi >= 0))
-    bad.row_lo[t] += 1
-    with pytest.raises(ProgramCorruptionError, match="row-envelope"):
-        verify_program(bad)
-
-
 # =========================================================== error taxonomy
 @pytest.mark.parametrize("leaf,builtin", [
     (ProgramCorruptionError, ValueError),
@@ -225,12 +210,12 @@ def test_unknown_backend_rejected(band):
 
 def test_jax_backend_rejects_pallas_options(band):
     b = random_rhs(band[0], seed=0)
-    with pytest.raises(BackendOptionsError, match="cycles_per_block"):
+    with pytest.raises(BackendOptionsError, match="placement"):
         api.solve_batch(band[1], np.stack([b, b], 1), backend="jax",
-                        cycles_per_block=64)
+                        placement="blocked")
 
 
-def test_infeasible_blocked_placement_rejected(ckt):
+def test_infeasible_blocked_placement_rejected(ckt, small_row_blocks):
     with pytest.raises(PlacementInfeasibleError, match="infeasible"):
         ops.resolve_placement(ckt[1], 8, placement="blocked")
 
@@ -345,8 +330,9 @@ def test_every_forced_stage_matches_oracle(band, stage):
 
 
 # ================================================= RobustSolver: degradation
-def test_build_failure_degrades_with_incident(ckt):
-    """An infeasible blocked placement degrades; the incident names it."""
+def test_build_failure_degrades_with_incident(ckt, small_row_blocks):
+    """An infeasible blocked placement degrades; the incident names it (the
+    hub DAG has no window once its stream spans several blocks)."""
     mat, prog = ckt
     rs = RobustSolver(prog, mat, ladder=("pallas-blocked", "jax"))
     b = random_rhs(mat, seed=2)

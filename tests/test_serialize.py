@@ -48,7 +48,7 @@ def test_roundtrip_bit_exact(prog, tmp_path):
     path = tmp_path / "band_cz.prog"
     api.save_program(prog, path)
     p2 = api.load_program(path)
-    for name in ("instr", "val_idx", "stream", "row_lo", "row_hi"):
+    for name in ("instr", "val_idx", "stream"):
         np.testing.assert_array_equal(getattr(prog, name), getattr(p2, name))
     assert p2.config == prog.config
     assert (p2.n, p2.num_slots) == (prog.n, prog.num_slots)
@@ -63,12 +63,6 @@ def test_roundtrip_bit_exact(prog, tmp_path):
     b = random_rhs(generate("band_cz"), seed=1)
     np.testing.assert_array_equal(api.solve_numpy(prog, b),
                                   api.solve_numpy(p2, b))
-
-
-def test_roundtrip_without_row_metadata(prog):
-    stripped = dataclasses.replace(prog, row_lo=None, row_hi=None)
-    p2 = serialize.loads_program(serialize.dumps_program(stripped))
-    assert p2.row_lo is None and p2.row_hi is None
 
 
 # ------------------------------------------------------------- targeted defects

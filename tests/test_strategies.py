@@ -67,10 +67,9 @@ def test_strategy_jax_and_pallas_executors_agree(strategy):
                                rtol=1e-5, atol=1e-5)
     xr = ops.solve(prog, b, interpret=True, placement="resident")
     np.testing.assert_allclose(xr, ref, rtol=1e-5, atol=1e-5)
-    plan = ops.plan_window(prog, 64)
+    plan = ops.plan_window(prog)
     assert plan.feasible, plan.reason
-    xb = ops.solve(prog, b, cycles_per_block=64, interpret=True,
-                   placement="blocked")
+    xb = ops.solve(prog, b, interpret=True, placement="blocked")
     np.testing.assert_allclose(xb, ref, rtol=1e-5, atol=1e-5)
 
 

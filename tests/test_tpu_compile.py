@@ -24,7 +24,6 @@ from repro.kernels import common
 from repro.kernels.sptrsv import kernel, ops
 
 B = 16
-CPB = 128
 V5E_VMEM = 128 << 20  # physical VMEM of one v5e TensorCore
 V5E_HBM = 16 << 30
 
@@ -65,15 +64,14 @@ def progs():
             for name in ("ckt_add20", "band_huge64k")}
 
 
-def _kernel_args(prog, rows, one_chip, batch=B, blocked=False):
-    """Argument shapes of the resident kernel (its row-serial stream) or,
-    with ``blocked``, of the blocked kernel (its lane-state stream)."""
-    if blocked:
-        stream = ops._stage_instructions(prog, CPB)
-        kw = dict(num_cus=prog.num_cus, num_slots=executor._psum_slots(prog),
-                  interpret=False)
+def _kernel_args(prog, rows, one_chip, batch=B, plan=None):
+    """Argument shapes of the resident kernel or, with a window ``plan``, of
+    the blocked kernel (each its row-serial stream)."""
+    if plan is not None:
+        stream = ops._stage_blocked(prog, plan)
+        kw = dict(window=plan.window, stride=plan.stride, interpret=False)
     else:
-        stream = ops._stage_rows(prog, CPB)
+        stream = ops._stage_rows(prog)
         kw = dict(interpret=False)
 
     def spec(a):
@@ -113,11 +111,13 @@ def test_resident_kernel_compiles_at_hpcg_rows(progs, one_chip,
 
 def test_blocked_kernel_compiles_for_v5e(progs, one_chip, no_compile_cache):
     prog = progs["band_huge64k"]
-    mode, plan = ops.resolve_placement(prog, B)
-    assert mode == "blocked"  # auto: x+b of 64k rows is past the threshold
-    args, kw = _kernel_args(prog, plan.n_hbm, one_chip, blocked=True)
-    compiled = kernel.sptrsv_pallas_blocked.lower(
-        *args, window=plan.window, stride=plan.stride, **kw).compile()
+    # the resident x of 64k rows fits a core's VMEM, so auto keeps it
+    # resident; the blocked kernel is asked for
+    assert ops.resolve_placement(prog, B)[0] == "resident"
+    mode, plan = ops.resolve_placement(prog, B, placement="blocked")
+    assert mode == "blocked" and plan.num_blocks > 1
+    args, kw = _kernel_args(prog, plan.n_hbm, one_chip, plan=plan)
+    compiled = kernel.sptrsv_pallas_blocked.lower(*args, **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
     # the compiler refuses a kernel past its scoped-VMEM limit, so the
     # compile passing proves the accounted window state fits under it
@@ -133,38 +133,41 @@ def test_blocked_kernel_compiles_for_v5e(progs, one_chip, no_compile_cache):
 
 def test_blocked_kernel_compiles_at_lap7_rows(one_chip, no_compile_cache):
     """The blocked kernel at the geometry `plan_window` gives lap7_ic0_72 in
-    level order (373,248 rows: window 21,832 at stride 1,032 over 346
-    cycle blocks), one column, and the largest segment a 128-cycle block
-    can have (every lane active).  Shapes alone: nothing that large is
-    compiled by the program here."""
-    blocks, k, window, stride = 346, CPB * 64, 21832, 1032
+    level order (373,248 rows, 1,477,440 row-serial entries: window 21,648
+    = 11 strides of 1,968 over 181 blocks of `kernel.ROW_BLOCK` entries),
+    one column.  Shapes alone: nothing that large is compiled by the
+    program here."""
+    blocks, k, window, stride = 181, kernel.ROW_BLOCK, 21648, 1968
     rows = (blocks - 1) * stride + window
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    args = (spec((blocks * kernel.STREAM_PLANES * k,), jnp.int32),
+    args = (spec((blocks * kernel.ROW_PLANES * k,), jnp.int32),
             spec((blocks * k,), jnp.float32), spec((blocks,), jnp.int32),
             spec((rows, 1), jnp.float32))
     compiled = kernel.sptrsv_pallas_blocked.lower(
-        *args, num_cus=64, num_slots=12, window=window, stride=stride,
-        interpret=False).compile()
+        *args, window=window, stride=stride, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    state = kernel.blocked_state_bytes(window, 128, 64, 12)
+    state = kernel.blocked_state_bytes(window, stride, 128)
+    # the ring and its spare row: no b windows, no lane state
+    assert state == common.tiled_bytes(window + 2 * stride + 1, 128)
+    assert state <= 14 * 10 ** 6
     assert state < kernel.vmem_limit(state) <= V5E_VMEM
-    assert 2 * k * (4 * kernel.STREAM_PLANES + 4) <= 1 << 20  # SMEM
+    assert 2 * k * (4 * kernel.ROW_PLANES + 4) <= 1 << 20  # SMEM
 
 
 def test_level_ordered_solve_compiles_for_v5e(one_chip, no_compile_cache,
-                                              monkeypatch):
+                                              monkeypatch, small_row_blocks):
     """A level-ordered program's whole jitted solve: b gathered into the
-    program's row order, the blocked kernel, x gathered back.  The level
-    order is forced at this small size."""
+    program's row order, the blocked kernel over two blocks, x gathered
+    back.  The level order is forced at this small size."""
     monkeypatch.setattr(compiler.order, "wants_level", lambda n: True)
     prog = api.compile(generate("lap7_ic0_8"))
     assert prog.row_perm is not None
-    solve = ops.build_solver_cols(prog, 1, cycles_per_block=32,
-                                  placement="blocked", interpret=False)
+    solve = ops.build_solver_cols(prog, 1, placement="blocked",
+                                  interpret=False)
+    assert solve.plan.num_blocks > 1
     b = jax.ShapeDtypeStruct((prog.n, 1), jnp.float32, sharding=one_chip)
     text = solve.lower(b).compile().as_text()
     assert "tpu_custom_call" in text and "gather" in text
